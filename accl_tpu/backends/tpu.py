@@ -48,11 +48,16 @@ from ..observability import metrics as _metrics
 from ..observability import trace as _trace
 from ..request import Request
 from ..utils.logging import get_logger
+from ..utils.platform import pallas_interpret
 from .base import CCLODevice
 
 # address space stride per buffer handle (addresses are opaque ids here,
 # not memory offsets; slices advance within the stride)
 _ADDR_STRIDE = 1 << 20
+
+#: `stats` counters of the gangs each collective lane executed: the XLA
+#: HLO collective, the Pallas ring kernels, the fused chunked ring
+_LANE_COUNTERS = ("lane_hlo", "lane_ring", "lane_fused")
 
 
 def _import_jax():
@@ -110,6 +115,12 @@ class TpuBuffer(BaseBuffer):
 
     def slice(self, start: int, end: int) -> "BaseBuffer":
         return _TpuBufferSlice(self, start, end)
+
+    def free(self) -> None:
+        """Release the device array: the engine forgets the buffer and
+        every cached gang plan that binds it."""
+        self._device.free_buffer(self)
+        self._dev = None
 
 
 class _TpuBufferSlice(BaseBuffer):
@@ -313,7 +324,8 @@ class TpuEngine:
         #: serialized inline lane, the rest on the executor thread.
         self.metrics = _metrics.MetricsRegistry()
         for k in ("leader_dispatches", "executor_dispatches", "batches",
-                  "batched_gangs", "plan_replays", "plan_auto_captures"):
+                  "batched_gangs", "plan_replays", "plan_auto_captures",
+                  *_LANE_COUNTERS):
             self.metrics.inc(k, 0)
         self._log = get_logger("accl_tpu.tpu")
         # per-link wire telemetry twin (r15): (src rank, comm, peer
@@ -374,6 +386,18 @@ class TpuEngine:
         with self._lock:
             self._buffers[rank][addr] = buf
         return buf
+
+    def free_buffer(self, buf: TpuBuffer) -> None:
+        """Drop `buf` from its rank's registry and evict the cached gang
+        plans that bind it as operand or result, so nothing pins its
+        device array."""
+        rank = self._dev_to_rank[buf._jax_device]
+        with self._lock:
+            self._buffers[rank].pop(buf.address, None)
+            for sig in [sig for sig, plan in self._gang_plans.items()
+                        if any(o[0] == rank and (o[1] is buf or o[4] is buf)
+                               for o in plan["ops"])]:
+                del self._gang_plans[sig]
 
     def resolve(self, rank: int, addr: int):
         """Map a descriptor address to (buffer, element offset)."""
@@ -1341,6 +1365,7 @@ class TpuEngine:
                 slot["op"], slot["comm"], slot["gang"],
                 plan["in_len"] * np.dtype(plan["dtype"]).itemsize,
                 wire_dtype=plan["fn_args"][6])
+            self.metrics.inc(plan["lane"])
             y = plan["compiled"](x)
             self._scatter_back(plan, y)
         elif kind == "local":
@@ -1553,8 +1578,8 @@ class TpuEngine:
                 return None
         plan = self._gang_plan(op, comm_id, gang)
         if plan["fn_args"][8]:
-            # ring=True: the Pallas ring kernels assign fixed
-            # collective_ids per segment parity; fusing two instances
+            # ring=True: the Pallas ring kernels use fixed
+            # collective_ids; fusing two instances
             # into one program would give data-independent rings the
             # SAME barrier/ACK semaphores, which cross-device skew can
             # alias into a double-buffer overrun on real hardware —
@@ -1636,6 +1661,7 @@ class TpuEngine:
                     op_, c_, gang_,
                     plan_["in_len"] * np.dtype(plan_["dtype"]).itemsize,
                     wire_dtype=plan_["fn_args"][6])
+                self.metrics.inc(plan_["lane"])
             fnb = _collective_fn(*items[0][3]["fn_args"],
                                  nbatch=len(items))
             t0 = time.perf_counter_ns()
@@ -1825,6 +1851,9 @@ class TpuEngine:
             # have no hazard at all (e.g. disjoint sub-communicator
             # gangs); only a same-rank overlap is a real RAW.
             "fn_args": fn_args,
+            # the served-lane counter every execution of this plan bumps
+            "lane": ("lane_fused" if fused else
+                     "lane_ring" if ring else "lane_hlo"),
             "opnd_addrs": frozenset(
                 (g, b.address) for g, b, _o, _f, _r, _ro, _os, _rt in ops
                 if b is not None),
@@ -1871,6 +1900,7 @@ class TpuEngine:
             op, comm_id, gang,
             plan["in_len"] * np.dtype(plan["dtype"]).itemsize,
             wire_dtype=plan["fn_args"][6])
+        self.metrics.inc(plan["lane"])
 
         t0 = time.perf_counter_ns()
         y = plan["compiled"](x)
@@ -2112,13 +2142,12 @@ def _collective_fn(mesh, op: Operation, nranks: int, in_len: int, root: int,
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     n = in_len if op not in (Operation.scatter, Operation.reduce_scatter,
                              Operation.alltoall) else in_len // nranks
     is_max = func == int(ReduceFunction.MAX)
-    # Pallas kernels execute under the TPU interpreter on the CPU rung
-    interpret = jax.default_backend() == "cpu"
+    interpret = pallas_interpret()
     red = "max" if is_max else "sum"
 
     def quant(v):
@@ -2242,7 +2271,10 @@ def _collective_fn(mesh, op: Operation, nranks: int, in_len: int, root: int,
                                            scatter_dimension=0,
                                            tiled=True)
         elif op == Operation.alltoall:
-            blocks = v.reshape(nranks, n)
+            # [P, rows, 128] blocks where they divide: a bf16 [P, n]
+            # all_to_all took 97 s to compile for v5e at 128 MiB
+            blocks = v.reshape((nranks, n // 128, 128) if n % 128 == 0
+                               else (nranks, n))
             out = jax.lax.all_to_all(blocks, "rank", split_axis=0,
                                      concat_axis=0, tiled=False)
             out = out.reshape(-1)

@@ -67,7 +67,7 @@ def run(nranks: int = 4, count: int = 1024, iters: int = 300,
 
     from accl_tpu import ReduceFunction
     from accl_tpu.backends.tpu import TpuWorld
-    from accl_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     out: dict = {"nranks": nranks, "count": count, "iters": iters,
                  "rounds": rounds}

@@ -67,7 +67,6 @@ def _make_step(mesh, cfg, lane: str, lr: float):
 
     from ..models.transformer import loss_fn
     from ..parallel.strategies import sync_gradients
-    from ..utils.compat import shard_map as _shard_map
 
     compress, ef = LANES[lane]
 
@@ -89,8 +88,8 @@ def _make_step(mesh, cfg, lane: str, lr: float):
         return (jax.tree_util.tree_map(lambda x: x[None], new_params),
                 loss[None])
 
-    fn = _shard_map(body, mesh=mesh, in_specs=(P("dp"), P("dp")),
-                    out_specs=(P("dp"), P("dp")))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P("dp"), P("dp")),
+                       out_specs=(P("dp"), P("dp")))
     return jax.jit(fn)
 
 

@@ -1,11 +1,10 @@
 """Shared flash-attention schedule sweep harness.
 
-One sweep loop used by both live-chip tools (scripts/flash_tune.py,
-scripts/chip_session.py) so methodology fixes (round structure,
-dead-candidate handling, flops accounting, matmul-peak context) happen
-in exactly one place.  The matmul peak is measured interleaved with the
-candidates because the shared chip's contention windows can depress
-identical kernels 30x — only same-window ratios mean anything.
+Used by the live-chip tuner (scripts/flash_tune.py) and bench.py's
+variant stage, so methodology fixes (round structure, flops accounting,
+matmul-peak context) happen in exactly one place.  The matmul peak is
+measured interleaved with the candidates so every ratio shares a
+window.
 """
 from __future__ import annotations
 

@@ -15,9 +15,6 @@ from __future__ import annotations
 import contextlib
 import csv
 import io
-import os
-import subprocess
-import sys
 import time
 from dataclasses import dataclass
 from typing import Iterable, Optional
@@ -27,87 +24,6 @@ import numpy as np
 from ..constants import ReduceFunction
 from ..observability import metrics as _metrics
 
-
-def claim_platform(prefer: str = "tpu",
-                   timeout_s: Optional[float] = None,
-                   attempts: int = 2) -> str:
-    """Claim an accelerator with the r16 fail-fast contract: probe the
-    ``prefer`` platform in a SUBPROCESS bounded by
-    ``ACCL_TPU_CLAIM_TIMEOUT_S`` (default 60 s) — a wedged libtpu
-    claim (metadata retries, chip held elsewhere) aborts with a clear
-    message instead of hanging the harness, the claim is retried
-    (contention is transient), and on exhaustion this process is
-    pinned to the CPU rung via ``JAX_PLATFORMS`` so whichever rung
-    succeeds gets recorded.  Call BEFORE anything imports jax.
-    Returns the platform actually claimed (``"tpu"``/``"cpu"``)."""
-    if prefer != "tpu":
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        return "cpu"
-    if timeout_s is None:
-        timeout_s = float(os.environ.get("ACCL_TPU_CLAIM_TIMEOUT_S",
-                                         "60"))
-    probe = ("import jax; print(jax.default_backend())")
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
-    for attempt in range(max(1, attempts)):
-        try:
-            proc = subprocess.run(
-                [sys.executable, "-c", probe], capture_output=True,
-                text=True, timeout=timeout_s, env=env)
-        except subprocess.TimeoutExpired:
-            print(f"[sweep] TPU claim attempt {attempt + 1}/{attempts} "
-                  f"exceeded ACCL_TPU_CLAIM_TIMEOUT_S={timeout_s:.0f}s "
-                  f"— aborted (libtpu metadata retries / chip held by "
-                  f"another process)", file=sys.stderr)
-            continue
-        backend = proc.stdout.strip().splitlines()[-1] \
-            if proc.stdout.strip() else ""
-        if proc.returncode == 0 and backend == "tpu":
-            # symmetric with the failure path below: a leftover
-            # JAX_PLATFORMS=cpu (prior fallback, user env) would make
-            # the REAL run silently land on CPU while labeled tpu
-            os.environ.pop("JAX_PLATFORMS", None)
-            return "tpu"
-        print(f"[sweep] TPU claim attempt {attempt + 1}/{attempts} "
-              f"landed on {backend or 'nothing'} "
-              f"(rc={proc.returncode})", file=sys.stderr)
-    print("[sweep] TPU unavailable — falling back to the CPU rung "
-          "(interpret-mode collectives; NOT a hardware number)",
-          file=sys.stderr)
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    return "cpu"
-
-
-def claim_watchdog(label: str, timeout_s: Optional[float] = None,
-                   advice: str = ""):
-    """Arm the in-process half of the claim fail-fast: a daemon timer
-    that aborts THIS process (exit code 3, the orchestrator's
-    retry/fallback signal) if the real libtpu claim wedges past
-    ``ACCL_TPU_CLAIM_TIMEOUT_S`` — the probe in :func:`claim_platform`
-    releases the chip, so the actual claim can still block when
-    another process grabs it in between.  Returns the started Timer
-    (``.cancel()`` once the claim lands) or None when the knob is 0.
-    Shared by bench.py's TPU worker and scripts/accl_tune.py."""
-    import threading
-
-    if timeout_s is None:
-        timeout_s = float(os.environ.get("ACCL_TPU_CLAIM_TIMEOUT_S",
-                                         "60"))
-    if timeout_s <= 0:
-        return None
-
-    def _fire():
-        print(f"[{label}] TPU claim exceeded "
-              f"ACCL_TPU_CLAIM_TIMEOUT_S={timeout_s:.0f}s (libtpu "
-              f"metadata retries / chip held by another process) — "
-              f"aborting the claim{'; ' + advice if advice else ''}",
-              file=sys.stderr, flush=True)
-        os._exit(3)
-
-    timer = threading.Timer(timeout_s, _fire)
-    timer.daemon = True
-    timer.start()
-    return timer
 
 COLLECTIVES = ("sendrecv", "bcast", "scatter", "gather", "allgather",
                "reduce", "allreduce", "reduce_scatter", "alltoall")

@@ -22,7 +22,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..parallel.ring_attention import _dense_attention
-from ..utils.compat import shard_map as _shard_map
 from .transformer import _rmsnorm, sum_count_device_step
 
 
@@ -232,7 +231,7 @@ def make_train_step(mesh, cfg: MoEConfig, lr: float = 1e-3,
             lambda p: loss_fn(p, tokens, cfg, ep, fused=fused),
             params, data_axes, lr)
 
-    step = _shard_map(device_step, mesh=mesh,
+    step = jax.shard_map(device_step, mesh=mesh,
                          in_specs=(specs, tok_spec),
                          out_specs=(specs, P()))
     return jax.jit(step), (specs, tok_spec)

@@ -21,8 +21,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..parallel.ring_attention import _dense_attention, ring_attention
-from ..utils.compat import axis_size as _axis_size
-from ..utils.compat import shard_map as _shard_map
+from ..utils.platform import pallas_interpret
 
 
 @dataclass(frozen=True)
@@ -188,7 +187,7 @@ def _global_positions(Tl: int, cfg: ModelConfig, sp_axis: Optional[str]):
         return jnp.arange(Tl)
     idx = lax.axis_index(sp_axis)
     if cfg.sp_schedule == "zigzag":
-        P_ = _axis_size(sp_axis)
+        P_ = lax.axis_size(sp_axis)
         C = Tl // 2
         a = jnp.arange(C)
         return jnp.concatenate([idx * C + a, (2 * P_ - 1 - idx) * C + a])
@@ -325,7 +324,7 @@ def forward(params, tokens, cfg: ModelConfig, tp_axis: Optional[str] = None,
             attn = flash_attention(q, k, v, causal=True,
                                    mxu_dtype=mxu_dt,
                                    window=cfg.attn_window,
-                                   interpret=jax.default_backend() != "tpu")
+                                   interpret=pallas_interpret())
         else:
             attn = _dense_attention(q, k, v, causal=True,
                                     window=cfg.attn_window)
@@ -365,7 +364,7 @@ def loss_fn(params, tokens, cfg: ModelConfig, tp_axis: Optional[str] = None,
         #                      is its OWN hi chunk's first token;
         #   hi chunk 2P-1-idx -> chunk 2P-idx = rank idx-1's hi-first,
         #                      except idx==0 (the global end, masked).
-        Pn = _axis_size(sp_axis)
+        Pn = lax.axis_size(sp_axis)
         idx = lax.axis_index(sp_axis)
         C = Tl // 2
         lo, hi = tokens[:, :C], tokens[:, C:]
@@ -378,7 +377,7 @@ def loss_fn(params, tokens, cfg: ModelConfig, tp_axis: Optional[str] = None,
             [lo[:, 1:], lo_end, hi[:, 1:], from_prev_hi], axis=1)
         valid = jnp.ones((B, Tl), bool).at[:, -1].set(idx != 0)
     elif sp_axis is not None:
-        Pn = _axis_size(sp_axis)
+        Pn = lax.axis_size(sp_axis)
         idx = lax.axis_index(sp_axis)
         nxt_first = lax.ppermute(tokens[:, :1], sp_axis,
                                  [(i, (i - 1) % Pn) for i in range(Pn)])
@@ -478,8 +477,7 @@ def make_train_step(mesh, cfg: ModelConfig, lr: float = 1e-3,
     tok_spec = P(dp, sp)
     data_axes = tuple(a for a in (dp, sp) if a)
     if check_vma is None:
-        check_vma = not (cfg.attn == "flash"
-                         and jax.default_backend() != "tpu")
+        check_vma = not (cfg.attn == "flash" and pallas_interpret())
 
     if optimizer is None:
         def device_step(params, tokens):
@@ -487,7 +485,7 @@ def make_train_step(mesh, cfg: ModelConfig, lr: float = 1e-3,
                 lambda p: loss_fn(p, tokens, cfg, tp, sp, fused=fused),
                 params, data_axes, lr)
 
-        step = _shard_map(device_step, mesh=mesh,
+        step = jax.shard_map(device_step, mesh=mesh,
                              in_specs=(specs, tok_spec),
                              out_specs=(specs, P()),
                              check_vma=check_vma)
@@ -523,7 +521,7 @@ def make_train_step(mesh, cfg: ModelConfig, lr: float = 1e-3,
         new_params = _optax.apply_updates(params, updates)
         return new_params, new_state, mean_loss
 
-    step = _shard_map(device_step, mesh=mesh,
+    step = jax.shard_map(device_step, mesh=mesh,
                          in_specs=(specs, opt_specs, tok_spec),
                          out_specs=(specs, opt_specs, P()),
                          check_vma=check_vma)

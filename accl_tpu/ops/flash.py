@@ -25,8 +25,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
-from ..utils.compat import tpu_compiler_params as _tpu_compiler_params
 
 NEG_INF = -1e30
 _LOG2E = 1.4426950408889634  # log2(e)
@@ -682,7 +682,6 @@ def _flash_forward_impl(qp, kp, vp, cfg):
     """The schedule dispatch — resolved static config only (see
     `_flash_call_packed`, which owns validation/auto-tuning)."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     (causal, bq, bk, ck, interpret, mxu_dtype, kernel, needs_cast,
      q_tiles, fuse_denom, window, static_max, kv_group) = cfg
@@ -738,7 +737,7 @@ def _flash_forward_impl(qp, kp, vp, cfg):
             # with cast/fused scratch the q-blocks of one batch-head must
             # run in-order ("arbitrary") so the iq==0 build is visible to
             # the rest; without it every cell is independent ("parallel")
-            compiler_params=_tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=(
                     ("parallel", "arbitrary")
                     if (needs_cast or fuse_denom)
@@ -796,7 +795,7 @@ def _flash_forward_impl(qp, kp, vp, cfg):
             ],
             # the k dimension carries the accumulator (sequential); the
             # bh/q-block dims are independent
-            compiler_params=_tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(qp, kp, vp)
@@ -1022,7 +1021,6 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l2_ref, dvec_ref,
 
 def _flash_backward(qp, kp, vp, out, lse, g_out, g_lse, cfg):
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     (causal, bq, bk, ck, interpret, mxu_dtype, _kernel, _nc, _qt,
      _fd, window, _sm, kvg) = cfg
@@ -1093,7 +1091,7 @@ def _flash_backward(qp, kp, vp, out, lse, g_out, g_lse, cfg):
         in_specs=[qb_spec, kb_spec, kb_spec, qb_spec, ql_spec, ql_spec],
         out_specs=qb_spec,
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q2, kp, vp, g_out, l2, dvec)
@@ -1133,7 +1131,7 @@ def _flash_backward(qp, kp, vp, out, lse, g_out, g_lse, cfg):
         out_specs=(ks_spec, ks_spec),
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
-        compiler_params=_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q2, kp, vp, g_out, l2, dvec)

@@ -49,9 +49,8 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.pallas import tpu as pltpu
 
-from ..utils.compat import axis_size as _axis_size
-from ..utils.compat import tpu_compiler_params as _tpu_compiler_params
 from .quantized import dequantize_blockwise, quantize_blockwise
 from .quantized import DEFAULT_BLOCK
 from .ring import (
@@ -218,7 +217,7 @@ def chunked_ring_reduce_scatter(x, axis: str = "rank", op: str = "sum",
     as C independent per-chunk ring chains.  fp32 fold order matches the
     Pallas ring bitwise; ``wire=(block, error_feedback)`` rides the r17
     int8 wire with per-hop requantization fused into the loop."""
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     if P == 1:
         return x
     N = x.shape[0]
@@ -256,7 +255,7 @@ def chunked_ring_all_gather(x, axis: str = "rank",
     per-chunk relay chains.  Values are relayed unchanged (fp) or
     quantized ONCE and relayed in wire form (int8 lane) — a single
     round-trip error regardless of P, as in r17."""
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     if P == 1:
         return x
     n = x.shape[0]
@@ -292,7 +291,7 @@ def chunked_ring_all_reduce(x, axis: str = "rank", op: str = "sum",
     feeding chunked all-gather.  Pads internally to a P*C multiple; on
     the int8 lane the wire-form carry crosses the phase seam without a
     dequant/requant round (r17 invariant, now per chunk)."""
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     if P == 1:
         return x
     N = x.shape[0]
@@ -343,7 +342,6 @@ def pallas_matmul(x, w, block_m: int = 256, block_n: int = 256,
     """Tiled MXU matmul (the compute half of the fusion).  Shapes must be
     multiples of the MXU tile (128) for peak efficiency."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     m, k = x.shape
     k2, n = w.shape
@@ -391,7 +389,7 @@ def fused_matmul_allreduce(x, w, axis: str = "tp", use_pallas: bool = True,
                        jnp.dot(x, w, preferred_element_type=jnp.float32))
         return lax.psum(partial_out, axis)
 
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     if P == 1:
         return (pallas_matmul(x, w, interpret=interpret) if use_pallas
                 else jnp.dot(x, w, preferred_element_type=jnp.float32))
@@ -437,7 +435,7 @@ def fused_expert_ffn(x, expert_idx, ffn: Callable, axis: str = "ep",
     FFN).  Same slotting/capacity semantics as
     parallel.strategies.expert_dispatch/expert_combine; ``ffn`` maps
     [T, D] -> [T, D] row-wise (the per-expert MLP)."""
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     N, D = x.shape
     cap = capacity or -(-N // P)
     C = _pick_chunks(cap, chunks)
@@ -487,9 +485,8 @@ def fused_matmul_reduce_scatter_pallas(x, w, axis: str = "rank",
     flies, then wait and fold.  Same double-buffered landing slots and
     ACK-window flow control; stamp rows use the overlapped clock."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     if P == 1:
         return jnp.dot(x[0], w, preferred_element_type=jnp.float32)
     V, m, K = x.shape
@@ -599,7 +596,7 @@ def fused_matmul_reduce_scatter_pallas(x, w, axis: str = "rank",
             pltpu.SemaphoreType.REGULAR((2,)),
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=collective_id),
         interpret=_interp(interpret),
     )(x, w)
@@ -610,24 +607,64 @@ def fused_matmul_reduce_scatter_pallas(x, w, axis: str = "rank",
     return res
 
 
+#: scoped VMEM one fused-kernel tile may fill with its scratch: the
+#: weight block, the activation block, the fp32 accumulator, its two
+#: landing slots and the fp32 product (v5e's scoped limit is 16 MiB and
+#: Mosaic keeps temporaries of its own beside these)
+_FUSED_VMEM_BUDGET = 12 << 20
+
+
+def _fused_tiles(m: int, K: int, N: int, xbytes: int,
+                 wbytes: int) -> tuple:
+    """(row block, column block) of the [m, N] product that one
+    fused_matmul_reduce_scatter_pallas call computes: halve columns,
+    then rows, until the tile's scratch fits _FUSED_VMEM_BUDGET."""
+    bm, bn = m, N
+
+    def need(bm, bn):
+        return K * bn * wbytes + bm * K * xbytes + 4 * bm * bn * 4
+
+    while need(bm, bn) > _FUSED_VMEM_BUDGET and bn % 256 == 0:
+        bn //= 2
+    while need(bm, bn) > _FUSED_VMEM_BUDGET and bm % 16 == 0:
+        bm //= 2
+    return bm, bn
+
+
 def fused_matmul_allreduce_pallas(x, w, axis: str = "rank",
                                   interpret: bool = False):
     """Allreduce-into-matmul, Pallas form: allreduce(sum_r x @ w_r) for
     x [M, K] (M divisible by P) and K-shard w [K, N] — the fused
     reduce-scatter kernel computes and folds per-hop partials under the
-    wire, then the ring all-gather relays the reduced product rows."""
-    from .ring import ring_all_gather_pallas
+    wire, then the ring all-gather relays the reduced product rows.
+    The product is split into tiles whose scratch fits VMEM, one fused
+    ring pass each."""
+    from .ring import ring_all_gather_segmented
 
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     M, K = x.shape
+    N = w.shape[1]
     if P == 1:
         return jnp.dot(x, w, preferred_element_type=jnp.float32)
     if M % P:
         raise ValueError(f"M ({M}) must divide the '{axis}' axis size "
                          f"({P}); pad the row dimension")
     m = M // P
-    mine = fused_matmul_reduce_scatter_pallas(
-        x.reshape(P, m, K), w, axis, interpret=interpret, collective_id=1)
-    gathered = ring_all_gather_pallas(mine, axis, interpret=interpret,
-                                      collective_id=0)
-    return gathered.reshape(M, w.shape[1])
+    bm, bn = _fused_tiles(m, K, N, x.dtype.itemsize, w.dtype.itemsize)
+    # [row block, P, bm, K] and [col block, K, bn]: one fused ring pass
+    # per (row, col) tile, scanned so the program holds one kernel
+    xs = jnp.swapaxes(x.reshape(P, m // bm, bm, K), 0, 1)
+    ws = jnp.swapaxes(w.reshape(K, N // bn, bn), 0, 1)
+
+    def row(_, xr):
+        def tile(_, wc):
+            return None, fused_matmul_reduce_scatter_pallas(
+                xr, wc, axis, interpret=interpret, collective_id=2)
+
+        _, cols = lax.scan(tile, None, ws)  # [N // bn, bm, bn]
+        return None, jnp.swapaxes(cols, 0, 1).reshape(bm, N)
+
+    _, mine = lax.scan(row, None, xs)  # [m // bm, bm, N]
+    gathered = ring_all_gather_segmented(mine.reshape(-1), axis,
+                                         interpret=interpret)
+    return gathered.reshape(M, N)

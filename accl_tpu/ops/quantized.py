@@ -22,7 +22,6 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
-from ..utils.compat import axis_size as _axis_size
 
 DEFAULT_BLOCK = 256
 
@@ -89,7 +88,7 @@ def _ring_reduce_scatter_q(x, axis: str, block: int,
     of accumulating linearly in P.  ``stochastic`` rounds with PRNG
     bits per (rank, hop) — the jnp twin of the Pallas compress lanes'
     on-core stochastic_round."""
-    size = _axis_size(axis)
+    size = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     if x.shape[0] % size != 0:
         raise ValueError(
@@ -128,7 +127,7 @@ def _ring_all_gather_q(q, sc, n: int, axis: str):
     """Ring all-gather of an already-quantized (q, scale) pair -> flat
     [P * n] f32 (rank-major); contributions are relayed in wire form and
     dequantized once at the end."""
-    size = _axis_size(axis)
+    size = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     fwd = [(i, (i + 1) % size) for i in range(size)]
 

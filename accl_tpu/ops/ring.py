@@ -38,10 +38,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental.pallas import tpu as pltpu
 
 from ..observability.trace import DEVICE_TRACE_FIELDS, record_device_steps
-from ..utils.compat import axis_size as _axis_size
-from ..utils.compat import tpu_compiler_params as _tpu_compiler_params
 
 #: stamp-row width of the ACCL_DEVICE_TRACE kernel output (the column
 #: schema lives with its consumer: observability/trace.py)
@@ -105,14 +104,12 @@ def _payload_nbytes(shape: tuple, dtype: Any) -> int:
 
 
 def _interp(interpret: bool):
+    """The `interpret` argument of a remote-DMA kernel's pallas_call:
+    the TPU interpreter simulates the remote copies and semaphores."""
     if not interpret:
         return False
-    from jax.experimental.pallas import tpu as pltpu
 
-    try:
-        return pltpu.InterpretParams()
-    except Exception:
-        return True
+    return pltpu.InterpretParams()
 
 
 # ---------------------------------------------------------------------------
@@ -166,9 +163,8 @@ def ring_all_gather_pallas(x, axis: str = "rank", interpret: bool = False,
     rank is this device, the result is x tiled V times (checkable).
     """
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     V = ring_size if ring_size is not None else P
     if V != P and P != 1:
         raise ValueError("ring_size override requires a 1-member axis "
@@ -260,7 +256,7 @@ def ring_all_gather_pallas(x, axis: str = "rank", interpret: bool = False,
             pltpu.SemaphoreType.REGULAR((2,)),
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=collective_id),
         interpret=_interp(interpret),
     )(x)
@@ -285,9 +281,8 @@ def ring_reduce_scatter_pallas(x, axis: str = "rank", op: str = "sum",
     result is the full `op`-reduction of our own V chunks (each hop's
     incoming partial is our own accumulator)."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     V = ring_size if ring_size is not None else P
     if V != P and P != 1:
         raise ValueError("ring_size override requires a 1-member axis "
@@ -384,7 +379,7 @@ def ring_reduce_scatter_pallas(x, axis: str = "rank", op: str = "sum",
             pltpu.SemaphoreType.REGULAR((2,)),
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=collective_id),
         interpret=_interp(interpret),
     )(x)
@@ -401,12 +396,11 @@ def ring_all_reduce_pallas(x, axis: str = "rank", op: str = "sum",
     """Segmented ring allreduce = ring reduce-scatter + ring all-gather
     (fw :1888-2071).  Per-member x: [P * n, ...] → same shape, reduced.
 
-    The two phases reuse the ring kernels; XLA overlaps the phase
-    boundary across segments when callers loop over segments.
-    ``ring_size`` propagates the single-device virtual self-ring mode
-    (see ring_all_gather_pallas).
+    The two phases reuse the ring kernels.  ``ring_size`` propagates
+    the single-device virtual self-ring mode (see
+    ring_all_gather_pallas).
     """
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     V = ring_size if ring_size is not None else P
     if V != P and P != 1:
         raise ValueError("ring_size override requires a 1-member axis "
@@ -428,16 +422,24 @@ def ring_all_reduce_pallas(x, axis: str = "rank", op: str = "sum",
 # ---------------------------------------------------------------------------
 # segmentation drivers — the firmware's rx-buffer segmentation above the
 # ring kernels (fw :1888-2071: chunk to rx-buf size, bulk/tail split for
-# ragged payloads).  Chunks are sized to fit VMEM; the Python segment
-# loop unrolls under jit, and alternating collective_id pairs per
-# segment parity keep consecutive segments' barrier semaphores distinct
-# so XLA may overlap them (the firmware's 2-deep end_move window).
+# ragged payloads).  The payload is split evenly over the fewest
+# segments that fit, each zero-padded to whole tiles (under one tile of
+# padding per segment), and a lax.scan runs one ring pass per segment
+# with the same collective ids, so the program holds one set
+# of kernels whatever the payload size: an unrolled loop of 128 MiB in
+# 1 MiB segments took minutes to compile on the chip's host, past the
+# driver's call timeout.  Each chunk travels as a [rows, 128] block, so
+# the kernels slice only untiled leading dimensions (Mosaic refuses a
+# row slice of a 2-D VMEM buffer that is not tile-aligned).
 # ---------------------------------------------------------------------------
 
-#: default segment length in ELEMENTS of the flat payload (1 MiB fp32);
-#: each ring chunk is seg/P elements — comfortably inside ~16 MB VMEM
-#: with the double-buffered landing slots
-DEFAULT_SEG_ELEMS = 1 << 18
+#: lane width of a TPU vreg
+LANES = 128
+
+#: bytes one ring kernel moves per hop; its VMEM scratch holds two
+#: (all-gather) or three (reduce-scatter) of these, well inside v5e's
+#: 16 MiB scoped VMEM
+RING_CHUNK_BYTES = 2 << 20
 
 
 def _pad_to(x, length):
@@ -447,82 +449,98 @@ def _pad_to(x, length):
     return jnp.concatenate([x, pad])
 
 
+def _tile(dtype: Any) -> int:
+    """Elements of one native [sublanes, 128] tile: 8 sublanes for
+    32-bit, 16 for 16-bit, 32 for 8-bit dtypes."""
+    return 8 * max(1, 4 // int(np.dtype(dtype).itemsize)) * LANES
+
+
+def _seg_len(n: int, seg_max: int) -> int:
+    """Payload elements per segment: `n` split evenly over the fewest
+    segments of at most `seg_max`."""
+    return -(-n // -(-n // seg_max))
+
+
+def _round_up(n: int, tile: int) -> int:
+    return -(-n // tile) * tile
+
+
+def _segments(x, seg: int, width: int):
+    """Flat [n] -> [nseg, width]: `seg` payload elements per segment,
+    each zero-padded to `width` (less than one tile more)."""
+    nseg = -(-x.shape[0] // seg)
+    segs = _pad_to(x, nseg * seg).reshape(nseg, seg)
+    return jnp.pad(segs, ((0, 0), (0, width - seg)))
+
+
 def ring_all_reduce_segmented(x, axis: str = "rank", op: str = "sum",
-                              seg_elems: int = DEFAULT_SEG_ELEMS,
+                              seg_elems: Optional[int] = None,
                               interpret: bool = False):
-    """Flat per-member [N] → [N] allreduced, segmented through the ring
-    kernels.  Handles ragged tails by padding the last segment up to a
-    multiple of the ring size (the firmware's bulk/tail counts,
-    fw :1909-1912)."""
-    P = _axis_size(axis)
+    """Flat per-member [N] → [N] allreduced, one ring pass per segment
+    of at most `seg_elems` elements (default: P chunks of
+    RING_CHUNK_BYTES)."""
+    P = lax.axis_size(axis)
     if P == 1:
         return x
     N = x.shape[0]
-    seg = max(P, (min(seg_elems, N) // P) * P)
-    outs = []
-    off = 0
-    i = 0
-    while off < N:
-        s = min(seg, N - off)
-        xs = x[off:off + s]
-        padded = _pad_to(xs, -(-s // P) * P)
-        cid = 2 * (i % 2)
-        red = ring_all_reduce_pallas(padded, axis, op=op,
-                                     interpret=interpret,
-                                     cid_rs=cid, cid_ag=cid + 1)
-        outs.append(red[:s])
-        off += s
-        i += 1
-    return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+    seg = _seg_len(N, seg_elems or P * RING_CHUNK_BYTES // x.dtype.itemsize)
+    width = _round_up(seg, P * _tile(x.dtype))
+    blocks = _segments(x, seg, width).reshape(-1, width // LANES, LANES)
+
+    def one(_, blk):
+        return None, ring_all_reduce_pallas(blk, axis, op=op,
+                                            interpret=interpret,
+                                            cid_rs=0, cid_ag=1)
+
+    _, out = lax.scan(one, None, blocks)
+    return out.reshape(-1, width)[:, :seg].reshape(-1)[:N]
 
 
 def ring_all_gather_segmented(x, axis: str = "rank",
-                              seg_elems: int = DEFAULT_SEG_ELEMS,
+                              seg_elems: Optional[int] = None,
                               interpret: bool = False):
-    """Flat per-member [n] → [P * n] (rank-major), segmented.  Each
-    segment gathers to [P, s]; blocks are re-interleaved so the final
-    layout matches one whole-payload all-gather."""
-    P = _axis_size(axis)
+    """Flat per-member [n] → [P * n] (rank-major), one ring pass per
+    segment; blocks are re-interleaved so the layout matches one
+    whole-payload all-gather."""
+    P = lax.axis_size(axis)
     if P == 1:
         return x
     n = x.shape[0]
-    seg = min(seg_elems, n)
-    pieces = []  # list of [P, s_i]
-    off = 0
-    i = 0
-    while off < n:
-        s = min(seg, n - off)
-        g = ring_all_gather_pallas(x[off:off + s], axis,
-                                   interpret=interpret,
-                                   collective_id=i % 2)
-        pieces.append(g)
-        off += s
-        i += 1
-    if len(pieces) == 1:
-        return pieces[0].reshape(-1)
-    return jnp.concatenate(pieces, axis=1).reshape(-1)
+    seg = _seg_len(n, seg_elems or RING_CHUNK_BYTES // x.dtype.itemsize)
+    width = _round_up(seg, _tile(x.dtype))
+    blocks = _segments(x, seg, width).reshape(-1, width // LANES, LANES)
+
+    def one(_, blk):
+        return None, ring_all_gather_pallas(blk, axis, interpret=interpret,
+                                            collective_id=0)
+
+    _, out = lax.scan(one, None, blocks)  # [nseg, P, rows, 128]
+    out = out.reshape(out.shape[0], P, width)[:, :, :seg]
+    return jnp.swapaxes(out, 0, 1).reshape(P, -1)[:, :n].reshape(-1)
 
 
 def ring_reduce_scatter_segmented(x, axis: str = "rank", op: str = "sum",
-                                  seg_elems: int = DEFAULT_SEG_ELEMS,
+                                  seg_elems: Optional[int] = None,
                                   interpret: bool = False):
-    """Flat per-member [P * n] (rank-major) → member's reduced [n],
-    segmented along the per-rank chunk dimension."""
-    P = _axis_size(axis)
+    """Flat per-member [P * n] (rank-major) → member's reduced [n], one
+    ring pass per segment of the per-rank chunk dimension."""
+    P = lax.axis_size(axis)
     if P == 1:
         return x
     n = x.shape[0] // P
-    chunks = x.reshape(P, n)
-    seg = min(seg_elems, n)
-    outs = []
-    off = 0
-    i = 0
-    while off < n:
-        s = min(seg, n - off)
-        r = ring_reduce_scatter_pallas(chunks[:, off:off + s], axis, op=op,
-                                       interpret=interpret,
-                                       collective_id=i % 2)
-        outs.append(r)
-        off += s
-        i += 1
-    return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+    seg = _seg_len(n, seg_elems or RING_CHUNK_BYTES // x.dtype.itemsize)
+    width = _round_up(seg, _tile(x.dtype))
+    nseg = -(-n // seg)
+    chunks = jnp.pad(x.reshape(P, n), ((0, 0), (0, nseg * seg - n)))
+    chunks = jnp.pad(chunks.reshape(P, nseg, seg),
+                     ((0, 0), (0, 0), (0, width - seg)))
+    blocks = jnp.swapaxes(chunks.reshape(P, nseg, width // LANES, LANES),
+                          0, 1)
+
+    def one(_, blk):
+        return None, ring_reduce_scatter_pallas(blk, axis, op=op,
+                                                interpret=interpret,
+                                                collective_id=0)
+
+    _, out = lax.scan(one, None, blocks)  # [nseg, rows, 128]
+    return out.reshape(nseg, width)[:, :seg].reshape(-1)[:n]

@@ -26,7 +26,6 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
-from ..utils.compat import axis_size as _axis_size
 
 
 # ---------------------------------------------------------------------------
@@ -115,7 +114,7 @@ def ring_reduce_scatter(x, axis: str = "rank"):
     running partial one hop forward and folding the arriving chunk.
     `x`: [P * n, ...] per member → returns member's reduced chunk [n, ...].
     """
-    size = _axis_size(axis)
+    size = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     n = x.shape[0] // size
     chunks = x.reshape((size, n) + x.shape[1:])
@@ -138,7 +137,7 @@ def ring_reduce_scatter(x, axis: str = "rank"):
 def ring_all_gather(x, axis: str = "rank"):
     """Ring all-gather (fw :1404-1502): P-1 steps, forwarding the newest
     block each step.  `x`: [n, ...] → [P * n, ...] in rank-major order."""
-    size = _axis_size(axis)
+    size = lax.axis_size(axis)
     idx = lax.axis_index(axis)
 
     def step(s, carry):

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..utils.compat import axis_size as _axis_size
+from ..utils.platform import pallas_interpret
 
 NEG_INF = -1e30
 
@@ -36,9 +36,7 @@ def _flash_defaults(q):
     HLO interpreter can't run inside shard_map with check_vma=True), and
     the MXU input format (16-bit activations keep their format, f32
     stays exact)."""
-    import jax as _jax
-
-    on_tpu = _jax.default_backend() == "tpu"
+    on_tpu = not pallas_interpret()
     mxu_dt = (q.dtype if q.dtype in (jnp.bfloat16, jnp.float16)
               else jnp.float32)
     return on_tpu, mxu_dt
@@ -201,7 +199,7 @@ def _dense_ring_loop(q, k, v, axis: str, bias_fn):
     streaming-softmax accumulator.  `bias_fn(idx, src) -> [Tl, Tl]`
     computes the additive causal mask for the shard that originated at
     rank `src` (None = unmasked)."""
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     B, Tl, H, D = q.shape
     perm = [(i, (i + 1) % P) for i in range(P)]
@@ -267,7 +265,7 @@ def _ring_attention_dense_zigzag(q, k, v, axis: str):
     bias computed from the zigzag GLOBAL positions of the local rows
     (chunk idx and its mirror 2P-1-idx) instead of a contiguous
     offset."""
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     Tl = q.shape[1]
     if Tl % 2 != 0:
         raise ValueError(f"zigzag needs an even local length, got {Tl}")
@@ -308,7 +306,7 @@ def _ring_attention_flash_zigzag(q, k, v, axis: str,
     from ..ops.flash import NEG_INF as _NI
     from ..ops.flash import flash_attention_lse
 
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     B, Tl, H, D = q.shape
     if Tl % 2 != 0:
@@ -432,7 +430,7 @@ def _ring_attention_windowed(q, k, v, axis: str, window: int,
     grid schedule's bounded-liveness path on TPU).  Boundary block:
     a banded dense cross against the previous shard's K/V (one block
     per rank — it cannot dominate at scale).  Exact merge by lse."""
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     B, Tl, H, D = q.shape
 
@@ -496,7 +494,7 @@ def _ring_attention_flash(q, k, v, axis: str, causal: bool,
     from ..ops.flash import NEG_INF as _NI
     from ..ops.flash import flash_attention_lse
 
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     B, Tl, H, D = q.shape
     perm = [(i, (i + 1) % P) for i in range(P)]
@@ -572,7 +570,7 @@ def ulysses_attention(q, k, v, axis: str = "sp", causal: bool = False,
     ops.flash.flash_attention) to keep the grouped layout and its
     HBM/memory saving.
     """
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     B, Tl, H, D = q.shape
     G = k.shape[2]
     if H % P != 0:
@@ -598,8 +596,7 @@ def ulysses_attention(q, k, v, axis: str = "sp", causal: bool = False,
     attn_fn_wants_expansion = (attn_fn is not None
                                and not attn_fn_gqa_aware)
     if attn_fn is None:
-        import jax as _jax
-        if _jax.default_backend() == "tpu":
+        if not pallas_interpret():
             # full-sequence local attention on the head subset runs the
             # flash kernel (same backend-resolved default as ring)
             from ..ops.flash import flash_attention

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..utils.compat import axis_size as _axis_size
 
 
 # ---------------------------------------------------------------------------
@@ -47,7 +46,7 @@ def sync_gradients(grads, axis: str = "dp", compress: Optional[str] = None,
     ops/quantized.py).  `error_feedback`/`stochastic`/`seed` forward to
     the quantized ring's per-hop requantization error carry (EQuARX);
     they only apply to the int8 lane."""
-    size = _axis_size(axis)
+    size = lax.axis_size(axis)
 
     def sync_leaf(g):
         orig = g.dtype
@@ -75,7 +74,7 @@ def sync_gradients(grads, axis: str = "dp", compress: Optional[str] = None,
 def zero_shard_gradients(grads, axis: str = "dp"):
     """ZeRO-1 style: reduce-scatter each flat gradient so every member
     owns 1/P of the reduced values (optimizer-state sharding)."""
-    size = _axis_size(axis)
+    size = lax.axis_size(axis)
 
     def shard_leaf(g):
         flat = _pad_to_multiple(g.reshape(-1), size)
@@ -133,7 +132,7 @@ def pipeline_apply(stage_fn: Callable, params, x_microbatches,
     ppermute — the reference's tagged send/recv between pipeline
     neighbors (async requests + per-stage communicators in the driver).
     """
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     M = x_microbatches.shape[0]
     fwd = [(i, i + 1) for i in range(P - 1)]  # no wraparound
@@ -170,7 +169,7 @@ def expert_dispatch(x, expert_idx, axis: str = "ep", capacity: int = 0):
     Returns (expert_inputs [P*cap, D], combine_info) — dropped tokens
     (over capacity) combine to zero, mirroring standard MoE capacity
     semantics."""
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     N, D = x.shape
     cap = capacity or -(-N // P)
     # slot each token within its expert bucket
@@ -191,7 +190,7 @@ def expert_dispatch(x, expert_idx, axis: str = "ep", capacity: int = 0):
 def expert_combine(y, combine_info, axis: str = "ep"):
     """Inverse of dispatch: return expert outputs to their source member
     and scatter back into token order.  y: [P*cap, D]."""
-    P = _axis_size(axis)
+    P = lax.axis_size(axis)
     expert_idx, slot, keep, cap = combine_info
     D = y.shape[-1]
     back = lax.all_to_all(y.reshape(P, cap, D), axis, split_axis=0,

@@ -2,7 +2,8 @@
 
 One home for the XLA virtual-device-count dance so its rule lives in
 one place (tests/conftest.py keeps a private inline copy because its
-bootstrap must run before this package can be imported).
+bootstrap must run before this package can be imported), and for the
+one rule that decides whether Pallas kernels run in interpret mode.
 """
 from __future__ import annotations
 
@@ -23,3 +24,18 @@ def ensure_host_device_count(n: int) -> None:
     flags = os.environ.get("XLA_FLAGS", "")
     if _FLAG not in flags:
         os.environ["XLA_FLAGS"] = f"{flags} --{_FLAG}={n}".strip()
+
+
+def pallas_interpret() -> bool:
+    """True on the CPU platform, where Pallas kernels run under the TPU
+    interpreter; False on the TPU, where they compile.  Any other
+    platform has no lowering here and raises."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"no Pallas lowering for platform {backend!r} "
+                       "(supported: tpu, and cpu in interpret mode)")

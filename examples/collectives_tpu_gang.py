@@ -25,8 +25,8 @@ ensure_host_device_count(4)
 
 import jax
 
-# pin CPU unless told otherwise — a busy shared chip blocks the claim
-# (docs/troubleshooting.md)
+# virtual CPU devices stand in for chips; ACCL_EXAMPLE_ON_TPU=1 runs on
+# the TPU instead
 if not os.environ.get("ACCL_EXAMPLE_ON_TPU"):
     jax.config.update("jax_platforms", "cpu")
 

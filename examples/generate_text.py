@@ -23,9 +23,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import jax
 
-# pin the CPU platform unless explicitly told to use an accelerator:
-# querying the backend would CLAIM it, and a busy shared chip blocks
-# the claim indefinitely (see docs/troubleshooting.md)
+# virtual CPU devices stand in for chips; ACCL_EXAMPLE_ON_TPU=1 runs on
+# the TPU instead
 if not os.environ.get("ACCL_EXAMPLE_ON_TPU"):
     jax.config.update("jax_platforms", "cpu")
 

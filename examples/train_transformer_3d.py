@@ -26,9 +26,8 @@ ensure_host_device_count(8)
 
 import jax
 
-# pin the CPU platform unless explicitly told to use an accelerator:
-# querying the backend would CLAIM it, and a busy shared chip blocks
-# the claim indefinitely (see docs/troubleshooting.md)
+# virtual CPU devices stand in for chips; ACCL_EXAMPLE_ON_TPU=1 runs on
+# the TPU instead
 if not os.environ.get("ACCL_EXAMPLE_ON_TPU"):
     jax.config.update("jax_platforms", "cpu")
 

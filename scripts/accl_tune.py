@@ -14,9 +14,7 @@ Usage:
   python scripts/accl_tune.py --backend tpu --ranks 4 \\
       --out tune_table.json --record bench/results/sweep_r16_tuned_vs_static
 
-The TPU rung claims the chip through the r16 fail-fast
-(ACCL_TPU_CLAIM_TIMEOUT_S, default 60 s) and falls back to the CPU
-rung, recording whichever succeeds.
+The TPU rung runs on the TPU that JAX finds and fails without one.
 """
 import argparse
 import csv
@@ -61,16 +59,6 @@ def main() -> int:
     # tests/conftest.py (explicit env still wins)
     os.environ.setdefault("ACCL_DEFAULT_TIMEOUT", "30000000")
 
-    # claim before anything imports jax (the fail-fast contract)
-    from accl_tpu.bench.sweep import claim_platform
-
-    if args.backend == "tpu":
-        claimed = claim_platform("tpu")
-        if claimed != "tpu":
-            args.backend = "emu"
-            print("[accl_tune] recording the emu/CPU rung instead",
-                  file=sys.stderr)
-
     from accl_tpu.tuning import TuneConfig, autotune
     from accl_tpu.utils.topology import parse_shape
 
@@ -87,19 +75,14 @@ def main() -> int:
                      measured_demotion=not args.no_demotion, **kwargs)
 
     if args.backend == "tpu":
-        # the probe in claim_platform released the chip; the REAL
-        # claim below gets the same fail-fast watchdog (another
-        # process can wedge the chip in the probe->claim window)
-        from accl_tpu.bench.sweep import claim_watchdog
+        import jax
 
-        guard = claim_watchdog(
-            "accl_tune", advice="re-run with --backend emu for the "
-            "CPU rung")
+        if jax.default_backend() != "tpu":
+            sys.exit(f"--backend tpu: JAX found platform "
+                     f"{jax.default_backend()!r}")
         from accl_tpu.backends.tpu import TpuWorld
 
         world = TpuWorld(args.ranks)
-        if guard is not None:
-            guard.cancel()
     else:
         from accl_tpu.backends.emu import EmuWorld
 

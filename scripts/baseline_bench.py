@@ -118,9 +118,10 @@ def config5(out, full: bool = False, reps: int = 5):
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from accl_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     from accl_tpu.ops.fused import fused_matmul_allreduce
+    from accl_tpu.utils.platform import pallas_interpret
     from accl_tpu.utils.profiling import time_fn
 
     n_dev = len(jax.devices())
@@ -132,7 +133,7 @@ def config5(out, full: bool = False, reps: int = 5):
     x = jnp.ones((m, k_per * n_dev), dtype)
     w = jnp.ones((k_per * n_dev, n), dtype)
 
-    use_pallas = jax.default_backend() == "tpu"
+    use_pallas = not pallas_interpret()
 
     @jax.jit
     def fused(x, w):

@@ -177,8 +177,6 @@ def _vsraw_stage(args, tag, TpuWorld) -> None:
     # shard_map psum.  The ratio column is the driver's end-to-end
     # overhead at each size.
     import jax as _jax
-    from accl_tpu.utils.compat import install as _compat_install
-    _compat_install(_jax)  # old-jax: alias jax.shard_map to the shim
     import jax.numpy as _jnp
     import numpy as _np
     from jax.sharding import Mesh as _Mesh, NamedSharding as _NS, PartitionSpec as _P

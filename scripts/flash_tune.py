@@ -2,9 +2,8 @@
 
 Sweeps resident-schedule block shapes / chunking / q-tile interleave /
 fused-denominator on the bench's D=128 shape and prints a TFLOPs table
-(matmul peak measured interleaved so fractions are window-robust on the
-shared chip).  The sweep loop itself lives in
-accl_tpu.bench.flash_sweep (shared with scripts/chip_session.py).
+(matmul peak measured interleaved, so fractions share a window).  The
+sweep loop itself lives in accl_tpu.bench.flash_sweep.
 
 Usage: python scripts/flash_tune.py [rounds]
 Env:   FLASH_TUNE_ONLY=substr1,substr2   filter candidates

@@ -128,8 +128,8 @@ def test_flash_gqa_lowers_through_mosaic(kern):
 ])
 def test_flash_scratch_paths_lower_through_mosaic(opts):
     # f32 inputs + bf16 MXU dtype: every VMEM scratch branch of the
-    # resident kernel must lower, or live-chip sweep candidates die
-    # DEAD in a scarce claim window
+    # resident kernel must lower, or live-chip sweep candidates fail on
+    # the chip
     from accl_tpu.ops.flash import flash_attention_packed
 
     N, T, D = 4, 2048, 128

@@ -9,6 +9,7 @@ import pytest
 
 from accl_tpu import DataType, ReduceFunction
 from accl_tpu.backends.tpu import TpuWorld
+from accl_tpu.utils.platform import pallas_interpret
 
 NRANKS = 4
 COUNT = 64
@@ -189,6 +190,31 @@ def test_allreduce(world):
         np.testing.assert_allclose(recv.host, exp, rtol=1e-5, atol=1e-5)
 
     world.run(fn)
+
+
+def test_buffer_free_releases_registry_and_plans(world):
+    # freed buffers leave the engine's registry and the cached gang
+    # plans that bound them, so repeated large calls do not pin device
+    # memory for the life of the world
+    eng = world.engine
+
+    def fn(accl, rank):
+        send = accl.create_buffer_like(_data(COUNT, rank))
+        recv = accl.create_buffer(COUNT, np.float32)
+        accl.allreduce(send, recv, COUNT, ReduceFunction.SUM)
+        return send, recv
+
+    bufs = world.run(fn)
+    assert any(o[1] is bufs[0][0] for plan in eng._gang_plans.values()
+               for o in plan["ops"])
+    for rank, (send, recv) in enumerate(bufs):
+        send.free()
+        recv.free()
+        assert eng.resolve(rank, send.address) == (None, 0)
+        assert send.dev is None
+    assert not any(o[1] is b or o[4] is b
+                   for plan in eng._gang_plans.values()
+                   for o in plan["ops"] for pair in bufs for b in pair)
 
 
 def test_reduce_scatter(world):
@@ -473,7 +499,7 @@ def test_driver_allreduce_close_to_raw_psum():
                 best = dt if best is None else min(best, dt)
             return best
 
-        on_tpu = jax.default_backend() not in ("cpu",)
+        on_tpu = not pallas_interpret()
         # 2x is the hardware target (asserted when running on real TPU);
         # the CPU virtual-device rung gets single-digit headroom for the
         # Python gang scheduler sharing one core with the XLA runtime —

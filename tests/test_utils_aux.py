@@ -136,41 +136,42 @@ def _restore_jax_cache_config():
         jax.config.update(k, v)
 
 
-def test_compile_cache_enable(tmp_path, _restore_jax_cache_config):
-    # the chip-facing tools call this before their first compile; it
-    # must activate the persistent cache (compiles survive process
-    # restarts) and report the directory it actually used
+def test_compile_cache_env_dir_gets_entries(tmp_path, monkeypatch,
+                                            _restore_jax_cache_config):
+    # JAX_COMPILATION_CACHE_DIR, where set, is the cache: a compile
+    # after enable() lands an entry there
     import jax
     import jax.numpy as jnp
 
     from accl_tpu.utils.compile_cache import enable
 
-    d = enable(str(tmp_path / "cache"))
-    assert d == str(tmp_path / "cache")
-    assert os.path.isdir(d)
-    # a compile after enable() lands an artifact in the cache dir
+    target = str(tmp_path / "envcache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", target)
+    assert enable() == target
+    assert jax.config.jax_compilation_cache_dir == target
     fn = jax.jit(lambda x: x * 2 + 1)
     fn(jnp.ones((8, 128))).block_until_ready()
-    assert os.listdir(d), "no cache entry written for a fresh compile"
+    assert os.listdir(target), "no cache entry written for a fresh compile"
 
 
-def test_compile_cache_env_override(tmp_path, monkeypatch,
-                                    _restore_jax_cache_config):
-    # $ACCL_COMPILE_CACHE wins over the per-user default when no
-    # explicit path is passed
-    from accl_tpu.utils.compile_cache import enable
-
-    target = str(tmp_path / "envcache")
-    monkeypatch.setenv("ACCL_COMPILE_CACHE", target)
-    assert enable() == target
-
-
-def test_compile_cache_default_dir_is_per_user():
-    # a world-shared fixed path would be owned by whoever ran first on
-    # a shared host; the default must be user-scoped
-    import getpass
+def test_compile_cache_default_is_fixed_in_checkout(
+        monkeypatch, _restore_jax_cache_config):
+    # unset, the cache sits at one path inside the checkout, the same
+    # on every call (the path is part of a cache entry's key)
+    import jax
 
     from accl_tpu.utils import compile_cache
 
-    d = compile_cache._default_dir()
-    assert getpass.getuser() in os.path.basename(d)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    assert compile_cache.enable() == want
+    assert compile_cache.enable() == want
+    assert jax.config.jax_compilation_cache_dir == want
+
+
+def test_compile_cache_default_dir_is_gitignored():
+    # cache entries are made at run time and never committed
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
