@@ -1649,8 +1649,13 @@ class ACCL:
         # flight recorder is in the gate because it is ON by default —
         # the always-on black box — so the no-observer fast path only
         # exists under ACCL_FLIGHT=0 + ACCL_METRICS=0 + trace off.
+        # Traced, the driver stage's annotation (accl.driver) opens here
+        # and closes at the queue hand-off (RequestQueue.submit).
+        traced = _trace.enabled()
+        if traced:
+            _trace.stage("accl.driver")
         observe = (self.flight_recorder is not None or _metrics.enabled()
-                   or _trace.enabled())
+                   or traced)
         t_submit = _trace.now_ns() if observe else 0
         # size validation: the descriptor carries the full count, so a
         # short buffer would silently corrupt (the reference throws from

@@ -328,17 +328,19 @@ class EmuDevice(CCLODevice):
     # -- call path ----------------------------------------------------
     def start(self, call: CCLOCall, request: Request) -> None:
         # the native engine owns the session send/recv + rendezvous
-        # retry loop below this point, so the span's device window is
+        # retry loop below this point, so the span's launch window is
         # the descriptor-post → engine-completion interval (its interior
         # breakdown is the engine's cycle-count duration, stamped on the
         # request as duration_ns)
         span = request.trace
         rec = request.flight
-        if rec is not None:
-            rec.mark_dispatched("emu", _trace.now_ns())
-        if span is not None:
-            span.lane = "emu"
-            span.t_dispatch = span.t_device_begin = _trace.now_ns()
+        if rec is not None or span is not None:
+            t = _trace.now_ns()
+            if rec is not None:
+                rec.mark_dispatched("emu", t)
+            if span is not None:
+                span.lane = "emu"
+                span.t_dispatch = span.t_launch = t
         # snapshot + submit + waiter registration happen atomically
         # against close() (the _lifecycle contract above): after this
         # block either the call is tracked (close joins its waiter
@@ -366,7 +368,7 @@ class EmuDevice(CCLODevice):
                                               ctypes.byref(ret),
                                               ctypes.byref(dur))
                 if span is not None:
-                    span.t_device_end = _trace.now_ns()
+                    span.t_launched = _trace.now_ns()
                 if ok:
                     request.complete(ret.value, dur.value)
                 else:

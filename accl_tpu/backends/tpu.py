@@ -155,33 +155,22 @@ class _TpuBufferSlice(BaseBuffer):
                                self._start + end)
 
 
-def _mark_spans(gang: dict, lane: Optional[str] = None,
-                t_ready: Optional[int] = None,
-                t_dispatch: Optional[int] = None,
-                t_dev0: Optional[int] = None,
-                t_dev1: Optional[int] = None) -> None:
-    """Stamp a gang's member TraceSpans with scheduler events (no-op
-    per member when tracing is off: request.trace stays None)."""
-    for _call, req, _krnl in gang.values():
-        span = req.trace
-        if span is None:
-            continue
-        if lane is not None:
-            span.lane = lane
-        if t_ready is not None:
-            span.t_gang_ready = t_ready
-        if t_dispatch is not None:
-            span.t_dispatch = t_dispatch
-        if t_dev0 is not None:
-            span.t_device_begin = t_dev0
-        if t_dev1 is not None:
-            span.t_device_end = t_dev1
+def _spans(gang: dict, lane: Optional[str] = None) -> list:
+    """A gang's member TraceSpans (None where a request carries none),
+    tagged with `lane` where given: the `spans` of trace.stage, called
+    only while tracing is on."""
+    spans = [req.trace for _call, req, _krnl in gang.values()]
+    if lane is not None:
+        for span in spans:
+            if span is not None:
+                span.lane = lane
+    return spans
 
 
 def _mark_flight(gang: dict, state: int, lane: Optional[str] = None,
                  t: Optional[int] = None) -> None:
     """Stamp a gang's member flight records with one scheduler state
-    transition — ALWAYS on (unlike _mark_spans): a handful of attribute
+    transition — ALWAYS on (unlike the spans): a handful of attribute
     writes per member, the whole per-call flight budget at this layer."""
     for _call, req, _krnl in gang.values():
         rec = req.flight
@@ -480,25 +469,17 @@ class TpuEngine:
         rec = request.flight
         try:
             if scenario in (Operation.copy, Operation.combine):
-                if rec is not None:
-                    rec.mark_dispatched("local", _trace.now_ns())
-                if span is not None:
-                    span.lane = "local"
-                    span.t_dispatch = span.t_device_begin = _trace.now_ns()
+                self._mark_local(rec, span, "local")
                 if scenario == Operation.copy:
                     self._exec_copy(rank, call)
                 else:
                     self._exec_combine(rank, call)
                 if span is not None:
-                    span.t_device_end = _trace.now_ns()
+                    span.t_launched = _trace.now_ns()
                 request.complete(0, 1.0)
                 return
             if scenario in (Operation.send, Operation.recv):
-                if rec is not None:
-                    rec.mark_dispatched("p2p", _trace.now_ns())
-                if span is not None:
-                    span.lane = "p2p"
-                    span.t_dispatch = span.t_device_begin = _trace.now_ns()
+                self._mark_local(rec, span, "p2p")
                 if scenario == Operation.send:
                     self._submit_send(rank, call, request)
                 else:
@@ -510,6 +491,20 @@ class TpuEngine:
 
             request.description += f" [{e}]"
             request.complete(int(ErrorCode.DMA_INTERNAL_ERROR), 0.0)
+
+    @staticmethod
+    def _mark_local(rec, span, lane: str) -> None:
+        """Dispatch stamp of a rank-local lane (local op, p2p): one
+        clock read for the flight record and the span, whose launch
+        opens at dispatch (these lanes have no gang stages)."""
+        if rec is None and span is None:
+            return
+        t = _trace.now_ns()
+        if rec is not None:
+            rec.mark_dispatched(lane, t)
+        if span is not None:
+            span.lane = lane
+            span.t_dispatch = span.t_launch = t
 
     # -- local ops -----------------------------------------------------
     def _exec_copy(self, rank: int, call: CCLOCall) -> None:
@@ -649,8 +644,8 @@ class TpuEngine:
                 self._push_stream(rank, call.tag, moved)
             else:
                 dst.set_dev_range(doff, moved)
-            if request.trace is not None:  # delivery == device window end
-                request.trace.t_device_end = _trace.now_ns()
+            if request.trace is not None:  # delivery ends the launch
+                request.trace.t_launched = _trace.now_ns()
             request.complete(0, 1.0)
 
     # -- collectives ---------------------------------------------------
@@ -679,6 +674,9 @@ class TpuEngine:
                 request.complete(
                     int(ErrorCode.SEGMENTER_EXPECTED_BTT_ERROR), 0.0)
                 return
+        span = request.trace
+        if span is not None:  # accl.gang: submit through gang join
+            _trace.stage("accl.gang")
         gkey = ("coll", int(call.scenario), call.comm, call.tag)
         # link twin (r15): gang-arrival stamp for straggler-wait
         # attribution (one clock read per collective submit)
@@ -700,22 +698,23 @@ class TpuEngine:
                 if P == 1:
                     ready = gang
                     q.remove(gang)
-        if ready is not None:
-            t_ready = _trace.now_ns()  # last member arrived: gang exists
-            _mark_flight(ready, _flight.S_GANG_READY, t=t_ready)
-            if _trace.enabled():
-                _mark_spans(ready, t_ready=t_ready)
-            self._account_gang_wait(call.comm, ready, t_ready)
-            # plan auto-capture (ACCL_PLAN_AUTO): arm a one-slot ring
-            # when EVERY member of this instance declared intent — the
-            # agreement rides the gang itself, so all ranks switch to
-            # replay on the same future instance.  One attr read per
-            # member on the ready path, only here.
-            if all(r_.plan_intent for _c, r_, _k in ready.values()):
-                self._arm_auto_ring(int(call.scenario), call.comm,
-                                    ready)
-            self._dispatch_gang(int(call.scenario), call.comm, ready,
-                                request)
+        if ready is None:
+            if span is not None:  # joined: accl.gang ends
+                _trace.stage(None)
+            return
+        t_ready = _trace.now_ns()  # last member arrived: gang exists
+        _mark_flight(ready, _flight.S_GANG_READY, t=t_ready)
+        if span is not None:  # joined: accl.gang ends
+            _trace.stage(None, _spans(ready), "t_gang_ready", t_ready)
+        self._account_gang_wait(call.comm, ready, t_ready)
+        # plan auto-capture (ACCL_PLAN_AUTO): arm a one-slot ring when
+        # EVERY member of this instance declared intent — the agreement
+        # rides the gang itself, so all ranks switch to replay on the
+        # same future instance.  One attr read per member on the ready
+        # path, only here.
+        if all(r_.plan_intent for _c, r_, _k in ready.values()):
+            self._arm_auto_ring(int(call.scenario), call.comm, ready)
+        self._dispatch_gang(int(call.scenario), call.comm, ready, request)
 
     def _dispatch_gang(self, scenario: int, comm_id: int, gang: dict,
                        leader_req: Request) -> None:
@@ -763,10 +762,6 @@ class TpuEngine:
                     return
                 try:
                     self.metrics.inc("leader_dispatches")
-                    _mark_flight(gang, _flight.S_DISPATCHED,
-                                 lane="leader", t=_trace.now_ns())
-                    if _trace.enabled():
-                        _mark_spans(gang, lane="leader")
                     self._exec_gang(scenario, comm_id, gang)
                 finally:
                     with self._ready_cv:
@@ -1614,46 +1609,45 @@ class TpuEngine:
 
     def _exec_gang(self, scenario: int, comm_id: int, gang: dict) -> None:
         # NB: signature is stable API for the lock-discipline test spies
-        # (tests/test_tpu_backend.py wraps it positionally); the leader
-        # lane pre-tags its spans, everything else defaults to executor
+        # (tests/test_tpu_backend.py wraps it positionally).  The lane
+        # follows from who runs it: a leader-dispatch run holds
+        # _inline_busy throughout, and the executor never runs beside it
+        lane = "leader" if self._inline_busy else "executor"
+        traced = _trace.enabled()
         try:
-            _mark_flight(gang, _flight.S_DISPATCHED, lane="executor",
-                         t=_trace.now_ns())
-            if _trace.enabled():
-                td = _trace.now_ns()
-                for _c, req, _k in gang.values():
-                    span = req.trace
-                    if span is not None:
-                        if span.lane is None:
-                            span.lane = "executor"
-                        span.t_dispatch = td
-            dt_ns, t0, t1 = self._run_collective(Operation(scenario),
-                                                 comm_id, gang)
-            if _trace.enabled():
-                _mark_spans(gang, t_dev0=t0, t_dev1=t1)
+            t = _trace.now_ns()
+            _mark_flight(gang, _flight.S_DISPATCHED, lane=lane, t=t)
+            if traced:
+                _trace.stage("accl.marshal", _spans(gang, lane),
+                             "t_dispatch", t)
+            dt_ns = self._run_collective(Operation(scenario), comm_id,
+                                         gang)
             for call, request, _krnl in gang.values():
                 request.complete(0, float(dt_ns))
         except Exception as e:
             for call, request, _krnl in gang.values():
                 request.description += f" [{e}]"
                 request.complete(int(ErrorCode.DMA_INTERNAL_ERROR), 0.0)
+        finally:
+            if traced:  # accl.finish ends: every member completed
+                _trace.stage(None)
 
     def _exec_gang_batch(self, items) -> None:
         """K same-program, RAW-independent gangs in ONE dispatch: the
         batched compiled fn takes K sharded globals and returns K
         results (inputs are all read before any rebind, which is
         exactly the sequential semantics the RAW guard preserves)."""
-        import time
-
+        traced = _trace.enabled()
         try:
-            tf = _trace.now_ns()
+            t = _trace.now_ns()
             for _op, _c, gang, _plan in items:
                 _mark_flight(gang, _flight.S_DISPATCHED, lane="batched",
-                             t=tf)
-            if _trace.enabled():
-                td = _trace.now_ns()
+                             t=t)
+            spans: list = []
+            if traced:
                 for _op, _c, gang, _plan in items:
-                    _mark_spans(gang, lane="batched", t_dispatch=td)
+                    spans += _spans(gang, "batched")
+                _trace.stage("accl.marshal", spans, "t_dispatch", t)
             xs = [self._assemble_global(plan, gang)
                   for _op, _c, gang, plan in items]
             for op_, c_, gang_, plan_ in items:
@@ -1664,26 +1658,28 @@ class TpuEngine:
                 self.metrics.inc(plan_["lane"])
             fnb = _collective_fn(*items[0][3]["fn_args"],
                                  nbatch=len(items))
-            t0 = time.perf_counter_ns()
+            # one fused launch shared by every batched gang — the
+            # aligned cross-gang slice the timeline shows
+            t0 = _trace.now_ns()
+            if traced:
+                _trace.stage("accl.launch", spans, "t_launch", t0)
             ys = fnb(*xs)
             if self.profile_sync:
                 import jax
 
                 jax.block_until_ready(ys)
-            t1 = time.perf_counter_ns()
-            dt_ns = t1 - t0
-            if _trace.enabled():
-                # one fused device window shared by every batched gang —
-                # the aligned cross-gang slice the timeline shows
-                for _op, _c, gang, _plan in items:
-                    _mark_spans(gang, t_dev0=t0, t_dev1=t1)
+            t1 = _trace.now_ns()
+            if traced:
+                _trace.stage("accl.finish", spans, "t_launched", t1)
             # per-call perf counter: the batch's wall time is shared by
             # K fused dispatches, so each call's duration is its share
             # (reporting the whole batch per call would inflate
             # get_duration by the batch width)
-            per_call = float(dt_ns) / len(items)
+            per_call = float(t1 - t0) / len(items)
             for (op, _c, gang, plan), y in zip(items, ys):
                 self._scatter_back(plan, y)
+                if traced:
+                    _trace.stamp(_spans(gang), "t_rebound")
                 for call, request, _krnl in gang.values():
                     request.complete(0, per_call)
         except Exception as e:
@@ -1698,6 +1694,9 @@ class TpuEngine:
                     request.description += f" [{e}]"
                     request.complete(int(ErrorCode.DMA_INTERNAL_ERROR),
                                      0.0)
+        finally:
+            if traced:  # accl.finish ends: every member completed
+                _trace.stage(None)
 
     def _gang_plan(self, op: Operation, comm_id: int, gang: dict):
         """Resolve one gang signature into an execution plan and cache
@@ -1884,15 +1883,14 @@ class TpuEngine:
         costs one make_array + one compiled dispatch, no per-member jax
         ops.
 
-        Returns (duration_ns, device_begin_ns, device_end_ns) so the
-        dispatch lanes can stamp the device window on member spans."""
-        import time
-
+        Returns the duration in ns.  Traced, it stamps the member spans'
+        launch, launched and rebound, and moves the dispatching
+        thread's stage from accl.marshal to accl.launch to accl.finish
+        (a barrier has no program: its spans keep those stamps None)."""
         jax, jnp, Mesh, NamedSharding, P = _import_jax()
 
         if op == Operation.barrier:
-            t = time.perf_counter_ns()
-            return 0, t, t  # gang completion IS the synchronization
+            return 0  # gang completion IS the synchronization
 
         plan = self._gang_plan(op, comm_id, gang)
         x = self._assemble_global(plan, gang)
@@ -1902,16 +1900,23 @@ class TpuEngine:
             wire_dtype=plan["fn_args"][6])
         self.metrics.inc(plan["lane"])
 
-        t0 = time.perf_counter_ns()
+        spans = _spans(gang) if _trace.enabled() else None
+        t0 = _trace.now_ns()
+        if spans is not None:
+            _trace.stage("accl.launch", spans, "t_launch", t0)
         y = plan["compiled"](x)
         if self.profile_sync:
             # exact perf-counter mode: duration is on-device time and
             # async errors surface here (see __init__)
             jax.block_until_ready(y)
-        t1 = time.perf_counter_ns()
+        t1 = _trace.now_ns()
+        if spans is not None:
+            _trace.stage("accl.finish", spans, "t_launched", t1)
 
         self._scatter_back(plan, y)
-        return t1 - t0, t0, t1
+        if spans is not None:
+            _trace.stamp(spans, "t_rebound")
+        return t1 - t0
 
     def _assemble_global(self, plan: dict, gang: dict):
         jax, jnp, Mesh, NamedSharding, P = _import_jax()

@@ -298,8 +298,8 @@ def _rank_window(rank: int, label: str):
         yield
     finally:
         if span is not None:
-            span.t_device_begin = span.t_submit
-            span.t_device_end = span.t_complete = _trace.now_ns()
+            span.t_launch = span.t_submit
+            span.t_launched = span.t_complete = _trace.now_ns()
             _trace.collector().add(span)
 
 

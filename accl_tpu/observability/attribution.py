@@ -34,8 +34,9 @@ Method
    arrival — zero for the straggler itself), ``dispatch`` (gang-ready →
    dispatch where the backend stamps it), and ``wire`` (everything
    after the gang assembled: transport + reduction).  When a Perfetto
-   trace doc is supplied, the device window splits ``wire`` into
-   ``wire`` (pre-device) and ``reduce`` (device-begin→device-end).
+   trace doc is supplied, the launch window splits ``wire`` into
+   ``wire`` (pre-launch) and ``reduce`` (launch→launched: the host's
+   call of the program, which on the chip returns at enqueue).
 4. **Straggler attribution** — per gang instance the last-arriving
    rank, its lateness vs the first arrival, aggregated per
    (collective, comm, size-bucket): episode counts, share, mean/max
@@ -131,25 +132,25 @@ def _arrival_ns(rec: dict) -> Optional[int]:
     return None
 
 
-def _device_windows(trace_doc: Optional[dict]) -> dict:
-    """(rank, collective, occurrence) -> (device_begin, device_end)
-    from a Perfetto doc's lane slices (trace.TraceCollector schema)."""
+def _launch_windows(trace_doc: Optional[dict]) -> dict:
+    """(rank, collective, occurrence) -> (launch, launched) from a
+    Perfetto doc's lane slices (trace.TraceCollector schema)."""
     if not trace_doc:
         return {}
     # multiple tracks (call / queue / lane) carry the SAME span, so the
-    # same device window repeats across consecutive events: collapse
+    # same launch window repeats across consecutive events: collapse
     # identical repeats per (rank, collective) and number the distinct
-    # windows — occurrence i is the i-th real device execution
+    # windows — occurrence i is the i-th real program launch
     per: dict = {}
     for ev in trace_doc.get("traceEvents", []):
         args = ev.get("args") or {}
         ts = (args.get("timestamps_ns") or {})
-        if ev.get("ph") != "X" or not ts.get("device_begin") \
-                or not ts.get("device_end"):
+        if ev.get("ph") != "X" or not ts.get("launch") \
+                or not ts.get("launched"):
             continue
         rank = ev.get("pid", -1)
         coll = ev.get("name", "").split(" ")[0]
-        win = (ts["device_begin"], ts["device_end"])
+        win = (ts["launch"], ts["launched"])
         lst = per.setdefault((rank, coll), [])
         if win not in lst:
             lst.append(win)
@@ -178,7 +179,7 @@ def attribute(dumps, trace_doc: Optional[dict] = None,
     ranks = sorted(rd["rank"] for rd in doc["ranks"])
     instances = _gang_instances(doc)
     skew = estimate_clock_skew(instances, ranks)
-    windows = _device_windows(trace_doc)
+    windows = _launch_windows(trace_doc)
     win_seen: dict = {}
 
     groups: dict = {}

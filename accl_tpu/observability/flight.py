@@ -129,7 +129,7 @@ class FlightRecord:
     def mark_dispatched(self, lane: str, t: int) -> None:
         """The one dispatch-stamp used by every lane (emu descriptor
         post, local/p2p, gang executor/leader/batched); a lane already
-        tagged by an earlier stage (leader pre-tag) is preserved."""
+        tagged is preserved."""
         self.state = S_DISPATCHED
         self.t_dispatch = t
         if self.lane is None:

@@ -3,12 +3,14 @@
 Every collective call carries a :class:`TraceSpan` through the whole
 stack — submit (driver), queue-enter (request layer), gang-ready and
 dispatch (backend gang scheduler, lane-tagged: leader / executor /
-batched / emu), device-begin/end (compiled program window), and
-callback-complete.  Spans from every rank of an in-process world land
-in one bounded ring buffer (:class:`TraceCollector`) and export as
-Chrome/Perfetto ``trace_event`` JSON: one process (pid) per rank, one
-track (tid) per stage/lane, and gang members share a gang id so a
-fused gang program shows as one aligned slice across ranks.
+batched / emu), launch/launched (the host call of the compiled
+program), rebound (results rebound into the member buffers) and
+complete (the last thing before the waiter wakes).  Spans from every
+rank of an in-process world land in one bounded ring buffer
+(:class:`TraceCollector`) and export as Chrome/Perfetto
+``trace_event`` JSON: one process (pid) per rank, one track (tid) per
+stage/lane, and gang members share a gang id so a fused gang program
+shows as one aligned slice across ranks.
 
 Reference analogs: the hardware exposes only a per-call cycle counter
 (get_duration, SURVEY §5) — this layer is the per-stage breakdown
@@ -20,6 +22,25 @@ Overhead discipline: tracing is OFF unless ``ACCL_TRACE`` is set
 path at exit).  When off, :func:`enabled` is a module-bool read and
 :func:`new_span` is never called — the instrumented hot paths allocate
 nothing (tests/test_observability.py pins this).
+
+Stages on the profiler's clock: while tracing is on, :func:`stage`
+also opens a ``jax.profiler.TraceAnnotation`` per stage on the thread
+that does the work, so a JAX profiler capture (``traced_window`` with
+``ACCL_XLA_TRACE``, or any ``jax.profiler`` trace) holds the host
+stages on the same timeline as the device ops.  The span stamps and the
+annotations share their boundaries:
+
+- ``accl.driver``, submit -> queue: descriptor, request, hand-off;
+- ``accl.gang``, queue -> gang join: ``TpuEngine.submit``, the join;
+- ``accl.marshal``, dispatch -> launch: plan, operands, accounting;
+- ``accl.launch``, launch -> launched: the compiled program's call;
+- ``accl.finish``, launched -> complete: rebind, completion, observers.
+
+``accl.driver`` and ``accl.gang`` are per call, on the caller's thread;
+the last three are per dispatch on the dispatching thread (one per
+gang, one per batch of gangs), since one program serves them all.
+Stamps with no annotation of their own: gang-ready (last member
+joined) and rebound (inside ``accl.finish``).
 
 Device timelines (r15): the ``ACCL_DEVICE_TRACE`` Pallas ring kernels
 (ops/ring.py) write per-step stamp rows — :data:`DEVICE_TRACE_FIELDS`
@@ -71,8 +92,8 @@ class TraceSpan:
 
     __slots__ = ("name", "desc", "rank", "gang_id", "lane", "tenant",
                  "count", "dtype", "nbytes", "nranks", "t_submit",
-                 "t_queue", "t_gang_ready", "t_dispatch",
-                 "t_device_begin", "t_device_end", "t_complete")
+                 "t_queue", "t_gang_ready", "t_dispatch", "t_launch",
+                 "t_launched", "t_rebound", "t_complete")
 
     def __init__(self, name: str, desc: str = "", rank: int = -1,
                  count: int = 0, dtype: str = "", nbytes: int = 0,
@@ -93,14 +114,18 @@ class TraceSpan:
         self.t_queue: Optional[int] = None
         self.t_gang_ready: Optional[int] = None
         self.t_dispatch: Optional[int] = None
-        self.t_device_begin: Optional[int] = None
-        self.t_device_end: Optional[int] = None
+        #: host clock immediately around the program call; on the chip
+        #: the call returns at enqueue, so this is NOT device time (the
+        #: profiler trace has that)
+        self.t_launch: Optional[int] = None
+        self.t_launched: Optional[int] = None
+        self.t_rebound: Optional[int] = None
         self.t_complete: Optional[int] = None
 
     def timestamps(self) -> dict:
         return {k: getattr(self, "t_" + k) for k in (
-            "submit", "queue", "gang_ready", "dispatch", "device_begin",
-            "device_end", "complete")}
+            "submit", "queue", "gang_ready", "dispatch", "launch",
+            "launched", "rebound", "complete")}
 
     def __repr__(self) -> str:  # pragma: no cover — debug aid
         return (f"TraceSpan({self.name!r}, rank={self.rank}, "
@@ -200,10 +225,12 @@ class TraceCollector:
         are per-rank stage tracks — ``call`` (submit→complete), ``queue``
         (queue-enter→dispatch, with the gang-ready boundary in args),
         and one ``lane:<name>`` track per dispatch lane holding the
-        device-begin→device-end slice.  Gang members carry the same
-        ``gang#<id>`` slice name and (for fused gang programs, whose
-        device window is measured once per gang) identical ts/dur — the
-        aligned cross-rank slice a Perfetto timeline groups visually."""
+        launch→launched slice: the host's call of the program, not
+        device time (on the chip the call returns at enqueue; the
+        device ops are in the profiler trace).  Gang members carry the
+        same ``gang#<id>`` slice name and (for fused gang programs,
+        launched once per gang) identical ts/dur — the aligned
+        cross-rank slice a Perfetto timeline groups visually."""
         events: list = []
         tids: dict = {}
         procs: set = set()
@@ -251,8 +278,9 @@ class TraceCollector:
                      {"gang_ready_ns": s.t_gang_ready})
             if s.lane is not None:
                 slice_ev(pid, f"lane:{s.lane}", s.name + gid,
-                         s.t_device_begin or s.t_dispatch,
-                         s.t_device_end or s.t_complete, args)
+                         s.t_launch or s.t_dispatch,
+                         s.t_launched or s.t_complete,
+                         dict(args, slice="host launch, not device time"))
         # device stamp-buffer tracks (r15): one `device:<collective>`
         # track per rank; each step renders its transfer window
         # (send-issue -> recv/ack-wait done) and its reduce/copy window
@@ -378,6 +406,51 @@ def new_span(name: str, desc: str = "", rank: int = -1, count: int = 0,
 
 
 # ---------------------------------------------------------------------------
+# stage boundaries: span stamps + profiler annotations
+# ---------------------------------------------------------------------------
+#: per thread, the ``accl.*`` annotation :func:`stage` last opened
+_stage_tls = threading.local()
+_annotation_cls = None
+
+
+def _open_annotation(name: str):
+    """A started ``jax.profiler.TraceAnnotation``: a TraceMe starts at
+    construction and records on the thread that stops it."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(name)
+
+
+def stamp(spans, field: str, t: Optional[int] = None) -> None:
+    """Write `field` (``"t_launch"``, ...) on every span of `spans`
+    (None entries skipped) at one clock read, `t` where the caller
+    already read it."""
+    if t is None:
+        t = now_ns()
+    for span in spans:
+        if span is not None:
+            setattr(span, field, t)
+
+
+def stage(name: Optional[str], spans=(), field: Optional[str] = None,
+          t: Optional[int] = None) -> None:
+    """One stage boundary on the calling thread (tracing on): stamp
+    `field` on `spans` as :func:`stamp` does, then close the thread's
+    open ``accl.*`` annotation and open `name` (None: open none).
+    Callers gate on :func:`enabled` or on a request's span, so with
+    tracing off nothing here runs: no clock read, no annotation."""
+    if field is not None:
+        stamp(spans, field, t)
+    ann = getattr(_stage_tls, "ann", None)
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    _stage_tls.ann = _open_annotation(name) if name is not None else None
+
+
+# ---------------------------------------------------------------------------
 # marked windows + XLA profiler integration
 # ---------------------------------------------------------------------------
 @contextlib.contextmanager
@@ -402,8 +475,8 @@ def traced_window(label: str,
             yield
     finally:
         if span is not None:
-            span.t_device_begin = span.t_submit
-            span.t_device_end = span.t_complete = now_ns()
+            span.t_launch = span.t_submit
+            span.t_launched = span.t_complete = now_ns()
             collector().add(span)
 
 
@@ -455,7 +528,7 @@ def salvage_torn_json(text: str, list_key: str) -> tuple:
 def merge_trace_files(paths, out_path: Optional[str] = None) -> dict:
     """Merge per-process trace files (e.g. one per multihost rank) into
     one timeline, aligning clocks by shared gang ids: each file is
-    shifted so the device-begin of the first gang it shares with the
+    shifted so the launch of the first gang it shares with the
     reference file coincides — the cross-rank alignment an in-process
     world gets for free from the shared monotonic clock."""
     merged: list = []
@@ -489,13 +562,13 @@ def merge_trace_files(paths, out_path: Optional[str] = None) -> dict:
             gid = args.get("gang_id")
             if gid is None or ev.get("ph") != "X" or gid in gangs:
                 continue
-            # anchor on the DEVICE window, not the slice ts: the call
-            # slice starts at the rank-local submit time, and shifting
-            # by that would absorb exactly the cross-rank submit skew
-            # the merged timeline exists to reveal — a fused gang's
-            # device-begin is the instant genuinely shared across ranks
-            dev0 = (args.get("timestamps_ns") or {}).get("device_begin")
-            anchor = dev0 / 1e3 if dev0 else ev["ts"]
+            # anchor on the LAUNCH, not the slice ts: the call slice
+            # starts at the rank-local submit time, and shifting by
+            # that would absorb exactly the cross-rank submit skew the
+            # merged timeline exists to reveal — a fused gang's launch
+            # is the instant genuinely shared across ranks
+            t_launch = (args.get("timestamps_ns") or {}).get("launch")
+            anchor = t_launch / 1e3 if t_launch else ev["ts"]
             if anchor > 0:
                 gangs[gid] = anchor
         offset = 0.0

@@ -119,26 +119,27 @@ class Request:
 
     def _observe(self) -> None:
         """Publish this call's completion to the observability layer:
-        callback-complete timestamp on the span (the last event — the
-        result-buffer sync in on_complete has already run), metrics
-        observation keyed by the driver-attached signature.  Observer
-        failures must never lose the completion event."""
-        if self.metric is None and self.trace is None \
-                and self.flight is None:
+        the flight record's finish and the metrics observation (both at
+        one clock read), then the span's complete stamp — the last
+        event before the waiter wakes, so the observers' own cost lies
+        inside the span.  Observer failures must never lose the
+        completion event."""
+        rec, metric, span = self.flight, self.metric, self.trace
+        if metric is None and span is None and rec is None:
             return
         try:
-            t_end = _trace.now_ns()
-            if self.flight is not None:
-                self.flight.finish(self.retcode, t_end)
-            if self.metric is not None:
-                reg, coll, dtype, nbytes, nranks, t0, tenant = self.metric
-                reg.observe_call(coll, dtype, nbytes, t_end - t0, nranks,
-                                 ok=self.retcode == 0,
-                                 engine_ns=self.duration_ns,
-                                 tenant=tenant)
-            span = self.trace
+            if rec is not None or metric is not None:
+                t_end = _trace.now_ns()
+                if rec is not None:
+                    rec.finish(self.retcode, t_end)
+                if metric is not None:
+                    reg, coll, dtype, nbytes, nranks, t0, tenant = metric
+                    reg.observe_call(coll, dtype, nbytes, t_end - t0,
+                                     nranks, ok=self.retcode == 0,
+                                     engine_ns=self.duration_ns,
+                                     tenant=tenant)
             if span is not None:
-                span.t_complete = t_end
+                span.t_complete = _trace.now_ns()
                 _trace.collector().add(span)
         except Exception:  # pragma: no cover — observability is best-effort
             pass
@@ -229,11 +230,13 @@ class RequestQueue:
     def submit(self, request: Request, start_fn: Callable[[Request], None]) -> Request:
         with self._lock:
             request.status = OperationStatus.EXECUTING
-            rec = request.flight
-            if rec is not None:
-                rec.t_queue = _trace.now_ns()
-                rec.state = _flight.S_QUEUED
-            if request.trace is not None:
-                request.trace.t_queue = _trace.now_ns()
+            rec, span = request.flight, request.trace
+            if rec is not None or span is not None:
+                t = _trace.now_ns()
+                if rec is not None:
+                    rec.t_queue = t
+                    rec.state = _flight.S_QUEUED
+                if span is not None:  # hand-off: accl.driver ends here
+                    _trace.stage(None, (span,), "t_queue", t)
             start_fn(request)
         return request

@@ -59,11 +59,11 @@ def test_span_ordering_invariants(tracing):
         ts = s.timestamps()
         # every stage stamped on the gang path
         for k in ("submit", "queue", "gang_ready", "dispatch",
-                  "device_begin", "device_end", "complete"):
+                  "launch", "launched", "rebound", "complete"):
             assert ts[k] is not None, f"stage {k} missing on {s!r}"
         assert s.t_submit <= s.t_queue <= s.t_gang_ready
-        assert s.t_gang_ready <= s.t_dispatch <= s.t_device_begin
-        assert s.t_device_begin <= s.t_device_end <= s.t_complete
+        assert s.t_gang_ready <= s.t_dispatch <= s.t_launch
+        assert s.t_launch <= s.t_launched <= s.t_rebound <= s.t_complete
         assert s.lane in ("leader", "executor", "batched")
         assert s.dtype == "float32"
         assert s.nbytes == COUNT * 4
@@ -81,16 +81,18 @@ def test_multi_rank_gang_id_merge(tracing):
     for gid, members in by_gang.items():
         assert gid is not None
         assert sorted(m.rank for m in members) == list(range(NRANKS))
-        # a fused gang program has ONE device window, so member slices
-        # are exactly aligned
-        assert len({(m.t_device_begin, m.t_device_end)
-                    for m in members}) == 1
+        # a fused gang program has ONE launch, so member slices are
+        # exactly aligned
+        assert len({(m.t_launch, m.t_launched) for m in members}) == 1
 
 
-def test_disabled_mode_zero_allocation(tracing):
+def test_disabled_mode_zero_allocation(tracing, monkeypatch):
     # flip OFF after the fixture armed a fresh collector: the driver
-    # and backends must not allocate spans nor touch the ring buffer
+    # and backends must not allocate spans nor touch the ring buffer,
+    # nor open a stage annotation
     obs_trace.disable()
+    opened = []
+    monkeypatch.setattr(obs_trace, "_open_annotation", opened.append)
     with _tpu_world() as w:
         def fn(accl, rank):
             s = accl.create_buffer_like(
@@ -103,6 +105,7 @@ def test_disabled_mode_zero_allocation(tracing):
         w.run(fn)
     assert obs_trace.new_span("x") is None
     assert len(tracing) == 0
+    assert opened == []
 
 
 # ---------------------------------------------------------------------------
@@ -151,8 +154,8 @@ def test_emu_backend_spans_and_merge(tracing):
     for s in spans:
         assert s.lane == "emu"
         assert s.t_submit <= s.t_queue <= s.t_dispatch
-        assert s.t_dispatch <= s.t_device_begin <= s.t_device_end
-        assert s.t_device_end <= s.t_complete
+        assert s.t_dispatch <= s.t_launch <= s.t_launched
+        assert s.t_launched <= s.t_complete
 
 
 def test_traced_window_and_merge_files(tracing, tmp_path):
