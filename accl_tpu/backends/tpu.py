@@ -68,6 +68,26 @@ def _import_jax():
     return jax, jnp, Mesh, NamedSharding, PartitionSpec
 
 
+def _global_constructor(nelems: int, dtype, sharding) -> Callable:
+    """A constructor of the 1-D global array of `nelems` elements from
+    its single-device shards, given as a list in `sharding`'s device
+    order.  The aval and sharding are fixed here, once, and a call skips
+    the per-call validation and rearrangement that
+    ``jax.make_array_from_single_device_arrays`` repeats: sound only for
+    shards that are whole, of the global's dtype and committed to their
+    devices — what a plan's all-fast operands are (jax 0.9 ``ArrayImpl``;
+    a missing or deleted shard still raises)."""
+    from functools import partial
+
+    from jax._src import core
+    from jax._src.array import ArrayImpl
+
+    aval = core.update_aval_with_sharding(
+        core.ShapedArray((nelems,), np.dtype(dtype)), sharding)
+    return partial(ArrayImpl, aval, sharding, committed=True,
+                   _skip_checks=True)
+
+
 class TpuBuffer(BaseBuffer):
     """Host numpy array paired with a single-device jax.Array resident on
     this rank's device (the FPGABuffer analog: host map + device BO)."""
@@ -311,10 +331,13 @@ class TpuEngine:
         #: fast-path tests read these through the `stats` view).  Each
         #: key has a single writer context — leader_dispatches under the
         #: serialized inline lane, the rest on the executor thread.
+        #: ``assemble_checked`` counts executions whose operands took the
+        #: checked assembly (_assemble_global); the prepared share is
+        #: 1 - assemble_checked / (sum of the lane counters).
         self.metrics = _metrics.MetricsRegistry()
         for k in ("leader_dispatches", "executor_dispatches", "batches",
                   "batched_gangs", "plan_replays", "plan_auto_captures",
-                  *_LANE_COUNTERS):
+                  *_LANE_COUNTERS, "assemble_checked"):
             self.metrics.inc(k, 0)
         self._log = get_logger("accl_tpu.tpu")
         # per-link wire telemetry twin (r15): (src rank, comm, peer
@@ -798,9 +821,34 @@ class TpuEngine:
             return (1.0 + 4.0 / max(block, 1)) / 4.0
         return 0.5  # float16 / bfloat16
 
-    def _account_gang_links(self, op, comm_id: int, gang: dict,
-                            nbytes: int, wire_dtype: str = "") -> None:
-        """Fold one dispatched gang into the link twin.
+    def _account_links(self, plan: dict) -> None:
+        """Fold one execution of `plan` into the link twin and the
+        compressed_tx_* counters: its precomputed deltas, under one
+        lock acquisition."""
+        links = plan["links"]
+        if links is None:
+            return
+        rows, comp = links
+        if comp is not None:
+            self.metrics.inc("compressed_tx_logical_bytes", comp[0])
+            self.metrics.inc("compressed_tx_bytes", comp[1])
+        with self._link_lock:
+            table = self._links
+            for key, counts in rows:
+                row = table.get(key)
+                if row is None:
+                    row = table[key] = {}
+                for k, v in counts:
+                    row[k] = row.get(k, 0) + v
+
+    def _link_deltas(self, op, comm_id: int, members: list,
+                     root_local: int, nbytes: int,
+                     wire_dtype: str = "") -> Optional[tuple]:
+        """What one dispatched gang adds to the link twin: ((rows,
+        compressed), with rows ((src, comm, peer), ((field, count),
+        ...)) and compressed (logical bytes, wire bytes) or None), or
+        None when there is nothing to add.  It depends only on the
+        gang's signature, so a plan computes it once.
 
         Ring collectives move ``busbw_factor × nbytes`` per rank to its
         right ring neighbor over P-1 (allgather/reduce_scatter) or
@@ -811,12 +859,12 @@ class TpuEngine:
         compressed ``wire_dtype`` the same logical traffic is also
         accounted at its compressed wire width (comp_tx_bytes per link,
         compressed_tx_* engine counters — the r17 bytes-saved plane)."""
-        members = self._comms.get(comm_id, [])
         P = len(members)
         if P < 2 or nbytes <= 0:
-            return
+            return None
         name = Operation(op).name
         ratio = self._wire_ratio(wire_dtype)
+        comp_counts = None
         if ratio < 1.0:
             # nbytes is in_len * itemsize, which ALREADY carries the P
             # factor for the n*P-operand collectives — divide it back
@@ -826,8 +874,14 @@ class TpuEngine:
                 P if name in ("scatter", "reduce_scatter", "alltoall")
                 else 1)
             logical = int(per_count * _metrics.payload_factor(name, P))
-            self.metrics.inc("compressed_tx_logical_bytes", logical)
-            self.metrics.inc("compressed_tx_bytes", int(logical * ratio))
+            comp_counts = (logical, int(logical * ratio))
+        rows: dict = {}
+
+        def add(src: int, peer: int, **counts) -> None:
+            row = rows.setdefault((src, comm_id, peer), {})
+            for k, v in counts.items():
+                row[k] = row.get(k, 0) + int(v)
+
         if name in ("allreduce", "allgather", "reduce_scatter",
                     "alltoall"):
             # nbytes is the per-rank operand (plan in_len); the busbw
@@ -842,12 +896,10 @@ class TpuEngine:
             for i, src in enumerate(members):
                 right = members[(i + 1) % P]
                 left = members[(i - 1) % P]
-                self._link_add(src, comm_id, right, tx_msgs=hops,
-                               tx_bytes=per_link, comp_tx_bytes=comp)
-                self._link_add(src, comm_id, left, rx_msgs=hops,
-                               rx_bytes=per_link)
+                add(src, right, tx_msgs=hops, tx_bytes=per_link,
+                    comp_tx_bytes=comp)
+                add(src, left, rx_msgs=hops, rx_bytes=per_link)
         elif name in ("bcast", "scatter", "gather", "reduce"):
-            root_local = next(iter(gang.values()))[0].root_src_dst
             root = members[root_local] if root_local < P else members[0]
             to_root = name in ("gather", "reduce")
             # scatter's operand is the root's WHOLE input (in_len =
@@ -858,10 +910,10 @@ class TpuEngine:
                 if m == root:
                     continue
                 a, b = (m, root) if to_root else (root, m)
-                self._link_add(a, comm_id, b, tx_msgs=1,
-                               tx_bytes=per_link, comp_tx_bytes=comp)
-                self._link_add(b, comm_id, a, rx_msgs=1,
-                               rx_bytes=per_link)
+                add(a, b, tx_msgs=1, tx_bytes=per_link, comp_tx_bytes=comp)
+                add(b, a, rx_msgs=1, rx_bytes=per_link)
+        return (tuple((key, tuple(row.items()))
+                      for key, row in rows.items()), comp_counts)
 
     def _account_gang_wait(self, comm_id: int, gang: dict,
                            t_ready: int) -> None:
@@ -1356,10 +1408,7 @@ class TpuEngine:
             # the matrix would report near-zero for exactly the lane
             # that matters (no gang-wait here: a replay rendezvouses
             # on the ring sequence, not per-member arrival)
-            self._account_gang_links(
-                slot["op"], slot["comm"], slot["gang"],
-                plan["in_len"] * np.dtype(plan["dtype"]).itemsize,
-                wire_dtype=plan["fn_args"][6])
+            self._account_links(plan)
             self.metrics.inc(plan["lane"])
             y = plan["compiled"](x)
             self._scatter_back(plan, y)
@@ -1650,11 +1699,8 @@ class TpuEngine:
                 _trace.stage("accl.marshal", spans, "t_dispatch", t)
             xs = [self._assemble_global(plan, gang)
                   for _op, _c, gang, plan in items]
-            for op_, c_, gang_, plan_ in items:
-                self._account_gang_links(
-                    op_, c_, gang_,
-                    plan_["in_len"] * np.dtype(plan_["dtype"]).itemsize,
-                    wire_dtype=plan_["fn_args"][6])
+            for _op, _c, _gang, plan_ in items:
+                self._account_links(plan_)
                 self.metrics.inc(plan_["lane"])
             fnb = _collective_fn(*items[0][3]["fn_args"],
                                  nbatch=len(items))
@@ -1707,18 +1753,22 @@ class TpuEngine:
         to a handful of register writes, fpgadevice.cpp:46-180).
         Safe to cache: the address->buffer registry only grows, buffer
         dev dtype/shape never change, and the compiled fn is keyed on
-        everything that shapes the program."""
-        jax, jnp, Mesh, NamedSharding, P = _import_jax()
+        everything that shapes the program.
+
+        A hit is one signature tuple and one locked lookup: whatever
+        depends only on the signature (the prepared operand assembly,
+        the link-twin deltas) was computed when the plan was built."""
         members = self._comms[comm_id]
         # ring_threshold_bytes is a runtime knob (tests force the ring
         # path by setting it to 0): it shapes the compiled program, so
         # it must be part of the signature or a threshold change would
-        # silently keep serving the previously-compiled lowering
-        sig = (int(op), comm_id, self.ring_threshold_bytes, tuple(
-            (g, c.addr_0, c.addr_2, c.count, c.root_src_dst, c.function,
+        # silently keep serving the previously-compiled lowering.  The
+        # comm id fixes the member order, so members need no slot.
+        sig = (int(op), comm_id, self.ring_threshold_bytes, tuple([
+            (c.addr_0, c.addr_2, c.count, c.root_src_dst, c.function,
              c.compression_flags, c.arithcfg, c.stream_flags, c.tag,
              c.fused)
-            for g, c in ((m, gang[m][0]) for m in members)))
+            for c in [gang[m][0] for m in members]]))
         # _gang_plan runs only on the dispatching context — the
         # executor thread or (leader-dispatch lane) the one inline
         # leader, never both at once — so the lock is effectively
@@ -1731,7 +1781,18 @@ class TpuEngine:
             if plan is not None:
                 self._gang_plans.move_to_end(sig)
                 return plan
+        plan = self._build_plan(op, comm_id, gang, members)
+        with self._lock:
+            self._gang_plans[sig] = plan
+            self._gang_plans.move_to_end(sig)
+            while len(self._gang_plans) > self._gang_plans_cap:
+                self._gang_plans.popitem(last=False)
+        return plan
 
+    def _build_plan(self, op: Operation, comm_id: int, gang: dict,
+                    members: list) -> dict:
+        """A gang signature's execution plan (see _gang_plan)."""
+        _jax, _jnp, _Mesh, NamedSharding, P = _import_jax()
         nranks = len(members)
         mesh = self._mesh_for(tuple(members))
         any_call = next(iter(gang.values()))[0]
@@ -1797,6 +1858,12 @@ class TpuEngine:
                 buf, off, fast = None, 0, False
             else:
                 buf, off = self.resolve(g, call.addr_0)
+                if buf is None and call.addr_0:
+                    # an address that names no live buffer (freed):
+                    # never substitute the result buffer for it
+                    raise ACCLError(
+                        f"rank {g}: operand address {call.addr_0:#x} "
+                        f"names no live buffer (freed?)")
                 if buf is None:
                     buf, off = self.resolve(g, call.addr_2)
                 fast = (buf is not None and off == 0
@@ -1805,6 +1872,10 @@ class TpuEngine:
             write_out = not (op in (Operation.reduce, Operation.gather)
                              and li != root)
             res, roff = self.resolve(g, call.addr_2)
+            if res is None and call.addr_2 and not res_stream:
+                raise ACCLError(
+                    f"rank {g}: result address {call.addr_2:#x} names "
+                    f"no live buffer (freed?)")
             res_tag = call.tag if (res_stream and write_out) else None
             ops.append((g, buf, off, fast,
                         res if (write_out and not res_stream) else None,
@@ -1831,14 +1902,32 @@ class TpuEngine:
                    str(np.dtype(dtype)), ring, fused)
         compiled = (None if op == Operation.barrier
                     else _collective_fn(*fn_args))
+        sharding = NamedSharding(mesh, P("rank"))
+        prepared = None
+        if all(o[3] for o in ops):
+            # every operand is a whole, same-dtype, resident buffer: a
+            # call assembles the global from the buffers' current arrays,
+            # bound here in the sharding's device order
+            by_dev = {self.devices[o[0]]: o[1] for o in ops}
+            prepared = (
+                _global_constructor(nranks * in_len, dtype, sharding),
+                tuple(by_dev[d]
+                      for d in sharding._addressable_device_assignment))
         plan = {
             "members": members,
             "nranks": nranks,
             "in_len": in_len,
             "dtype": dtype,
-            "sharding": NamedSharding(mesh, P("rank")),
+            "sharding": sharding,
             "compiled": compiled,
             "ops": ops,
+            # (global constructor, operand buffers in shard order), or None:
+            # some operand needs the checked assembly (_assemble_global)
+            "prepared": prepared,
+            # this signature's link-twin deltas (_account_links)
+            "links": self._link_deltas(
+                op, comm_id, members, root,
+                in_len * np.dtype(dtype).itemsize, wire_dtype),
             # batching metadata: gangs with the same fn_args can share
             # one dispatch; the address sets drive the RAW guard (a
             # candidate whose operands intersect an earlier batch
@@ -1860,11 +1949,6 @@ class TpuEngine:
                 (g, r.address) for g, _b, _o, _f, r, _ro, _os, _rt in ops
                 if r is not None),
         }
-        with self._lock:
-            self._gang_plans[sig] = plan
-            self._gang_plans.move_to_end(sig)
-            while len(self._gang_plans) > self._gang_plans_cap:
-                self._gang_plans.popitem(last=False)
         return plan
 
     def _run_collective(self, op: Operation, comm_id: int,
@@ -1880,24 +1964,19 @@ class TpuEngine:
         Hot path: the plan cache resolves everything per SIGNATURE, the
         global array is 1-D with each member's whole buffer as its
         shard, and full-length results rebind buffers — a repeated call
-        costs one make_array + one compiled dispatch, no per-member jax
-        ops.
+        costs one prepared global + one compiled dispatch, no per-member
+        jax ops.
 
         Returns the duration in ns.  Traced, it stamps the member spans'
         launch, launched and rebound, and moves the dispatching
         thread's stage from accl.marshal to accl.launch to accl.finish
         (a barrier has no program: its spans keep those stamps None)."""
-        jax, jnp, Mesh, NamedSharding, P = _import_jax()
-
         if op == Operation.barrier:
             return 0  # gang completion IS the synchronization
 
         plan = self._gang_plan(op, comm_id, gang)
         x = self._assemble_global(plan, gang)
-        self._account_gang_links(
-            op, comm_id, gang,
-            plan["in_len"] * np.dtype(plan["dtype"]).itemsize,
-            wire_dtype=plan["fn_args"][6])
+        self._account_links(plan)
         self.metrics.inc(plan["lane"])
 
         spans = _spans(gang) if _trace.enabled() else None
@@ -1908,6 +1987,8 @@ class TpuEngine:
         if self.profile_sync:
             # exact perf-counter mode: duration is on-device time and
             # async errors surface here (see __init__)
+            import jax
+
             jax.block_until_ready(y)
         t1 = _trace.now_ns()
         if spans is not None:
@@ -1919,7 +2000,17 @@ class TpuEngine:
         return t1 - t0
 
     def _assemble_global(self, plan: dict, gang: dict):
-        jax, jnp, Mesh, NamedSharding, P = _import_jax()
+        """The gang's operands as one global array sharded over the
+        members.  A prepared plan builds it from the bound buffers'
+        current arrays; any other plan takes the checked path (slices,
+        dtype widening, stream operands, padded placeholders), counted
+        under ``assemble_checked``."""
+        prepared = plan["prepared"]
+        if prepared is not None:
+            build, bufs = prepared
+            return build([b._dev for b in bufs])
+        self.metrics.inc("assemble_checked")
+        jax, jnp, _Mesh, _NamedSharding, _P = _import_jax()
         in_len = plan["in_len"]
         dtype = plan["dtype"]
 
@@ -1943,27 +2034,8 @@ class TpuEngine:
                 pad = jnp.zeros((in_len - shard.shape[0],), shard.dtype)
                 shard = jnp.concatenate([shard, pad])
             shards.append(jax.device_put(shard, self.devices[g]))
-
-        # assembled-global cache: when every shard is the IDENTICAL
-        # array object as the previous call (the steady state of a
-        # training loop — all-fast-path operands, none rebound since),
-        # the previous global is still an exact alias of them, so the
-        # per-call make_array disappears.  Sound because jax arrays are
-        # immutable: any buffer update rebinds to a NEW object and
-        # misses this check.  The cache holds strong refs, so object
-        # identity cannot be recycled out from under it.
-        cached = plan.get("assembled")
-        if (cached is not None and len(cached[0]) == len(shards)
-                and all(a is b for a, b in zip(cached[0], shards))):
-            return cached[1]
-        x = jax.make_array_from_single_device_arrays(
+        return jax.make_array_from_single_device_arrays(
             (plan["nranks"] * in_len,), plan["sharding"], shards)
-        # only all-fast-path gangs can ever hit (slow-path members
-        # create fresh arrays per call), so storing anything else
-        # would just pin dead device copies between calls
-        if all(o[3] for o in plan["ops"]):
-            plan["assembled"] = (shards, x)
-        return x
 
     def _scatter_back(self, plan: dict, y) -> None:
         # scatter result shards back into per-rank result buffers without

@@ -216,6 +216,10 @@ METRIC_HELP = {
                                  "through a compressed lane (r17)"),
     "accl_compressed_tx_logical_bytes": (
         "uncompressed-equivalent bytes of the compressed gang traffic"),
+    "accl_assemble_checked": (
+        "gang executions whose operands took the checked assembly "
+        "(slice, widening, stream or padded operand); the rest were "
+        "assembled from the plan's prepared aval and sharding"),
 }
 
 #: HELP for families minted with dynamic name parts (bench lane labels,
