@@ -1332,6 +1332,62 @@ class ACCL:
                              sync_out=[] if to_fpga else [(recvbuf, count * comm.size)],
                              run_async=run_async, desc="alltoall")
 
+    def alltoallv(
+        self,
+        sendbuf: BaseBuffer,
+        recvbuf: BaseBuffer,
+        sendcounts: BaseBuffer,
+        recvcounts: BaseBuffer,
+        row: int = 1,
+        comm_id: int = GLOBAL_COMM,
+        from_fpga: bool = False,
+        to_fpga: bool = False,
+        run_async: bool = False,
+    ):
+        """All-to-all with per-peer counts held on the device.
+
+        ``sendcounts`` is an int32 buffer of P counts, in rows of `row`
+        elements: ``sendcounts[j]`` rows go to comm rank j, and the send
+        layout is packed in rank order.  The call fills the int32
+        buffer ``recvcounts`` with the rows received from each rank and
+        packs them into ``recvbuf`` in source order; what the rows past
+        their sum hold is unspecified (the TPU engine writes zeros, the
+        emulator leaves them).  The counts never reach the host during a
+        call, so the router that made them on the device is not
+        stalled.
+
+        ``recvbuf``'s length is the capacity: the caller sizes it for
+        the worst case (every rank's rows sent here).  Nothing checks
+        at run time that the received rows fit, nor that the sent rows
+        fit ``sendbuf``: the sums must fit.  Same-rank rows move
+        locally; zero counts and an all-to-one routing are legal.  On
+        the TPU engine every member's send (and receive) buffer has one
+        length.
+
+        The descriptor's count is `row`; the count operand rides in the
+        OP1 slot and the count result's address is the descriptor's
+        ``addr_3`` (``CCLOCall.addr_3_words``)."""
+        comm = self.communicator(comm_id)
+        P = comm.size
+        for name, b in (("sendcounts", sendcounts),
+                        ("recvcounts", recvcounts)):
+            if b.data_type != DataType.int32 or b.length != P:
+                raise ACCLError(f"alltoallv: {name} must be an int32 "
+                                f"buffer of {P} counts")
+        if row < 1 or sendbuf.length % row or recvbuf.length % row:
+            raise ACCLError(f"alltoallv: buffer lengths must be whole "
+                            f"rows of {row} elements")
+        call = self._build(Operation.alltoallv, row, comm_id,
+                           **CCLOCall.addr_3_words(recvcounts.address),
+                           op0=sendbuf, res=recvbuf, counts=sendcounts)
+        return self._execute(
+            call,
+            sync_in=[] if from_fpga else [(sendbuf, sendbuf.length),
+                                          (sendcounts, P)],
+            sync_out=[] if to_fpga else [(recvbuf, recvbuf.length),
+                                         (recvcounts, P)],
+            run_async=run_async, desc="alltoallv")
+
     def barrier(self, comm_id: int = GLOBAL_COMM, run_async: bool = False):
         """Barrier over the communicator (reference: accl.cpp:947)."""
         call = self._build(Operation.barrier, 0, comm_id)
@@ -1362,6 +1418,7 @@ class ACCL:
         op0_dtype: Optional[DataType] = None,
         res_dtype: Optional[DataType] = None,
         fused: Optional[bool] = None,
+        counts: Optional[BaseBuffer] = None,
     ) -> CCLOCall:
         """Build a call descriptor: select the arithmetic config from the
         (uncompressed, compressed) dtype pair, derive per-operand and
@@ -1414,7 +1471,7 @@ class ACCL:
         memo_key = (scenario, count, comm_id, root_src_dst, function, tag,
                     _bkey(op0), _bkey(op1), _bkey(res),
                     stream_flags, compress_dtype, op0_dtype, res_dtype,
-                    fused)
+                    fused, _bkey(counts))
         cached = self._call_memo.get(memo_key)
         if cached is not None:
             self._call_memo.move_to_end(memo_key)
@@ -1552,7 +1609,9 @@ class ACCL:
             stream_flags=stream_flags,
             host_flags=host_flags,
             addr_0=op0.address,
-            addr_1=op1.address,
+            # alltoallv's count operand: in the OP1 slot, outside the
+            # dtype algebra (int32 counts beside a bf16 payload)
+            addr_1=op1.address if counts is None else counts.address,
             addr_2=res.address,
             fused=fused,
         )

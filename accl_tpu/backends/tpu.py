@@ -408,7 +408,8 @@ class TpuEngine:
             self._buffers[rank].pop(buf.address, None)
             for sig in [sig for sig, plan in self._gang_plans.items()
                         if any(o[0] == rank and (o[1] is buf or o[4] is buf)
-                               for o in plan["ops"])]:
+                               for o in plan["ops"]
+                               + (plan.get("count_ops") or []))]:
                 del self._gang_plans[sig]
 
     def resolve(self, rank: int, addr: int):
@@ -860,9 +861,19 @@ class TpuEngine:
         accounted at its compressed wire width (comp_tx_bytes per link,
         compressed_tx_* engine counters — the r17 bytes-saved plane)."""
         P = len(members)
+        name = Operation(op).name
+        if name == "alltoallv" and P > 1:
+            # the counts live on the device: the host records one
+            # message per link and no bytes (the caller tallies them)
+            rows = []
+            for src in members:
+                for peer in members:
+                    if peer != src:
+                        rows.append(((src, comm_id, peer), (("tx_msgs", 1),)))
+                        rows.append(((peer, comm_id, src), (("rx_msgs", 1),)))
+            return tuple(rows), None
         if P < 2 or nbytes <= 0:
             return None
-        name = Operation(op).name
         ratio = self._wire_ratio(wire_dtype)
         comp_counts = None
         if ratio < 1.0:
@@ -1610,7 +1621,7 @@ class TpuEngine:
         race-free.  Returns a list of (op, comm, gang, plan) when a
         batch of >= 2 formed, else None."""
         op = Operation(scenario)
-        if op in (Operation.barrier,):
+        if op in (Operation.barrier, Operation.alltoallv):
             return None
         if self.profile_sync:
             # exact perf-counter mode: every gang dispatches alone so
@@ -1637,7 +1648,7 @@ class TpuEngine:
                     break
                 nscen, ncomm, ngang = self._ready[0]
             nop = Operation(nscen)
-            if nop in (Operation.barrier,):
+            if nop in (Operation.barrier, Operation.alltoallv):
                 break
             try:
                 nplan = self._gang_plan(nop, ncomm, ngang)
@@ -1765,7 +1776,8 @@ class TpuEngine:
         # silently keep serving the previously-compiled lowering.  The
         # comm id fixes the member order, so members need no slot.
         sig = (int(op), comm_id, self.ring_threshold_bytes, tuple([
-            (c.addr_0, c.addr_2, c.count, c.root_src_dst, c.function,
+            (c.addr_0, c.addr_1, c.addr_2, c.count, c.root_src_dst,
+             c.function,
              c.compression_flags, c.arithcfg, c.stream_flags, c.tag,
              c.fused)
             for c in [gang[m][0] for m in members]]))
@@ -1792,6 +1804,8 @@ class TpuEngine:
     def _build_plan(self, op: Operation, comm_id: int, gang: dict,
                     members: list) -> dict:
         """A gang signature's execution plan (see _gang_plan)."""
+        if op == Operation.alltoallv:
+            return self._build_alltoallv_plan(comm_id, gang, members)
         _jax, _jnp, _Mesh, NamedSharding, P = _import_jax()
         nranks = len(members)
         mesh = self._mesh_for(tuple(members))
@@ -1916,6 +1930,8 @@ class TpuEngine:
         plan = {
             "members": members,
             "nranks": nranks,
+            # a second operand and result (alltoallv's counts), or None
+            "count_ops": None,
             "in_len": in_len,
             "dtype": dtype,
             "sharding": sharding,
@@ -1950,6 +1966,81 @@ class TpuEngine:
                 if r is not None),
         }
         return plan
+
+    def _build_alltoallv_plan(self, comm_id: int, gang: dict,
+                              members: list) -> dict:
+        """An alltoallv gang's plan: two operands (the payload and the
+        int32 send counts) and two results (the payload and the receive
+        counts), each a global sharded over the members.  Every buffer
+        must be whole and resident, so the plan is always prepared."""
+        _jax, _jnp, _Mesh, NamedSharding, P = _import_jax()
+        nranks = len(members)
+        mesh = self._mesh_for(tuple(members))
+        row = next(iter(gang.values()))[0].count
+        ops, count_ops, shapes = [], [], set()
+        for g in members:
+            call = gang[g][0]
+            if call.stream_flags or call.compression_flags:
+                raise ACCLError("alltoallv takes no stream operand and no "
+                                "wire compression")
+            bufs = []
+            for what, addr in (
+                    ("send", call.addr_0), ("send count", call.addr_1),
+                    ("receive", call.addr_2),
+                    ("receive count", call.addr_3)):
+                b, off = self.resolve(g, addr)
+                if b is None or off:
+                    raise ACCLError(f"rank {g}: alltoallv {what} address "
+                                    f"{addr:#x} names no whole live buffer")
+                bufs.append(b)
+            send, scount, recv, rcount = bufs
+            for b in (scount, rcount):
+                if b.dev.dtype != np.int32 or b.dev.shape[0] != nranks:
+                    raise ACCLError(f"rank {g}: alltoallv counts must be "
+                                    f"int32 buffers of {nranks}")
+            shapes.add((send.dev.shape[0], recv.dev.shape[0],
+                        str(send.dev.dtype), str(recv.dev.dtype)))
+            ops.append((g, send, 0, True, recv, 0, False, None))
+            count_ops.append((g, scount, 0, True, rcount, 0, False, None))
+        if len(shapes) != 1:
+            raise ACCLError(f"alltoallv: members' buffers differ "
+                            f"(send, receive lengths and dtypes): {shapes}")
+        send_len, recv_len, dtype, rdtype = shapes.pop()
+        if dtype != rdtype or send_len % row or recv_len % row:
+            raise ACCLError(f"alltoallv: send and receive must share a "
+                            f"dtype and hold whole rows of {row}")
+        sharding = NamedSharding(mesh, P("rank"))
+        order = sharding._addressable_device_assignment
+        by_dev = {self.devices[o[0]]: o[1] for o in ops}
+        counts_by_dev = {self.devices[o[0]]: o[1] for o in count_ops}
+        build_x = _global_constructor(nranks * send_len, dtype, sharding)
+        build_c = _global_constructor(nranks * nranks, np.int32, sharding)
+
+        def build(devs):
+            return build_x(devs[:nranks]), build_c(devs[nranks:])
+
+        fn_args = (mesh, nranks, send_len // row, recv_len // row, row,
+                   dtype)
+        return {
+            "members": members,
+            "nranks": nranks,
+            "count_ops": count_ops,
+            "in_len": send_len,
+            "dtype": np.dtype(dtype),
+            "sharding": sharding,
+            "compiled": _alltoallv_fn(*fn_args),
+            "ops": ops,
+            "prepared": (build, tuple(by_dev[d] for d in order)
+                         + tuple(counts_by_dev[d] for d in order)),
+            "links": self._link_deltas(Operation.alltoallv, comm_id,
+                                       members, 0, 0),
+            "fn_args": fn_args,
+            "lane": "lane_hlo",
+            "opnd_addrs": frozenset((o[0], o[1].address)
+                                    for o in ops + count_ops),
+            "res_addrs": frozenset((o[0], o[4].address)
+                                   for o in ops + count_ops),
+        }
 
     def _run_collective(self, op: Operation, comm_id: int,
                         gang: dict) -> tuple:
@@ -2045,12 +2136,19 @@ class TpuEngine:
         # resolved once per plan and later calls zip straight through
         # (the dict build + Device hashing was a measured slice of the
         # per-call budget at call rate).
+        count_ops = plan["count_ops"]
+        if count_ops is not None:   # alltoallv: (payload, counts)
+            y, counts = y
         shard_list = y.addressable_shards
         order = plan.get("shard_order")
         if order is None:
             order = tuple(self._dev_to_rank[s.device] for s in shard_list)
             plan["shard_order"] = order
         out_shards = dict(zip(order, (s.data for s in shard_list)))
+        if count_ops is not None:   # same sharding: same shard order
+            got = dict(zip(order, (s.data for s in counts.addressable_shards)))
+            for g, _b, _o, _f, res, _ro, _os, _rt in count_ops:
+                res._dev = got[g]
         for g, _buf, _off, _fast, res, roff, _op0s, res_tag in plan["ops"]:
             if res_tag is not None:
                 # RES_STREAM: the member's result lands in its local
@@ -2379,6 +2477,70 @@ def _collective_fn(mesh, op: Operation, nranks: int, in_len: int, root: int,
         return tuple(fn(v) for v in vs)
 
     return jax.jit(batched).lower(*([arg] * nbatch)).compile()
+
+
+def _ragged_exchange_supported(mesh) -> bool:
+    """Whether the mesh's compiler lowers ``ragged-all-to-all``: XLA:CPU
+    refuses it ("not supported by XLA:CPU ThunkEmitter")."""
+    return mesh.devices.flat[0].platform != "cpu"
+
+
+@lru_cache(maxsize=64)
+def _alltoallv_fn(mesh, nranks: int, send_rows: int, recv_rows: int,
+                  row: int, dtype: str) -> Callable:
+    """Build + AOT-compile one alltoallv program: the members' P x P
+    count matrix is gathered on the device, the send and receive
+    offsets derived from it, and the rows moved by
+    ``ragged_all_to_all`` on ``[rows, row]`` views.  Where the compiler
+    lacks that op, the same program exchanges ``[P, send_rows, row]``
+    blocks with ``all_to_all`` and packs them (both forms write zeros
+    past the received total).  The compiled callable takes and
+    returns a (payload, counts) pair of globals."""
+    import jax
+    import jax.numpy as jnp
+    from jax import shard_map
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    ragged = _ragged_exchange_supported(mesh)
+
+    def body(x, c):
+        xv = x.reshape(send_rows, row)
+        m = jax.lax.all_gather(c, "rank")     # m[i, j]: rows i -> j
+        r = jax.lax.axis_index("rank")
+        send, recv = m[r], m[:, r]
+        in_off = jnp.cumsum(send) - send
+        if ragged:
+            # where my rows land on receiver j: after the rows that the
+            # ranks before me send it
+            before = jnp.arange(nranks)[:, None] < r
+            out_off = jnp.sum(jnp.where(before, m, 0), axis=0)
+            out = jnp.zeros((recv_rows, row), x.dtype)
+            y = jax.lax.ragged_all_to_all(xv, out, in_off, send, out_off,
+                                          recv, axis_name="rank")
+            return y.reshape(-1), recv
+        k = jnp.arange(send_rows)
+        padded = jnp.concatenate([xv, jnp.zeros_like(xv)])
+        blocks = jnp.stack([
+            jnp.where((k < send[j])[:, None],
+                      jax.lax.dynamic_slice_in_dim(padded, in_off[j],
+                                                   send_rows), 0)
+            for j in range(nranks)])
+        got = jax.lax.all_to_all(blocks, "rank", 0, 0, tiled=False)
+        ends = jnp.cumsum(recv)
+        t = jnp.arange(recv_rows)
+        src = jnp.minimum(jnp.searchsorted(ends, t, side="right"),
+                          nranks - 1)
+        pos = jnp.clip(t - (ends - recv)[src], 0, send_rows - 1)
+        y = jnp.where((t < ends[-1])[:, None], got[src, pos], 0)
+        return y.reshape(-1), recv
+
+    fn = shard_map(body, mesh=mesh, in_specs=(P("rank"), P("rank")),
+                   out_specs=(P("rank"), P("rank")), check_vma=False)
+    sh = NamedSharding(mesh, P("rank"))
+    args = (jax.ShapeDtypeStruct((nranks * send_rows * row,),
+                                 np.dtype(dtype), sharding=sh),
+            jax.ShapeDtypeStruct((nranks * nranks,), np.int32, sharding=sh))
+    return jax.jit(lambda xc: fn(*xc)).lower(args).compile()
 
 
 class TpuDeviceView(CCLODevice):

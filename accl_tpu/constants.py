@@ -35,6 +35,9 @@ class Operation(enum.IntEnum):
     reduce_scatter = 11
     barrier = 12
     alltoall = 13
+    #: all-to-all with per-peer row counts held on the device (not in the
+    #: reference's enum: ACCL has no alltoallv)
+    alltoallv = 14
     nop = 255
 
 
@@ -45,7 +48,8 @@ class Operation(enum.IntEnum):
 GANG_OPERATIONS = frozenset((
     Operation.bcast, Operation.scatter, Operation.gather,
     Operation.allgather, Operation.reduce, Operation.allreduce,
-    Operation.reduce_scatter, Operation.alltoall, Operation.barrier,
+    Operation.reduce_scatter, Operation.alltoall, Operation.alltoallv,
+    Operation.barrier,
 ))
 
 
@@ -322,6 +326,18 @@ class CCLOCall:
     #: decision).  Riding on the call object keeps it visible to plan
     #: capture/replay and the gang scheduler without widening to_words.
     fused: bool = False
+
+    @staticmethod
+    def addr_3_words(addr: int) -> dict:
+        """The root and function words that carry a fourth address,
+        alltoallv's count result (an all-to-all has no root and no
+        function): low half, high half."""
+        return {"root_src_dst": addr & 0xFFFFFFFF, "function": addr >> 32}
+
+    @property
+    def addr_3(self) -> int:
+        """The fourth address of :meth:`addr_3_words`."""
+        return self.root_src_dst | (self.function << 32)
 
     def to_words(self) -> list[int]:
         """Serialize to the 15-word stream format pushed to the engine."""

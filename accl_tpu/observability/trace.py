@@ -450,6 +450,24 @@ def stage(name: Optional[str], spans=(), field: Optional[str] = None,
     _stage_tls.ann = _open_annotation(name) if name is not None else None
 
 
+_NO_REGION = contextlib.nullcontext()
+
+
+def region(name: str):
+    """A profiler annotation `name` around a block while tracing is on,
+    a shared no-op context otherwise.  Unlike :func:`stage` it nests:
+    a model layer's region (``moe.dispatch``) encloses the driver's
+    ``accl.*`` stages of the calls it makes."""
+    if not _enabled:
+        return _NO_REGION
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(name)
+
+
 # ---------------------------------------------------------------------------
 # marked windows + XLA profiler integration
 # ---------------------------------------------------------------------------

@@ -93,6 +93,7 @@ enum class Op : uint32_t {
   ReduceScatter = 11,
   Barrier = 12,
   Alltoall = 13,
+  Alltoallv = 14,  // per-peer counts from the count operand
   Nop = 255,
 };
 
@@ -246,6 +247,9 @@ struct CallDesc {
   uint64_t addr0() const { return uint64_t(w[9]) | (uint64_t(w[10]) << 32); }
   uint64_t addr1() const { return uint64_t(w[11]) | (uint64_t(w[12]) << 32); }
   uint64_t addr2() const { return uint64_t(w[13]) | (uint64_t(w[14]) << 32); }
+  // alltoallv's count result: the root and function words, which an
+  // all-to-all has no use for (CCLOCall.addr_3 in constants.py).
+  uint64_t addr3() const { return uint64_t(w[3]) | (uint64_t(w[4]) << 32); }
 };
 
 // Thrown by a rendezvous wait-point whose peer state has not arrived;

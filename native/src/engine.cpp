@@ -1828,6 +1828,7 @@ void Engine::dispatch(CallDesc& c, Progress& p) {
     case Op::ReduceScatter: coll_reduce_scatter(c, p); break;
     case Op::Allreduce: coll_allreduce(c, p); break;
     case Op::Alltoall: coll_alltoall(c, p); break;
+    case Op::Alltoallv: coll_alltoallv(c, p); break;
     case Op::Barrier: coll_barrier(c, p); break;
     default: sticky_err_ |= COLLECTIVE_NOT_IMPLEMENTED; break;
   }
@@ -3333,6 +3334,102 @@ void Engine::coll_alltoall(CallDesc& c, Progress& p) {
     uint32_t r = (t.local + P - i) % P;  // peer for whom we are (their+i)
     recv_eager(c, r, c.tag(), c.addr2() + uint64_t(r) * res_stride, elems,
                RecvMode::COPY, 0, comp);
+  }
+}
+
+// All-to-all with per-peer counts (no reference counterpart).  The count
+// operand (addr1) holds P int32 counts in rows of count() elements; the
+// count result's address rides in the root and function words.  Step 1
+// trades each peer its count in one 8-byte slot (eager); the rows then
+// move on coll_alltoall's eager or rendezvous paths with per-peer
+// offsets, packed in rank order on the send side and in source order
+// on the receive side.  Both peers of a pair know its count after step
+// 1, so they pick the same protocol.  Every primitive is its own step,
+// so a rendezvous retry resumes without resending.
+void Engine::coll_alltoallv(CallDesc& c, Progress& p) {
+  const CommTable& t = comm_for(c);
+  Dom d = dom(c);
+  uint32_t P = t.size;
+  uint32_t me = t.local;
+  uint64_t row = c.count();
+  uint64_t slot = 8 / d.ub;  // one 8-byte count in payload elements
+  uint64_t counts_addr = c.addr3();
+  if (!c.scratch0) c.scratch0 = alloc(uint64_t(P) * 8, 64);  // ours
+  if (!c.scratch1) c.scratch1 = alloc(uint64_t(P) * 8, 64);  // theirs
+  step_local(p, [&] {
+    MutexLock g(mem_mu_);
+    const uint8_t* in = mem(c.addr1(), uint64_t(P) * 4);
+    uint8_t* ours = mem(c.scratch0, uint64_t(P) * 8);
+    uint8_t* theirs = mem(c.scratch1, uint64_t(P) * 8);
+    for (uint32_t j = 0; j < P; ++j) {
+      int32_t v;
+      std::memcpy(&v, in + 4 * j, 4);
+      uint64_t n = v < 0 ? 0 : uint64_t(v);
+      std::memcpy(ours + 8 * j, &n, 8);
+    }
+    std::memcpy(theirs + 8 * me, ours + 8 * me, 8);
+  });
+  for (uint32_t i = 1; i < P; ++i) {
+    uint32_t r = (me + i) % P;
+    step_local(p, [&] {
+      send_eager(c, r, c.tag(), c.scratch0 + 8 * r, slot, false, 0, 0);
+    });
+  }
+  for (uint32_t i = 1; i < P; ++i) {
+    uint32_t r = (me + P - i) % P;
+    step_local(p, [&] {
+      recv_eager(c, r, c.tag(), c.scratch1 + 8 * r, slot, RecvMode::COPY,
+                 0, 0);
+    });
+  }
+  std::vector<uint64_t> sc(P), rc(P), soff(P), roff(P);
+  {
+    MutexLock g(mem_mu_);
+    std::memcpy(sc.data(), mem(c.scratch0, uint64_t(P) * 8), P * 8);
+    std::memcpy(rc.data(), mem(c.scratch1, uint64_t(P) * 8), P * 8);
+  }
+  for (uint32_t j = 1; j < P; ++j) {
+    soff[j] = soff[j - 1] + sc[j - 1];
+    roff[j] = roff[j - 1] + rc[j - 1];
+  }
+  step_local(p, [&] {
+    std::vector<int32_t> got(P);
+    for (uint32_t j = 0; j < P; ++j) got[j] = int32_t(rc[j]);
+    MutexLock g(mem_mu_);
+    std::memcpy(mem(counts_addr, uint64_t(P) * 4), got.data(), P * 4);
+  });
+  uint64_t op_row = row * d.eb(d.op0);
+  uint64_t res_row = row * d.eb(d.res);
+  step_local(p, [&] {
+    local_move(c, c.addr0() + soff[me] * op_row,
+               c.addr2() + roff[me] * res_row, sc[me] * row, d.op0, d.res);
+  });
+  for (uint32_t i = 1; i < P; ++i) {
+    uint32_t r = (me + P - i) % P;
+    if (use_rendezvous(c, rc[r] * row))
+      rndzv_post_addr(c, p, r, c.tag(), c.addr2() + roff[r] * res_row,
+                      rc[r] * row, d.res);
+  }
+  for (uint32_t i = 1; i < P; ++i) {
+    uint32_t r = (me + i) % P;
+    uint64_t src = c.addr0() + soff[r] * op_row;
+    if (use_rendezvous(c, sc[r] * row))
+      rndzv_send(c, p, r, c.tag(), src, sc[r] * row, d.op0);
+    else
+      step_local(p, [&] {
+        send_eager(c, r, c.tag(), src, sc[r] * row, false, 0, 0);
+      });
+  }
+  for (uint32_t i = 1; i < P; ++i) {
+    uint32_t r = (me + P - i) % P;
+    if (use_rendezvous(c, rc[r] * row)) {
+      rndzv_wait_done(c, p, r, c.tag());
+    } else {
+      step_local(p, [&] {
+        recv_eager(c, r, c.tag(), c.addr2() + roff[r] * res_row,
+                   rc[r] * row, RecvMode::COPY, 0, 0);
+      });
+    }
   }
 }
 

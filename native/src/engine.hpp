@@ -529,6 +529,7 @@ class Engine {
   void coll_reduce_scatter(CallDesc& c, Progress& p);
   void coll_allreduce(CallDesc& c, Progress& p);
   void coll_alltoall(CallDesc& c, Progress& p);
+  void coll_alltoallv(CallDesc& c, Progress& p);
   void coll_barrier(CallDesc& c, Progress& p);
   void do_config(CallDesc& c);
 

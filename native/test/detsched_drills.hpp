@@ -15,6 +15,7 @@
 //   abort_vs_traffic     — ACCL.abort racing an in-flight send/recv
 //   join_vs_traffic      — elastic join racing live traffic
 //   shutdown_vs_waiters  — two-phase shutdown racing blocked receivers
+//   alltoallv_counts     — alltoallv's count operand and count result
 //   detach_race          — InprocHub::detach vs a mid-flight delivery
 //                          (sensitivity drill: the ACCL_FAULT_DETACH_RACE
 //                          build reverts the r13 drain and the checker
@@ -412,6 +413,71 @@ inline void drill_subcomm_allgather() { subcomm_allgather_impl(4); }
 // explicit budget, not in the default --ci sweep)
 inline void drill_subcomm_allgather8() { subcomm_allgather_impl(8); }
 
+// ---- drill: alltoallv's descriptor shape ---------------------------------
+// Three ranks run one alltoallv: the count operand in the OP1 slot, the
+// count result's address in the root and function words.  Counts are
+// skewed (a zero pair, an all-eager pair, a 5-row pair of multi-segment
+// eager traffic on the 4 x 256 B pool) so the count trade and the rows
+// interleave with pool pressure.  Invariants on every schedule: the call
+// finalizes cleanly (or with a timeout an injection caused), and every
+// rank holds each peer's count and rows bit for bit.
+inline void drill_alltoallv_counts() {
+  constexpr int P = 3;
+  constexpr uint32_t row = 16;  // f32 elements (64 B a row)
+  const uint32_t counts[P][P] = {{1, 0, 5}, {2, 1, 0}, {0, 3, 1}};
+  DetWorld w(P);
+  std::vector<Thread> ranks;
+  for (int r = 0; r < P; ++r) {
+    ranks.emplace_back(Thread([&w, &counts, r] {
+      Engine& e = *w.eng[size_t(r)];
+      uint32_t rows = 0;
+      for (int j = 0; j < P; ++j) rows += counts[r][j];
+      std::vector<float> payload(size_t(rows) * row);
+      for (size_t k = 0; k < payload.size(); ++k)
+        payload[k] = float(r * 1000 + int(k));
+      uint64_t src = e.alloc(std::max<uint64_t>(payload.size(), 1) * 4, 64);
+      uint64_t dst = e.alloc(uint64_t(P) * 6 * row * 4, 64);
+      uint64_t sc = e.alloc(P * 4, 64);
+      uint64_t rc = e.alloc(P * 4, 64);
+      e.write_mem(src, payload.data(), payload.size() * 4);
+      int32_t mine[P];
+      for (int j = 0; j < P; ++j) mine[j] = int32_t(counts[r][j]);
+      e.write_mem(sc, mine, sizeof(mine));
+      auto d = DetWorld::desc(Op::Alltoallv, row, 0, uint32_t(rc), TAG_ANY,
+                              src, dst);
+      d[4] = uint32_t(rc >> 32);
+      d[11] = uint32_t(sc & 0xFFFFFFFFu);
+      d[12] = uint32_t(sc >> 32);
+      uint64_t id = e.start_call(d.data());
+      uint32_t ret = w.wait_call(r, id, "alltoallv never completed");
+      det::expect(ret == 0 || det::timeout_injections() > 0,
+                  "alltoallv classified an error");
+      if (ret == 0) {
+        int32_t got[P];
+        e.read_mem(rc, got, sizeof(got));
+        uint64_t at = 0;
+        for (int i = 0; i < P; ++i) {
+          det::expect(got[i] == int32_t(counts[i][r]),
+                      "alltoallv receive count differs from the peer's");
+          uint32_t off = 0;
+          for (int j = 0; j < r; ++j) off += counts[i][j];
+          std::vector<float> rows_in(size_t(counts[i][r]) * row);
+          e.read_mem(dst + at * 4, rows_in.data(), rows_in.size() * 4);
+          for (size_t k = 0; k < rows_in.size(); ++k)
+            det::expect(rows_in[k] == float(i * 1000 + int(off * row + k)),
+                        "alltoallv row landed wrong");
+          at += rows_in.size();
+        }
+      }
+      e.free_addr(src);
+      e.free_addr(dst);
+      e.free_addr(sc);
+      e.free_addr(rc);
+    }));
+  }
+  for (auto& t : ranks) t.join();
+}
+
 // ---- sensitivity drill: a submitted call that never finalizes -----------
 // Exercises the liveness invariant directly: two workers each take a
 // live token (one per "submitted call"); one finalizes, the other
@@ -447,6 +513,7 @@ inline const std::map<std::string, std::function<void()>>& registry() {
       {"subcomm_allgather", drill_subcomm_allgather},
       {"subcomm_allgather8", drill_subcomm_allgather8},
       {"liveness_leak", drill_liveness_leak},
+      {"alltoallv_counts", drill_alltoallv_counts},
   };
   return *m;
 }

@@ -66,6 +66,8 @@ ENGINE_DRILLS = (
     # explicit budget (heavier per schedule)
     "subcomm_allgather",
     "subcomm_allgather8",
+    # alltoallv's descriptor shape: count operand and count result
+    "alltoallv_counts",
 )
 SENSITIVITY_DRILL = "detach_race"
 WEDGE_DRILL = "subcomm_allgather8"

@@ -189,6 +189,26 @@ def test_driver_hlo_program_128mib_bf16_4_chips(ring4, op):
             + mem.temp_size_in_bytes) < HBM_BYTES
 
 
+@pytest.mark.parametrize("send_rows,recv_rows", [(49152, 196608),
+                                                  (196608, 49152)])
+def test_driver_alltoallv_moonlight_4_chips(ring4, send_rows, recv_rows):
+    # ep_alltoall.4chip's dispatch and combine: bf16 rows of 2048, the
+    # receive sized for the dropless worst case; the chip's compiler
+    # takes the ragged-all-to-all form, inside the 60 s call wait
+    import time
+
+    from accl_tpu.backends.tpu import _alltoallv_fn
+
+    t0 = time.perf_counter()
+    compiled = _alltoallv_fn(ring4, 4, send_rows, recv_rows, 2048,
+                             "bfloat16")
+    assert time.perf_counter() - t0 < 30
+    assert "ragged-all-to-all" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES
+
+
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_flash_attention_d128(one_chip, direction):
     from accl_tpu.ops.flash import flash_attention

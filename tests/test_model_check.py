@@ -80,6 +80,20 @@ def test_clean_drill_zero_findings(harness):
     assert res["unique_traces"] == res["runs"]
 
 
+def test_alltoallv_drill_zero_findings(harness):
+    # alltoallv's descriptor shape (count operand in OP1, count result
+    # in the root/function words) under explored schedules: counts and
+    # rows land bit for bit on every schedule, every run distinct
+    res = run_json(
+        harness, "--drill", "alltoallv_counts", "--explore", "300",
+        "--seed", "5",
+    )
+    assert res["exit_code"] == 0
+    assert res["findings"] == 0
+    assert res["runs"] >= 300
+    assert res["unique_traces"] == res["runs"]
+
+
 def test_fault_build_rediscovers_detach_race(harness):
     # sensitivity: the seeded r13 race must be found, with a non-empty
     # minimal failing prefix naming the invariant
