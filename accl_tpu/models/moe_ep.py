@@ -17,8 +17,11 @@ a replica of the router and of the shared expert.  A layer call is:
 - three driver calls: the ``[P, experts per rank]`` row counts by
   ``alltoall`` (int32), the rows by ``alltoallv`` (dispatch), and after
   the experts the rows back by ``alltoallv`` (combine);
-- experts: the received rows grouped by local expert, one
-  ``ragged_dot`` per projection (f32 accumulation, bf16 storage);
+- experts: one grouped matmul (a Pallas kernel) per projection on the
+  received rows where they landed (f32 accumulation, bf16 storage):
+  the groups are ``(source, local expert)`` in received order, each
+  with its local expert's weights, so no row is permuted in either
+  direction;
 - combine: the rows un-permuted, the gate-weighted sum, the shared
   expert and the residual.
 
@@ -43,6 +46,7 @@ import numpy as np
 
 from ..accl import GLOBAL_COMM
 from ..observability import trace as _trace
+from ..utils.platform import pallas_interpret
 
 #: tally fields (rows): dispatch rows sent off-chip, dispatch rows
 #: received, combine rows sent off-chip
@@ -124,30 +128,79 @@ def _route(cfg: EPMoEConfig, nranks: int, x, p):
     return rows, ecounts, pcounts, order, gates, xf + shared
 
 
+#: rows of a grouped-matmul tile; the receive holds whole tiles
+_TILE_ROWS = 512
+#: VMEM for one tile of rows, a whole [K, N] weight and the output
+#: tile, each double-buffered (about 23 MB at Moonlight's widths)
+_VMEM_BYTES = 64 * 1024 * 1024
+
+
+def _grouped_matmul(x, w, sizes, interpret: bool):
+    """``x[rows of group g] @ w[g % E]`` for consecutive groups of
+    `sizes` rows, in bf16 with f32 accumulation; rows past the last
+    group are not written.  x: [M, K], M a multiple of ``_TILE_ROWS``;
+    w: [E, K, N]; sizes: [G] int32; `interpret` runs the kernel under
+    the Pallas TPU interpreter.
+
+    A Pallas kernel on megablox's tile schedule
+    (``make_group_metadata``): one grid step per (row tile, group) that
+    meet, so a tile holding rows of several groups is visited once per
+    group and stored under that group's row mask, and a step picks its
+    weight by ``group % E`` (no copy of the weights per group).  Tiles
+    past the last group are never visited, and consecutive steps of one
+    group keep their weight in VMEM."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import \
+        make_group_metadata
+
+    (M, K), (E, _, N) = x.shape, w.shape
+    tm = _TILE_ROWS
+    meta, steps = make_group_metadata(
+        group_sizes=sizes, m=M, tm=tm, start_group=jnp.int32(0),
+        num_nonzero_groups=sizes.shape[0], visit_empty_groups=False)
+
+    def kernel(offsets, groups, tiles, x_ref, w_ref, o_ref):
+        i = pl.program_id(0)
+        g = groups[i]
+        row = tiles[i] * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1),
+                                                       0)
+        mine = (row >= offsets[g]) & (row < offsets[g + 1])
+        y = jnp.dot(x_ref[...], w_ref[...],
+                    preferred_element_type=jnp.float32)
+        o_ref[...] = jnp.where(mine, y.astype(o_ref.dtype), o_ref[...])
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((M, N), jnp.bfloat16),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(steps,),
+            in_specs=[
+                pl.BlockSpec((tm, K), lambda i, o, g, t: (t[i], 0)),
+                pl.BlockSpec((None, K, N),
+                             lambda i, o, g, t: (g[i] % E, 0, 0))],
+            out_specs=pl.BlockSpec((tm, N), lambda i, o, g, t: (t[i], 0))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_BYTES),
+        interpret=interpret,
+    )(*meta, x, w)
+
+
 @partial(jax.jit, static_argnums=(0, 1))
-def _experts(cfg: EPMoEConfig, nranks: int, rows, counts, p):
-    """The held experts on the received rows.  rows: [cap * H] bf16 in
-    source order, each source's rows grouped by local expert; counts:
-    [P * held] int32, rows from source i for local expert e at
-    ``i * held + e``."""
-    H = cfg.hidden
-    xs = rows.reshape(-1, H)
-    cap = xs.shape[0]
-    held = counts.shape[0] // nranks
-    ends = jnp.cumsum(counts)
-    seg = jnp.searchsorted(ends, jnp.arange(cap), side="right")
-    local = jnp.where(seg < counts.shape[0], seg % held, held)
-    perm = jnp.argsort(local, stable=True)
-    xg = xs[perm]
-    sizes = counts.reshape(nranks, held).sum(0)
-    h1 = jax.lax.ragged_dot(xg, p["w1"], sizes,
-                            preferred_element_type=jnp.bfloat16)
-    h3 = jax.lax.ragged_dot(xg, p["w3"], sizes,
-                            preferred_element_type=jnp.bfloat16)
-    a = _silu_glu(h1, h3).astype(jnp.bfloat16)
-    out = jax.lax.ragged_dot(a, p["w2"], sizes,
-                             preferred_element_type=jnp.bfloat16)
-    return out[_inverse(perm)].reshape(-1)
+def _experts(cfg: EPMoEConfig, interpret: bool, rows, counts, p):
+    """The held experts on the received rows, where they landed.  rows:
+    [cap * H] bf16 in source order, each source's rows grouped by local
+    expert; counts: [P * held] int32, rows from source i for local
+    expert e at ``i * held + e``.  Group ``g`` is (source ``g // held``,
+    local expert ``g % held``) and meets that expert's weights, so the
+    output is in the received order the combine sends back; no row is
+    permuted.  Rows past the received total are unspecified."""
+    mm = partial(_grouped_matmul, sizes=counts, interpret=interpret)
+    xs = rows.reshape(-1, cfg.hidden)
+    a = _silu_glu(mm(xs, p["w1"]), mm(xs, p["w3"])).astype(jnp.bfloat16)
+    return mm(a, p["w2"]).reshape(-1)
 
 
 @partial(jax.jit, static_argnums=(0, 1), donate_argnums=(8,))
@@ -194,13 +247,17 @@ class EPMoELayer:
         self.ecount_recv = mk(cfg.n_experts, np.int32)
         self.dispatch_send = mk(rows * H, bf16)
         self.dispatch_count = mk(P, np.int32)
-        # dropless: every rank's every row may come here
-        self.dispatch_recv = mk(P * rows * H, bf16)
+        # dropless: every rank's every row may come here; whole tiles of
+        # the expert kernel
+        cap = -(-P * rows // _TILE_ROWS) * _TILE_ROWS
+        self.dispatch_recv = mk(cap * H, bf16)
         self.dispatch_recv_count = mk(P, np.int32)
-        self.combine_send = mk(P * rows * H, bf16)
+        self.combine_send = mk(cap * H, bf16)
         self.combine_recv = mk(rows * H, bf16)
         self.combine_recv_count = mk(P, np.int32)
         self.calls = 0
+        # the expert kernels compile on the TPU, run interpreted on the CPU
+        self.interpret = pallas_interpret()
         self._tally = jax.device_put(jnp.zeros(len(TALLY_FIELDS),
                                                jnp.int32),
                                      self.dispatch_send.dev.device)
@@ -221,7 +278,7 @@ class EPMoELayer:
                            self.dispatch_count, self.dispatch_recv_count,
                            row=cfg.hidden, **kw)
         with _trace.region("moe.experts"):
-            out = _experts(cfg, P, self.dispatch_recv.dev,
+            out = _experts(cfg, self.interpret, self.dispatch_recv.dev,
                            self.ecount_recv.dev, p)
             self.combine_send.set_dev_range(0, out)
         with _trace.region("moe.combine"):

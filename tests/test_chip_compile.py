@@ -209,6 +209,33 @@ def test_driver_alltoallv_moonlight_4_chips(ring4, send_rows, recv_rows):
             + mem.temp_size_in_bytes) < HBM_BYTES
 
 
+def test_moe_experts_moonlight_one_chip(one_chip):
+    # ep_alltoall.4chip's expert layer on one chip's dropless receive
+    # (4 x 8192 x 6 rows of 2048): the grouped matmuls run on the rows
+    # where they landed, with no sort or row index over the capacity
+    from accl_tpu.models import moe_ep
+
+    cfg = moe_ep.EPMoEConfig(hidden=2048, n_experts=64, top_k=6,
+                             expert_width=1408, shared_width=2816,
+                             scaling=2.446, eps=1e-6)
+    cap, held = 4 * 8192 * 6, 16
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    p = {"w1": shape(held, 2048, 1408), "w3": shape(held, 2048, 1408),
+         "w2": shape(held, 1408, 2048)}
+    compiled = moe_ep._experts.lower(
+        cfg, False, shape(cap * 2048), shape(4 * held, dtype=jnp.int32),
+        p).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert " sort(" not in text and f"s32[{cap}]" not in text
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES
+
+
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_flash_attention_d128(one_chip, direction):
     from accl_tpu.ops.flash import flash_attention

@@ -117,6 +117,77 @@ def test_no_token_dropped_under_extreme_skew():
     assert recv[2] == recv[3] == 0
 
 
+def _permuted_experts(nranks, rows, counts, p):
+    """The expert layer as it was before its groups were (source, local
+    expert): rows permuted into expert-major order, one ``ragged_dot``
+    group per local expert, rows permuted back."""
+    xs = rows.reshape(-1, CFG.hidden)
+    held = counts.shape[0] // nranks
+    ends = jnp.cumsum(counts)
+    seg = jnp.searchsorted(ends, jnp.arange(xs.shape[0]), side="right")
+    local = jnp.where(seg < counts.shape[0], seg % held, held)
+    perm = jnp.argsort(local, stable=True)
+    sizes = counts.reshape(nranks, held).sum(0)
+
+    def mm(x, w):
+        return jax.lax.ragged_dot(x, w, sizes,
+                                  preferred_element_type=jnp.bfloat16)
+
+    xg = xs[perm]
+    a = moe_ep._silu_glu(mm(xg, p["w1"]), mm(xg, p["w3"]))
+    out = mm(a.astype(jnp.bfloat16), p["w2"])
+    return out[moe_ep._inverse(perm)]
+
+
+def _recv_counts(case, nranks, held, rng):
+    """[nranks * held] rows received from source i for local expert e
+    at ``i * held + e``; each source sends at most T * top_k rows."""
+    sent = T * CFG.top_k
+    c = np.zeros((nranks, held), np.int64)
+    for i in range(nranks):
+        c[i] = np.bincount(rng.integers(0, held, rng.integers(sent)),
+                           minlength=held)
+    if case == "empty_group":
+        c[1, 2] = c[3, 0] = 0
+    elif case == "zero_rows":
+        c[:] = 0
+    elif case == "one_source":
+        c[np.arange(nranks) != nranks - 1] = 0
+    elif case == "full_capacity":
+        for i in range(nranks):
+            c[i] = np.bincount(rng.integers(0, held, sent), minlength=held)
+    return jnp.asarray(c.reshape(-1), jnp.int32)
+
+
+@pytest.mark.parametrize("case", ["empty_group", "zero_rows", "one_source",
+                                  "full_capacity", "one_rank"])
+def test_experts_in_received_order_match_permuted_experts(case):
+    nranks = 1 if case == "one_rank" else P
+    held = CFG.n_experts // nranks
+    rng = np.random.default_rng(7)
+    counts = _recv_counts(case, nranks, held, rng)
+    total = int(counts.sum())
+    full = nranks * T * CFG.top_k   # every source's every row here
+    tile = moe_ep._TILE_ROWS
+    cap = -(-full // tile) * tile
+    # rows past the received total hold noise here: none may leak out
+    rows = jnp.asarray(rng.standard_normal((cap, CFG.hidden)),
+                       jnp.bfloat16).reshape(-1)
+    p = {k: v[:held] for k, v in _params(8)[0].items()
+         if k in ("w1", "w3", "w2")}
+    got = np.asarray(moe_ep._experts(CFG, True, rows, counts, p)
+                     ).reshape(cap, CFG.hidden)
+    want = np.asarray(_permuted_experts(nranks, rows, counts, p))
+    # the same products in f32, summed in another order: at most one
+    # bf16 rounding step apart (rows past the total are unspecified)
+    steps = np.abs(got[:total].view(np.int16).astype(np.int32)
+                   - want[:total].view(np.int16).astype(np.int32))
+    assert steps.max(initial=0) <= 1
+    assert np.count_nonzero(steps) <= steps.size // 1000
+    assert (total == full) == (case == "full_capacity")
+    assert (total == 0) == (case == "zero_rows")
+
+
 def test_select_is_the_layers_router():
     layers, xs = _params(5), _inputs(6)
     p = layers[0]
