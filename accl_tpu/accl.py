@@ -49,6 +49,7 @@ from .observability import flight as _flight
 from .observability import health as _health
 from .observability import metrics as _metrics
 from .observability import trace as _trace
+from .observability.record import CallRecord
 from .request import Request, RequestQueue
 from .utils.logging import get_logger
 
@@ -153,14 +154,6 @@ class ACCL:
         #: member group across ranks, so disjoint sub-comm captures
         #: never skew each other's pairing
         self._plan_group_seq: dict = {}
-        #: ACCL_PLAN_AUTO state (armed at initialize): streak-detect
-        #: identical resident sync gang calls and transparently route
-        #: them through a one-step plan ring once every gang member
-        #: agreed (None = auto lane off, zero per-call cost)
-        self._plan_auto = 0
-        self._auto_rings: Optional[dict] = None
-        self._auto_last = None
-        self._auto_streak = 0
         #: learned algorithm-selection policy (accl_tpu/tuning): armed
         #: at initialize from ACCL_TUNE_TABLE (ACCL_TUNE=0 disarms).
         #: None adds ONE falsy read to _execute — with the knobs unset
@@ -347,12 +340,6 @@ class ACCL:
         #    nothing when it is off (the default)
         if os.environ.get("ACCL_SUPERVISE", "0") == "1":
             self.supervisor = self.supervise()
-
-        # 10. plan auto-capture (ACCL_PLAN_AUTO=N; honors ACCL_PLAN=0):
-        #     the env is read here, not at import, so tests and worlds
-        #     created after an env change see it
-        self._plan_auto = _plans.auto_threshold()
-        self._auto_rings = {} if self._plan_auto else None
 
     # ------------------------------------------------------------------
     # properties / config
@@ -622,7 +609,7 @@ class ACCL:
             for ref in self._async_pending:
                 r = ref()
                 if r is not None and not r.done and \
-                        (r.flight is None or r.flight.comm == comm_id):
+                        (r.record is None or r.record.comm == comm_id):
                     r.complete(err)
 
     def shrink_communicator(self, comm_id: int = GLOBAL_COMM,
@@ -722,9 +709,9 @@ class ACCL:
     def _invalidate_plans(self, comm_id: Optional[int],
                           reason: str) -> None:
         """Fence live plans touching ``comm_id`` (None = all): part of
-        the abort/reset/shrink/grow contract — a replay must raise (or
-        transparently re-capture, on the auto lane) after any epoch
-        fence, never silently run the dead world's program."""
+        the abort/reset/shrink/grow contract — a replay must raise
+        after any epoch fence, never silently run the dead world's
+        program."""
         live = []
         for ref in self._plans:
             p = ref()
@@ -734,10 +721,6 @@ class ACCL:
             if comm_id is None or comm_id in p.comms:
                 p._invalidate(reason)
         self._plans = live
-        if self._auto_rings:
-            self._auto_rings.clear()
-        self._auto_last = None
-        self._auto_streak = 0
         # the selection-policy memo keys on (scenario, arithcfg,
         # count, comm): after a membership change the same comm id can
         # mean a different size (and payload bucket), so drop the
@@ -819,41 +802,6 @@ class ACCL:
         finally:
             self._in_hier = False
         return self._last_request
-
-    def _replay_auto(self, entry, desc: str) -> Optional[Request]:
-        """Route one auto-captured call through its plan ring; returns
-        a completed Request, or None when the ring was invalidated by
-        an epoch fence (the caller falls through to the eager path,
-        which re-captures — or fast-fails if the comm is still dead)."""
-        call, ring = entry
-        rec = None
-        if self.flight_recorder is not None and _flight.enabled():
-            rec = self.flight_recorder.new_record(
-                next(_plans._replay_ids), "plan_replay", call.comm,
-                call.tag, "plan", call.count, 0, self.comm.size, True,
-                _trace.now_ns())
-            rec.mark_dispatched("plan", _trace.now_ns())
-        try:
-            self._device.plan_replay(ring, run_async=False,
-                                     timeout_s=self.call_timeout_s)
-        except ACCLError as e:
-            code = int(getattr(e, "code", 0))
-            if rec is not None:
-                rec.finish(code or int(ErrorCode.DMA_INTERNAL_ERROR),
-                           _trace.now_ns())
-            self._auto_rings.pop(id(call), None)
-            if code & int(ErrorCode.COMM_ABORTED) \
-                    or "invalidated" in str(e):
-                return None  # fenced: transparent re-capture via eager
-            raise
-        if rec is not None:
-            rec.finish(0, _trace.now_ns())
-        if _metrics.enabled():
-            _metrics.default_registry().inc("plans/replays")
-        req = Request(desc, sync=True)
-        req.complete(0, 0.0)
-        self._last_request = req
-        return req
 
     def _install_communicator(self, comm: Communicator) -> int:
         """Append + upload an explicitly-built communicator (the elastic
@@ -1681,27 +1629,6 @@ class ACCL:
                                           run_async, desc)
                 if routed is not None:
                     return routed
-        # plan auto-replay (ACCL_PLAN_AUTO, accl_tpu/plans.py): a call
-        # whose gang agreed to arm a one-step ring replays through it —
-        # no descriptor work, no gang assembly, no per-call request
-        # plumbing.  One falsy read when the auto lane is off; the
-        # identity check (`is`) is sound because _build memoizes: the
-        # steady-state loop returns the SAME CCLOCall object each step.
-        # Placed after the abort fast-fail so a fenced comm raises
-        # before any replay could run on a dead epoch.
-        if self._auto_rings is not None and not run_async:
-            entry = self._auto_rings.get(id(call))
-            if entry is not None and entry[0] is call \
-                    and self._plan_recorder is None \
-                    and not _san.active():
-                # the recorder/sanitizer guards keep an armed ring from
-                # short-circuiting an explicit capture_plan or an
-                # ACCL_SANITIZE lane that must observe every call
-                replayed = self._replay_auto(entry, desc)
-                if replayed is not None:
-                    return replayed
-                # ring fenced: fall through to the eager path, which
-                # re-captures (or fast-fails if the comm is still dead)
         # observability gate first: one module-bool read each when all
         # are off, and t_submit marks user-call entry (operand staging
         # below is inside the submit→queue window by design).  The
@@ -1713,8 +1640,8 @@ class ACCL:
         traced = _trace.enabled()
         if traced:
             _trace.stage("accl.driver")
-        observe = (self.flight_recorder is not None or _metrics.enabled()
-                   or traced)
+        observe = ((self.flight_recorder is not None and _flight.enabled())
+                   or _metrics.enabled() or traced)
         t_submit = _trace.now_ns() if observe else 0
         # size validation: the descriptor carries the full count, so a
         # short buffer would silently corrupt (the reference throws from
@@ -1733,7 +1660,7 @@ class ACCL:
         # execute the gang inline on the last-arriving rank's thread
         req = Request(desc, sync=not run_async)
         if observe:
-            self._observe_call(call, desc, req, t_submit)
+            self._observe_call(call, desc, req, t_submit, traced)
         # collective sanitizer lane (analysis/sanitizer.py): off-path
         # cost is this one module-bool read; with ACCL_SANITIZE=1 the
         # call is validated (comm/root/peer/operand-overlap) and, on
@@ -1749,22 +1676,6 @@ class ACCL:
         if self._plan_recorder is not None:
             self._plan_recorder.on_call(call, sync_in, sync_out,
                                         run_async, desc, req)
-        # plan auto-capture intent (ACCL_PLAN_AUTO): after N identical
-        # resident sync gang calls, mark intent on the request — the
-        # engine arms a ring only when EVERY member of the same gang
-        # instance carries intent, so no rank ever replays against an
-        # eager peer (the agreement rides the gang itself)
-        if self._auto_rings is not None and not run_async \
-                and not sync_in and not sync_out \
-                and self._plan_recorder is None and not _san.active() \
-                and call.scenario in _GANG_OPS:
-            if call is self._auto_last:
-                self._auto_streak += 1
-                if self._auto_streak >= self._plan_auto:
-                    req.plan_intent = True
-            else:
-                self._auto_last = call
-                self._auto_streak = 1
 
         if sync_out:  # device-resident results need no completion sync
             def finish(r: Request) -> None:
@@ -1795,11 +1706,6 @@ class ACCL:
             raise ACCLError(f"{desc} timed out waiting for engine "
                             f"completion{req.flight_info()}")
         req.check()
-        # plan auto-capture adoption: the engine published a ring on
-        # this request (every member of the gang carried intent) —
-        # subsequent identical calls route through _replay_auto
-        if self._auto_rings is not None and req.plan_ring is not None:
-            self._auto_rings[id(call)] = (call, req.plan_ring)
         return req
 
     def resolve_call_signature(self, call: CCLOCall) -> tuple:
@@ -1823,36 +1729,33 @@ class ACCL:
         return op, nranks, rank, dtype_name, nbytes
 
     def _observe_call(self, call: CCLOCall, desc: str, req: Request,
-                      t_submit: int) -> None:
-        """Attach the observability record(s) to one outgoing request:
-        the metrics signature (collective, dtype, size bucket — published
-        by Request.complete) and, when tracing is on, the TraceSpan with
-        its submit timestamp and gang id.  The gang-id key matches the
-        engines' FIFO pairing key (scenario, comm, tag), so rank R's Nth
-        instance joins the same gang id every engine would assemble."""
+                      t_submit: int, traced: bool) -> None:
+        """Attach the call's one record to the outgoing request: into
+        the flight ring when the recorder is on, the metrics registry
+        that observes it at completion, and, traced, its gang id.  The
+        gang-id key matches the engines' FIFO pairing key (scenario,
+        comm, tag), so rank R's Nth instance joins the same gang id
+        every engine would assemble."""
         op, nranks, rank, dtype_name, nbytes = \
             self.resolve_call_signature(call)
         # tenant label (r20): rides the issuing communicator; one
         # attribute read (class-level None when unlabeled)
         tenant = (self._communicators[call.comm].tenant
                   if call.comm < len(self._communicators) else None)
+        gang = op in _GANG_OPS
+        rec = CallRecord(op.name, desc, rank, call.count, dtype_name,
+                         nbytes, nranks, call.comm, call.tag, gang, req.id,
+                         tenant, t_submit)
         if self.flight_recorder is not None and _flight.enabled():
-            req.flight = self.flight_recorder.new_record(
-                req.id, op.name, call.comm, call.tag, dtype_name,
-                call.count, nbytes, nranks, op in _GANG_OPS, t_submit,
-                tenant)
+            self.flight_recorder.add(rec)
         if _metrics.enabled():
-            req.metric = (_metrics.default_registry(), op.name, dtype_name,
-                          nbytes, nranks, t_submit, tenant)
-        if _trace.enabled():
-            span = _trace.new_span(op.name, desc, rank, call.count,
-                                   dtype_name, nbytes, nranks)
-            span.t_submit = t_submit
-            span.tenant = tenant
-            if op in _GANG_OPS:
-                span.gang_id = _trace.collector().gang_id_for(
+            rec.registry = _metrics.default_registry()
+        if traced:
+            rec.traced = True
+            if gang:
+                rec.gang_id = _trace.collector().gang_id_for(
                     (int(op), call.comm, call.tag), rank)
-            req.trace = span
+        req.record = rec
 
     # ------------------------------------------------------------------
     # observability (reference: accl.cpp:980-1064 dump utilities, plus

@@ -31,7 +31,7 @@ from ..backends.base import CCLODevice
 from ..buffer import BaseBuffer
 from ..communicator import Communicator, Rank
 from ..constants import CCLOCall, CfgFunc, DataType, Operation
-from ..observability import trace as _trace
+from ..observability.record import S_DISPATCHED, mark
 from ..request import Request
 from .checks import check_programs
 from .program import CollectiveProgram, RecordedCall
@@ -106,7 +106,7 @@ class LintDevice(CCLODevice):
         from ..constants import DATA_TYPE_SIZE
 
         elem_bytes = (DATA_TYPE_SIZE[pair[0]] // 8) if pair else 4
-        rec = request.flight
+        rec = request.record
         self.program.calls.append(RecordedCall(
             index=len(self.program.calls), rank=self.rank, op=op,
             comm=call.comm, root=call.root_src_dst,
@@ -120,7 +120,7 @@ class LintDevice(CCLODevice):
             flight_seq=rec.seq if rec is not None else -1,
             request=request))
         if rec is not None:
-            rec.mark_dispatched("lint", _trace.now_ns())
+            mark((rec,), ("t_dispatch",), state=S_DISPATCHED, lane="lint")
         request.complete(0, 0.0)
 
     # -- device memory (bump allocator, no storage) --------------------

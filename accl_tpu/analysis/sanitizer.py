@@ -152,7 +152,7 @@ class CaptureSession:
                         comm.id, [r.session for r in comm.ranks])
             self.eager_threshold = min(self.eager_threshold,
                                        accl.max_eager_size)
-            rec = req.flight
+            rec = req.record
             prog.calls.append(RecordedCall(
                 index=len(prog.calls), rank=rank,
                 op=Operation(call.scenario), comm=call.comm,
@@ -348,9 +348,9 @@ def _runtime_checks(accl, call: CCLOCall, desc: str, req,
         if domain is not None:
             instance = accl._sanitize_seq.get(call.comm, 0)
             accl._sanitize_seq[call.comm] = instance + 1
-            flight = req.flight
+            rec = req.record
             info = (f"rank {comm.local_rank}, flight seq "
-                    f"{flight.seq}" if flight is not None
+                    f"{rec.seq}" if rec is not None and rec.seq >= 0
                     else f"rank {comm.local_rank}")
             _gang_exchange(domain, call.comm, instance,
                            call_fingerprint(call), comm.local_rank, P,
@@ -371,7 +371,7 @@ def on_call(accl, call: CCLOCall, desc: str, req,
             # the call will never dispatch: retire its flight record
             # (distinct retcode, not engine success) so the watchdog
             # never reports the aborted call as a hung gang
-            rec = req.flight
+            rec = req.record
             if rec is not None and rec.in_flight:
                 rec.finish(SANITIZER_ABORT_ERROR, now_ns())
             raise

@@ -29,7 +29,7 @@ from ..communicator import Communicator, Rank
 from ..constants import ACCLError, CCLOCall, ErrorCode
 from ..observability import flight as _flight
 from ..observability import health as _health
-from ..observability import trace as _trace
+from ..observability.record import LOCAL_DISPATCH, S_DISPATCHED, mark
 from ..request import Request
 from ..utils.logging import get_logger
 from .base import CCLODevice
@@ -328,19 +328,13 @@ class EmuDevice(CCLODevice):
     # -- call path ----------------------------------------------------
     def start(self, call: CCLOCall, request: Request) -> None:
         # the native engine owns the session send/recv + rendezvous
-        # retry loop below this point, so the span's launch window is
+        # retry loop below this point, so the record's launch window is
         # the descriptor-post → engine-completion interval (its interior
         # breakdown is the engine's cycle-count duration, stamped on the
         # request as duration_ns)
-        span = request.trace
-        rec = request.flight
-        if rec is not None or span is not None:
-            t = _trace.now_ns()
-            if rec is not None:
-                rec.mark_dispatched("emu", t)
-            if span is not None:
-                span.lane = "emu"
-                span.t_dispatch = span.t_launch = t
+        rec = request.record
+        if rec is not None:
+            mark((rec,), LOCAL_DISPATCH, state=S_DISPATCHED, lane="emu")
         # snapshot + submit + waiter registration happen atomically
         # against close() (the _lifecycle contract above): after this
         # block either the call is tracked (close joins its waiter
@@ -367,8 +361,8 @@ class EmuDevice(CCLODevice):
                                               self._timeout_ms,
                                               ctypes.byref(ret),
                                               ctypes.byref(dur))
-                if span is not None:
-                    span.t_launched = _trace.now_ns()
+                if rec is not None:
+                    mark((rec,), ("t_launched",))
                 if ok:
                     request.complete(ret.value, dur.value)
                 else:

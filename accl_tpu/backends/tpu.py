@@ -42,10 +42,11 @@ from ..constants import (
     ReduceFunction,
     StreamFlags,
 )
-from ..observability import flight as _flight
 from ..observability import health as _health
 from ..observability import metrics as _metrics
 from ..observability import trace as _trace
+from ..observability.record import (LOCAL_DISPATCH, S_DISPATCHED,
+                                    S_GANG_READY, mark)
 from ..request import Request
 from ..utils.logging import get_logger
 from ..utils.platform import pallas_interpret
@@ -175,33 +176,9 @@ class _TpuBufferSlice(BaseBuffer):
                                self._start + end)
 
 
-def _spans(gang: dict, lane: Optional[str] = None) -> list:
-    """A gang's member TraceSpans (None where a request carries none),
-    tagged with `lane` where given: the `spans` of trace.stage, called
-    only while tracing is on."""
-    spans = [req.trace for _call, req, _krnl in gang.values()]
-    if lane is not None:
-        for span in spans:
-            if span is not None:
-                span.lane = lane
-    return spans
-
-
-def _mark_flight(gang: dict, state: int, lane: Optional[str] = None,
-                 t: Optional[int] = None) -> None:
-    """Stamp a gang's member flight records with one scheduler state
-    transition — ALWAYS on (unlike the spans): a handful of attribute
-    writes per member, the whole per-call flight budget at this layer."""
-    for _call, req, _krnl in gang.values():
-        rec = req.flight
-        if rec is None:
-            continue
-        if state == _flight.S_DISPATCHED:
-            rec.mark_dispatched(lane, t)
-        else:
-            rec.state = state
-            if state == _flight.S_GANG_READY and t is not None:
-                rec.t_gang_ready = t
+def _records(gang: dict) -> list:
+    """A gang's member call records (None where a request has none)."""
+    return [req.record for _call, req, _krnl in gang.values()]
 
 
 class PlanRing:
@@ -336,7 +313,7 @@ class TpuEngine:
         #: 1 - assemble_checked / (sum of the lane counters).
         self.metrics = _metrics.MetricsRegistry()
         for k in ("leader_dispatches", "executor_dispatches", "batches",
-                  "batched_gangs", "plan_replays", "plan_auto_captures",
+                  "batched_gangs", "plan_replays",
                   *_LANE_COUNTERS, "assemble_checked"):
             self.metrics.inc(k, 0)
         self._log = get_logger("accl_tpu.tpu")
@@ -489,21 +466,24 @@ class TpuEngine:
             if err is not None:
                 request.complete(err, 0.0)
                 return
-        span = request.trace
-        rec = request.flight
+        rec = request.record
         try:
             if scenario in (Operation.copy, Operation.combine):
-                self._mark_local(rec, span, "local")
+                if rec is not None:
+                    mark((rec,), LOCAL_DISPATCH, state=S_DISPATCHED,
+                         lane="local")
                 if scenario == Operation.copy:
                     self._exec_copy(rank, call)
                 else:
                     self._exec_combine(rank, call)
-                if span is not None:
-                    span.t_launched = _trace.now_ns()
+                if rec is not None:
+                    mark((rec,), ("t_launched",))
                 request.complete(0, 1.0)
                 return
             if scenario in (Operation.send, Operation.recv):
-                self._mark_local(rec, span, "p2p")
+                if rec is not None:
+                    mark((rec,), LOCAL_DISPATCH, state=S_DISPATCHED,
+                         lane="p2p")
                 if scenario == Operation.send:
                     self._submit_send(rank, call, request)
                 else:
@@ -515,20 +495,6 @@ class TpuEngine:
 
             request.description += f" [{e}]"
             request.complete(int(ErrorCode.DMA_INTERNAL_ERROR), 0.0)
-
-    @staticmethod
-    def _mark_local(rec, span, lane: str) -> None:
-        """Dispatch stamp of a rank-local lane (local op, p2p): one
-        clock read for the flight record and the span, whose launch
-        opens at dispatch (these lanes have no gang stages)."""
-        if rec is None and span is None:
-            return
-        t = _trace.now_ns()
-        if rec is not None:
-            rec.mark_dispatched(lane, t)
-        if span is not None:
-            span.lane = lane
-            span.t_dispatch = span.t_launch = t
 
     # -- local ops -----------------------------------------------------
     def _exec_copy(self, rank: int, call: CCLOCall) -> None:
@@ -668,8 +634,8 @@ class TpuEngine:
                 self._push_stream(rank, call.tag, moved)
             else:
                 dst.set_dev_range(doff, moved)
-            if request.trace is not None:  # delivery ends the launch
-                request.trace.t_launched = _trace.now_ns()
+            if request.record is not None:  # delivery ends the launch
+                mark((request.record,), ("t_launched",))
             request.complete(0, 1.0)
 
     # -- collectives ---------------------------------------------------
@@ -698,8 +664,8 @@ class TpuEngine:
                 request.complete(
                     int(ErrorCode.SEGMENTER_EXPECTED_BTT_ERROR), 0.0)
                 return
-        span = request.trace
-        if span is not None:  # accl.gang: submit through gang join
+        traced = _trace.enabled()
+        if traced:  # accl.gang: submit through gang join
             _trace.stage("accl.gang")
         gkey = ("coll", int(call.scenario), call.comm, call.tag)
         # link twin (r15): gang-arrival stamp for straggler-wait
@@ -723,21 +689,14 @@ class TpuEngine:
                     ready = gang
                     q.remove(gang)
         if ready is None:
-            if span is not None:  # joined: accl.gang ends
+            if traced:  # joined: accl.gang ends
                 _trace.stage(None)
             return
         t_ready = _trace.now_ns()  # last member arrived: gang exists
-        _mark_flight(ready, _flight.S_GANG_READY, t=t_ready)
-        if span is not None:  # joined: accl.gang ends
-            _trace.stage(None, _spans(ready), "t_gang_ready", t_ready)
+        # joined: accl.gang ends
+        mark(_records(ready), ("t_gang_ready",), t_ready,
+             state=S_GANG_READY, stage=None)
         self._account_gang_wait(call.comm, ready, t_ready)
-        # plan auto-capture (ACCL_PLAN_AUTO): arm a one-slot ring when
-        # EVERY member of this instance declared intent — the agreement
-        # rides the gang itself, so all ranks switch to replay on the
-        # same future instance.  One attr read per member on the ready
-        # path, only here.
-        if all(r_.plan_intent for _c, r_, _k in ready.values()):
-            self._arm_auto_ring(int(call.scenario), call.comm, ready)
         self._dispatch_gang(int(call.scenario), call.comm, ready, request)
 
     def _dispatch_gang(self, scenario: int, comm_id: int, gang: dict,
@@ -1414,8 +1373,8 @@ class TpuEngine:
             if plan is None:  # barrier: the replay rendezvous IS it
                 return
             x = self._assemble_global(plan, slot["gang"])
-            # link twin (r15): replayed collectives are the dominant
-            # steady-state traffic under ACCL_PLAN_AUTO — without this
+            # link twin (r15): replayed collectives are the whole
+            # steady-state traffic of a captured plan — without this
             # the matrix would report near-zero for exactly the lane
             # that matters (no gang-wait here: a replay rendezvouses
             # on the ring sequence, not per-member arrival)
@@ -1478,35 +1437,6 @@ class TpuEngine:
                 self._plan_rings.remove(ring)
         ring.slots = []  # drop the pinned gang plans/buffers now
 
-    def _arm_auto_ring(self, scenario: int, comm_id: int,
-                       gang: dict) -> None:
-        """ACCL_PLAN_AUTO: every member of this gang instance carried
-        plan intent — arm a one-slot ring from the gang's descriptors
-        and publish it on each member's request (the driver adopts it
-        after completion, so every rank switches on the SAME instance
-        and no rank ever replays against an eager peer)."""
-        try:
-            op = Operation(scenario)
-            members = self._comms[comm_id]
-            gang2 = {g: (c, None, None)
-                     for g, (c, _r, _k) in gang.items()}
-            plan = (None if op == Operation.barrier
-                    else self._gang_plan(op, comm_id, gang2))
-            ring = PlanRing(
-                [{"kind": "gang", "op": op, "comm": comm_id,
-                  "gang": gang2, "plan": plan}],
-                frozenset(members),
-                {comm_id: self._comm_gen.get(comm_id, 0)})
-            with self._plan_cv:
-                self._plan_rings.append(ring)
-            for _c, req, _k in gang.values():
-                req.plan_ring = ring
-            self.metrics.inc("plan_auto_captures")
-        except Exception as e:  # noqa: BLE001 — auto arming is
-            # best-effort: a failure keeps the eager path, never
-            # breaks the call that triggered it
-            self._log.warning("plan auto-capture failed: %s", e)
-
     def shutdown(self) -> None:
         if self._watchdog is not None:
             self._watchdog.stop()
@@ -1548,8 +1478,8 @@ class TpuEngine:
                 members = self._comms.get(comm_id, [])
                 for gang in gangs:
                     arrived = sorted(gang)
-                    recs = [req.flight for _c, req, _k in gang.values()
-                            if req.flight is not None]
+                    recs = [req.record for _c, req, _k in gang.values()
+                            if req.record is not None]
                     out.append({
                         "kind": "collective",
                         "collective": Operation(scenario).name,
@@ -1675,11 +1605,8 @@ class TpuEngine:
         lane = "leader" if self._inline_busy else "executor"
         traced = _trace.enabled()
         try:
-            t = _trace.now_ns()
-            _mark_flight(gang, _flight.S_DISPATCHED, lane=lane, t=t)
-            if traced:
-                _trace.stage("accl.marshal", _spans(gang, lane),
-                             "t_dispatch", t)
+            mark(_records(gang), ("t_dispatch",), state=S_DISPATCHED,
+                 lane=lane, stage="accl.marshal")
             dt_ns = self._run_collective(Operation(scenario), comm_id,
                                          gang)
             for call, request, _krnl in gang.values():
@@ -1699,15 +1626,10 @@ class TpuEngine:
         exactly the sequential semantics the RAW guard preserves)."""
         traced = _trace.enabled()
         try:
-            t = _trace.now_ns()
-            for _op, _c, gang, _plan in items:
-                _mark_flight(gang, _flight.S_DISPATCHED, lane="batched",
-                             t=t)
-            spans: list = []
-            if traced:
-                for _op, _c, gang, _plan in items:
-                    spans += _spans(gang, "batched")
-                _trace.stage("accl.marshal", spans, "t_dispatch", t)
+            recs = [r for _op, _c, gang, _plan in items
+                    for r in _records(gang)]
+            mark(recs, ("t_dispatch",), state=S_DISPATCHED,
+                 lane="batched", stage="accl.marshal")
             xs = [self._assemble_global(plan, gang)
                   for _op, _c, gang, plan in items]
             for _op, _c, _gang, plan_ in items:
@@ -1718,16 +1640,14 @@ class TpuEngine:
             # one fused launch shared by every batched gang — the
             # aligned cross-gang slice the timeline shows
             t0 = _trace.now_ns()
-            if traced:
-                _trace.stage("accl.launch", spans, "t_launch", t0)
+            mark(recs, ("t_launch",), t0, stage="accl.launch")
             ys = fnb(*xs)
             if self.profile_sync:
                 import jax
 
                 jax.block_until_ready(ys)
             t1 = _trace.now_ns()
-            if traced:
-                _trace.stage("accl.finish", spans, "t_launched", t1)
+            mark(recs, ("t_launched",), t1, stage="accl.finish")
             # per-call perf counter: the batch's wall time is shared by
             # K fused dispatches, so each call's duration is its share
             # (reporting the whole batch per call would inflate
@@ -1735,8 +1655,7 @@ class TpuEngine:
             per_call = float(t1 - t0) / len(items)
             for (op, _c, gang, plan), y in zip(items, ys):
                 self._scatter_back(plan, y)
-                if traced:
-                    _trace.stamp(_spans(gang), "t_rebound")
+                mark(_records(gang), ("t_rebound",))
                 for call, request, _krnl in gang.values():
                     request.complete(0, per_call)
         except Exception as e:
@@ -2058,10 +1977,11 @@ class TpuEngine:
         costs one prepared global + one compiled dispatch, no per-member
         jax ops.
 
-        Returns the duration in ns.  Traced, it stamps the member spans'
-        launch, launched and rebound, and moves the dispatching
+        Returns the duration in ns.  It stamps the member records'
+        launch, launched and rebound and, traced, moves the dispatching
         thread's stage from accl.marshal to accl.launch to accl.finish
-        (a barrier has no program: its spans keep those stamps None)."""
+        (a barrier has no program: its records keep those stamps
+        None)."""
         if op == Operation.barrier:
             return 0  # gang completion IS the synchronization
 
@@ -2070,10 +1990,9 @@ class TpuEngine:
         self._account_links(plan)
         self.metrics.inc(plan["lane"])
 
-        spans = _spans(gang) if _trace.enabled() else None
+        recs = _records(gang)
         t0 = _trace.now_ns()
-        if spans is not None:
-            _trace.stage("accl.launch", spans, "t_launch", t0)
+        mark(recs, ("t_launch",), t0, stage="accl.launch")
         y = plan["compiled"](x)
         if self.profile_sync:
             # exact perf-counter mode: duration is on-device time and
@@ -2082,12 +2001,10 @@ class TpuEngine:
 
             jax.block_until_ready(y)
         t1 = _trace.now_ns()
-        if spans is not None:
-            _trace.stage("accl.finish", spans, "t_launched", t1)
+        mark(recs, ("t_launched",), t1, stage="accl.finish")
 
         self._scatter_back(plan, y)
-        if spans is not None:
-            _trace.stamp(spans, "t_rebound")
+        mark(recs, ("t_rebound",))
         return t1 - t0
 
     def _assemble_global(self, plan: dict, gang: dict):
@@ -2609,7 +2526,6 @@ class TpuDeviceView(CCLODevice):
             "plan_ring_generation": gen,
             "plan_ring_replays": ring_replays,
             "plan_replays": counters.get("plan_replays", 0),
-            "plan_auto_captures": counters.get("plan_auto_captures", 0),
             "leader_dispatches": counters.get("leader_dispatches", 0),
             "executor_dispatches": counters.get("executor_dispatches", 0),
             "batches": counters.get("batches", 0),

@@ -1,6 +1,7 @@
-"""End-to-end observability: per-call trace spans (Perfetto export),
-the metrics registry both backends and the bench harnesses publish
-into, the always-on flight recorder, the hang watchdog + health/
+"""End-to-end observability: one record per call (record.py), which
+the tracer's Perfetto export, the metrics registry both backends and
+the bench harnesses publish into, and the always-on flight recorder
+all read; the hang watchdog + health/
 OpenMetrics surface, and the r14 performance observatory — cross-rank
 critical-path attribution, the native-engine telemetry sampler, and
 the continuous regression sentinel.  See docs/observability.md and
@@ -11,7 +12,6 @@ from .attribution import (  # noqa: F401
     estimate_clock_skew,
 )
 from .flight import (  # noqa: F401
-    FlightRecord,
     FlightRecorder,
     dump_all as dump_all_flight,
     enabled as flight_enabled,
@@ -39,6 +39,7 @@ from .metrics import (  # noqa: F401
     size_bucket,
     validate_openmetrics,
 )
+from .record import CallRecord  # noqa: F401
 from .sentinel import (  # noqa: F401
     Baseline,
     Sentinel,
@@ -49,7 +50,6 @@ from .telemetry import (  # noqa: F401
 )
 from .trace import (  # noqa: F401
     TraceCollector,
-    TraceSpan,
     collector,
     disable as disable_tracing,
     enable as enable_tracing,

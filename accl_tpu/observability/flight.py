@@ -1,18 +1,19 @@
 """Always-on per-rank flight recorder: the collective black box.
 
 Unlike the opt-in tracer (``ACCL_TRACE``), the flight recorder is ON by
-default: every rank keeps a fixed-size, lock-cheap ring of the last N
-collective records — seq, collective, comm, dtype/shape, dispatch lane,
-state transitions (submitted → queued → gang-ready → dispatched →
-complete) and monotonic timestamps — so when a gang wedges in
+default: every rank keeps a fixed-size, lock-cheap ring of its last N
+call records (observability/record.py — the same object the tracer
+exports) — seq, collective, comm, dtype/shape, dispatch lane, state
+transitions (submitted → queued → gang-ready → dispatched → complete)
+and monotonic timestamps — so when a gang wedges in
 production there is always a recent history to dump, the way the
 reference CCLO's host-visible retcode/cycle-counter state machine keeps
 a wedged offload engine diagnosable (PAPER §driver/firmware; ACCL+,
 arxiv 2312.11742).
 
-Overhead discipline: one small ``__slots__`` object and a bounded
-``deque.append`` per call, plus a handful of attribute writes at each
-state transition — no locks on the record path (the per-rank seq comes
+Overhead discipline: a bounded ``deque.append`` of the call's record,
+plus a handful of attribute writes at each state transition
+(``record.mark``) — no locks on the record path (the per-rank seq comes
 from an atomic ``itertools.count``; ``deque`` appends are GIL-atomic).
 ``ACCL_FLIGHT=0`` turns it off entirely; ``ACCL_FLIGHT_CAP`` resizes
 the ring (default 512 records per rank).
@@ -33,33 +34,14 @@ import threading
 import weakref
 from typing import Iterable, Optional
 
+from .record import S_ABORTED, CallRecord
+from .record import S_COMPLETE, STATE_NAMES  # noqa: F401 — read here too
 from .trace import now_ns
-
-# record states, in lifecycle order (ints: one attribute write per
-# transition on the always-on path; names only materialize at dump time)
-S_SUBMITTED = 0
-S_QUEUED = 1
-S_GANG_READY = 2
-S_DISPATCHED = 3
-S_COMPLETE = 4
-S_FAILED = 5
-S_ABORTED = 6  # finalized by a communicator abort (COMM_ABORTED), not
-#              # an engine fault — a terminal state, never "in flight"
-S_RECOVERING = 7  # a recovery-supervisor phase record (non-gang): the
-#              # rank is mid detect->abort->probe->shrink/grow->resume;
-#              # finish() retires it complete/failed like any record
-STATE_NAMES = ("submitted", "queued", "gang_ready", "dispatched",
-               "complete", "failed", "aborted", "recovering")
 
 #: states that mean "this record is retired" — the hang analyzer and
 #: the watchdog must treat all three alike (an abort in flight is a
 #: recovery action, not a phantom hang)
 TERMINAL_STATE_NAMES = ("complete", "failed", "aborted")
-
-#: retcode bit marking an abort-finalized call (constants.ErrorCode.
-#: COMM_ABORTED; kept as a literal here so the always-on record path
-#: adds no import edge)
-_COMM_ABORTED_BIT = 1 << 27
 
 #: retcode bit for an engine receive-timeout (constants.ErrorCode.
 #: RECEIVE_TIMEOUT_ERROR) — the trigger for the r20 forensic capture
@@ -78,116 +60,9 @@ RECORD_SCHEMA_KEYS = (
 )
 
 
-class FlightRecord:
-    """One collective call's black-box record (mutated in place as the
-    call moves through the stack; the ring holds the live object, so a
-    dump mid-flight shows the exact stage a wedged call reached)."""
-
-    __slots__ = ("seq", "req_id", "rank", "collective", "comm", "tag",
-                 "dtype", "count", "nbytes", "nranks", "lane", "state",
-                 "gang", "retcode", "tenant", "t_submit", "t_queue",
-                 "t_gang_ready", "t_dispatch", "t_complete", "_recorder")
-
-    def __init__(self, recorder: "FlightRecorder", seq: int, req_id: int,
-                 collective: str, comm: int, tag: int, dtype: str,
-                 count: int, nbytes: int, nranks: int, gang: bool,
-                 t_submit: int, tenant: Optional[str] = None):
-        self._recorder = recorder
-        self.seq = seq
-        self.req_id = req_id
-        self.rank = recorder.rank
-        self.collective = collective
-        self.comm = comm
-        self.tag = tag
-        self.dtype = dtype
-        self.count = count
-        self.nbytes = nbytes
-        self.nranks = nranks
-        self.gang = gang
-        self.tenant = tenant
-        self.lane: Optional[str] = None
-        self.state = S_SUBMITTED
-        self.retcode = 0
-        self.t_submit = t_submit
-        self.t_queue = 0
-        self.t_gang_ready = 0
-        self.t_dispatch = 0
-        self.t_complete = 0
-
-    @property
-    def in_flight(self) -> bool:
-        # a recovery-phase record is a live episode until finish()
-        # retires it (it is never gang=True, so the watchdog's
-        # stuck-GANG scan and the merge hang analysis both skip it)
-        return self.state < S_COMPLETE or self.state == S_RECOVERING
-
-    def age_ns(self, now: Optional[int] = None) -> int:
-        """Nanoseconds since submit (in flight) or submit→complete."""
-        end = self.t_complete or (now if now is not None else now_ns())
-        return max(end - self.t_submit, 0)
-
-    def mark_dispatched(self, lane: str, t: int) -> None:
-        """The one dispatch-stamp used by every lane (emu descriptor
-        post, local/p2p, gang executor/leader/batched); a lane already
-        tagged is preserved."""
-        self.state = S_DISPATCHED
-        self.t_dispatch = t
-        if self.lane is None:
-            self.lane = lane
-
-    def mark_recovering(self, t: int) -> None:
-        """Flip a supervisor phase record into the live `recovering`
-        state (resilience/supervisor.py publishes one record per
-        state-machine transition; finish() retires it)."""
-        self.state = S_RECOVERING
-        self.t_dispatch = t
-        if self.lane is None:
-            self.lane = "supervisor"
-
-    def finish(self, retcode: int, t: int) -> None:
-        self.retcode = retcode
-        self.t_complete = t
-        if retcode == 0:
-            self.state = S_COMPLETE
-        elif retcode & _COMM_ABORTED_BIT:
-            self.state = S_ABORTED
-        else:
-            self.state = S_FAILED
-            if retcode & _RECEIVE_TIMEOUT_BIT:
-                # RECEIVE_TIMEOUT forensics (r20): capture the link
-                # rows + gang-assembly state at classification time —
-                # best-effort, never raising on the record path
-                self._recorder._note_timeout(self)
-        self._recorder._note_finished(self)
-
-    def summary(self, now: Optional[int] = None) -> str:
-        """One-line human rendering, used by error embedding and logs."""
-        return (f"seq={self.seq} {self.collective} comm={self.comm} "
-                f"state={STATE_NAMES[self.state]} lane={self.lane} "
-                f"dtype={self.dtype} count={self.count} "
-                f"age={self.age_ns(now) / 1e6:.1f}ms")
-
-    def to_dict(self, now: Optional[int] = None) -> dict:
-        return {
-            "seq": self.seq, "req_id": self.req_id, "rank": self.rank,
-            "collective": self.collective, "comm": self.comm,
-            "tag": self.tag, "dtype": self.dtype, "count": self.count,
-            "nbytes": self.nbytes, "nranks": self.nranks,
-            "lane": self.lane, "state": STATE_NAMES[self.state],
-            "gang": self.gang, "retcode": self.retcode,
-            "tenant": self.tenant,
-            "age_us": round(self.age_ns(now) / 1e3, 1),
-            "t_submit": self.t_submit, "t_queue": self.t_queue,
-            "t_gang_ready": self.t_gang_ready,
-            "t_dispatch": self.t_dispatch, "t_complete": self.t_complete,
-        }
-
-    def __repr__(self) -> str:  # pragma: no cover — debug aid
-        return f"FlightRecord(r{self.rank} {self.summary()})"
-
-
 class FlightRecorder:
-    """Fixed-size ring of one rank's last N FlightRecords."""
+    """Fixed-size ring of one rank's last N call records
+    (:class:`~accl_tpu.observability.record.CallRecord`)."""
 
     def __init__(self, rank: int, capacity: Optional[int] = None):
         from collections import deque
@@ -199,7 +74,7 @@ class FlightRecorder:
         # clear-error contract) — construction time, not the record path
         self.capacity = capacity if capacity is not None else env_int(
             "ACCL_FLIGHT_CAP", 512, minimum=1)
-        self._records: "deque[FlightRecord]" = deque(maxlen=self.capacity)
+        self._records: "deque[CallRecord]" = deque(maxlen=self.capacity)
         self._seq = itertools.count()
         #: highest seq that reached complete/failed (monotonic
         #: best-effort: lock-free, diagnostic — not a synchronization
@@ -220,23 +95,36 @@ class FlightRecorder:
         self._forensics: "deque" = deque(maxlen=8)
 
     # -- record path (always-on; keep it allocation + append only) -----
-    def new_record(self, req_id: int, collective: str, comm: int,
-                   tag: int, dtype: str, count: int, nbytes: int,
-                   nranks: int, gang: bool, t_submit: int,
-                   tenant: Optional[str] = None) -> FlightRecord:
-        rec = FlightRecord(self, next(self._seq), req_id, collective,
-                           comm, tag, dtype, count, nbytes, nranks, gang,
-                           t_submit, tenant)
+    def add(self, rec: CallRecord) -> CallRecord:
+        """Put one record in the ring: it takes this rank's next seq."""
+        rec.seq = next(self._seq)
+        rec._recorder = self
         self._records.append(rec)
         return rec
 
-    def _note_finished(self, rec: FlightRecord) -> None:
+    def new_record(self, req_id: int, collective: str, comm: int,
+                   tag: int, dtype: str, count: int, nbytes: int,
+                   nranks: int, gang: bool, t_submit: int,
+                   tenant: Optional[str] = None) -> CallRecord:
+        """A record of this rank that no driver call built (supervisor
+        phases, lifecycle anchors), added to the ring."""
+        return self.add(CallRecord(
+            collective, rank=self.rank, count=count, dtype=dtype,
+            nbytes=nbytes, nranks=nranks, comm=comm, tag=tag, gang=gang,
+            req_id=req_id, tenant=tenant, t_submit=t_submit))
+
+    def _note_finished(self, rec: CallRecord) -> None:
         if rec.seq > self.last_completed_seq:
             self.last_completed_seq = rec.seq
         if rec.retcode != 0:
             self.last_error_ns = rec.t_complete
-        if rec.state == S_ABORTED:
-            self.last_abort_ns = rec.t_complete
+            if rec.state == S_ABORTED:
+                self.last_abort_ns = rec.t_complete
+            elif rec.retcode & _RECEIVE_TIMEOUT_BIT:
+                # RECEIVE_TIMEOUT forensics (r20): capture the link
+                # rows + gang-assembly state at classification time —
+                # best-effort, never raising on the record path
+                self._note_timeout(rec)
 
     # -- RECEIVE_TIMEOUT forensics (r20, ROADMAP item 5 wedge) ---------
     def set_forensics_sources(self, sources: dict) -> None:
@@ -249,7 +137,7 @@ class FlightRecorder:
         still correlates with host logs."""
         self._forensics_sources = dict(sources)
 
-    def _note_timeout(self, rec: FlightRecord) -> None:
+    def _note_timeout(self, rec: CallRecord) -> None:
         if not self._forensics_sources:
             return
         import time as _time

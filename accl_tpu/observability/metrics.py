@@ -60,7 +60,7 @@ METRIC_HELP = {
     "accl_plans_captures": ("persistent collective plans captured + "
                             "armed (ACCL.capture_plan)"),
     "accl_plans_replays": ("plan replays issued through the submission "
-                           "ring (sync + async + auto lanes)"),
+                           "ring (sync + async lanes)"),
     "accl_plans_invalidations": ("plans fenced by an abort/epoch bump/"
                                  "membership change/reset — each one "
                                  "is a replay that was REFUSED instead "
@@ -135,8 +135,6 @@ METRIC_HELP = {
     "accl_engine_plan_ring_generation": ("max per-comm fence generation "
                                          "(abort/rebuild bumps it)"),
     "accl_engine_plan_ring_replays": "replays issued on live TPU rings",
-    "accl_engine_plan_auto_captures": ("plan rings armed by the "
-                                       "ACCL_PLAN_AUTO streak detector"),
     "accl_engine_leader_dispatches": ("gangs executed inline on the "
                                       "last-arriving rank's thread"),
     "accl_engine_executor_dispatches": "gangs executed on the executor",
@@ -210,8 +208,6 @@ METRIC_HELP = {
     "accl_batches": "fused executor dispatch batches",
     "accl_batched_gangs": "gangs fused into executor batches",
     "accl_plan_replays": "plan replays issued through the ring",
-    "accl_plan_auto_captures": ("plan rings armed by the ACCL_PLAN_AUTO "
-                                "streak detector"),
     "accl_compressed_tx_bytes": ("wire bytes the gang scheduler moved "
                                  "through a compressed lane (r17)"),
     "accl_compressed_tx_logical_bytes": (

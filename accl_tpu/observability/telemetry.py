@@ -146,7 +146,6 @@ COUNTER_FIELDS = frozenset((
     "compressed_tx_bytes",
     "compressed_tx_logical_bytes",
     # TPU dispatch-lane counters (TpuDeviceView.engine_stats)
-    "plan_auto_captures",
     "leader_dispatches",
     "executor_dispatches",
     "batches",

@@ -1,13 +1,14 @@
 """Structured per-call tracing: event spans + Perfetto export.
 
-Every collective call carries a :class:`TraceSpan` through the whole
+A traced call's span is its :class:`~accl_tpu.observability.record.
+CallRecord`, the one object that carries the call through the whole
 stack — submit (driver), queue-enter (request layer), gang-ready and
 dispatch (backend gang scheduler, lane-tagged: leader / executor /
 batched / emu), launch/launched (the host call of the compiled
 program), rebound (results rebound into the member buffers) and
-complete (the last thing before the waiter wakes).  Spans from every
+complete (the last thing before the waiter wakes).  Records from every
 rank of an in-process world land in one bounded ring buffer
-(:class:`TraceCollector`) and export as Chrome/Perfetto
+(:class:`TraceCollector`) at completion and export as Chrome/Perfetto
 ``trace_event`` JSON: one process (pid) per rank, one track (tid) per
 stage/lane, and gang members share a gang id so a fused gang program
 shows as one aligned slice across ranks.
@@ -19,16 +20,17 @@ each bench.
 
 Overhead discipline: tracing is OFF unless ``ACCL_TRACE`` is set
 (``1`` = collect, any other non-``0`` value = collect and dump to that
-path at exit).  When off, :func:`enabled` is a module-bool read and
-:func:`new_span` is never called — the instrumented hot paths allocate
-nothing (tests/test_observability.py pins this).
+path at exit).  When off, :func:`enabled` is a module-bool read, no
+record enters the collector and no annotation opens
+(tests/test_observability.py pins this).
 
-Stages on the profiler's clock: while tracing is on, :func:`stage`
-also opens a ``jax.profiler.TraceAnnotation`` per stage on the thread
-that does the work, so a JAX profiler capture (``traced_window`` with
-``ACCL_XLA_TRACE``, or any ``jax.profiler`` trace) holds the host
-stages on the same timeline as the device ops.  The span stamps and the
-annotations share their boundaries:
+Stages on the profiler's clock: while tracing is on, each boundary
+(``record.mark``) also switches a ``jax.profiler.TraceAnnotation`` per
+stage on the thread that does the work (:func:`stage`), so a JAX
+profiler capture (``traced_window`` with ``ACCL_XLA_TRACE``, or any
+``jax.profiler`` trace) holds the host stages on the same timeline as
+the device ops.  The record's stamps and the annotations share their
+boundaries:
 
 - ``accl.driver``, submit -> queue: descriptor, request, hand-off;
 - ``accl.gang``, queue -> gang join: ``TpuEngine.submit``, the join;
@@ -83,55 +85,6 @@ _collector: Optional["TraceCollector"] = None
 _atexit_armed = False
 
 
-class TraceSpan:
-    """One call's event record: monotonic ns timestamps per stage.
-
-    Unset stages stay None (e.g. gang-ready on the emulator backend,
-    whose native engine matches calls below the Python layer); export
-    skips slices whose endpoints are missing."""
-
-    __slots__ = ("name", "desc", "rank", "gang_id", "lane", "tenant",
-                 "count", "dtype", "nbytes", "nranks", "t_submit",
-                 "t_queue", "t_gang_ready", "t_dispatch", "t_launch",
-                 "t_launched", "t_rebound", "t_complete")
-
-    def __init__(self, name: str, desc: str = "", rank: int = -1,
-                 count: int = 0, dtype: str = "", nbytes: int = 0,
-                 nranks: int = 1):
-        self.name = name
-        self.desc = desc
-        self.rank = rank
-        self.gang_id: Optional[int] = None
-        self.lane: Optional[str] = None
-        #: tenant/lane label of the issuing communicator (r20) — spans
-        #: of a labeled tenant render on their own per-tenant call track
-        self.tenant: Optional[str] = None
-        self.count = count
-        self.dtype = dtype
-        self.nbytes = nbytes
-        self.nranks = nranks
-        self.t_submit: Optional[int] = None
-        self.t_queue: Optional[int] = None
-        self.t_gang_ready: Optional[int] = None
-        self.t_dispatch: Optional[int] = None
-        #: host clock immediately around the program call; on the chip
-        #: the call returns at enqueue, so this is NOT device time (the
-        #: profiler trace has that)
-        self.t_launch: Optional[int] = None
-        self.t_launched: Optional[int] = None
-        self.t_rebound: Optional[int] = None
-        self.t_complete: Optional[int] = None
-
-    def timestamps(self) -> dict:
-        return {k: getattr(self, "t_" + k) for k in (
-            "submit", "queue", "gang_ready", "dispatch", "launch",
-            "launched", "rebound", "complete")}
-
-    def __repr__(self) -> str:  # pragma: no cover — debug aid
-        return (f"TraceSpan({self.name!r}, rank={self.rank}, "
-                f"gang={self.gang_id}, lane={self.lane})")
-
-
 class TraceCollector:
     """Bounded ring buffer of completed spans + the gang-id registry.
 
@@ -156,7 +109,8 @@ class TraceCollector:
         self._occurrence: dict = {}
 
     # -- span intake ---------------------------------------------------
-    def add(self, span: TraceSpan) -> None:
+    def add(self, span) -> None:
+        """Take one completed record (record.CallRecord)."""
         with self._lock:
             self._spans.append(span)
 
@@ -271,13 +225,14 @@ class TraceCollector:
                     "timestamps_ns": s.timestamps()}
             call_track = ("call" if s.tenant is None
                           else f"call:{s.tenant}")
-            slice_ev(pid, call_track, s.name + gid, s.t_submit,
-                     s.t_complete, args)
-            slice_ev(pid, "queue", s.name + gid, s.t_queue,
+            name = s.collective + gid
+            slice_ev(pid, call_track, name, s.t_submit, s.t_complete,
+                     args)
+            slice_ev(pid, "queue", name, s.t_queue,
                      s.t_dispatch or s.t_complete,
                      {"gang_ready_ns": s.t_gang_ready})
             if s.lane is not None:
-                slice_ev(pid, f"lane:{s.lane}", s.name + gid,
+                slice_ev(pid, f"lane:{s.lane}", name,
                          s.t_launch or s.t_dispatch,
                          s.t_launched or s.t_complete,
                          dict(args, slice="host launch, not device time"))
@@ -396,17 +351,21 @@ def record_device_steps(collective: str, buf) -> None:
 
 
 def new_span(name: str, desc: str = "", rank: int = -1, count: int = 0,
-             dtype: str = "", nbytes: int = 0,
-             nranks: int = 1) -> Optional[TraceSpan]:
-    """Allocate a span for one call — returns None when tracing is off,
-    so callers hold the no-allocation fast path with one check."""
+             dtype: str = "", nbytes: int = 0, nranks: int = 1):
+    """A traced record (record.CallRecord) for a span that is not a
+    driver call, as a marked window — None when tracing is off, so
+    callers hold the no-allocation fast path with one check."""
     if not _enabled:
         return None
-    return TraceSpan(name, desc, rank, count, dtype, nbytes, nranks)
+    from .record import CallRecord
+
+    span = CallRecord(name, desc, rank, count, dtype, nbytes, nranks)
+    span.traced = True
+    return span
 
 
 # ---------------------------------------------------------------------------
-# stage boundaries: span stamps + profiler annotations
+# stage boundaries: profiler annotations
 # ---------------------------------------------------------------------------
 #: per thread, the ``accl.*`` annotation :func:`stage` last opened
 _stage_tls = threading.local()
@@ -424,26 +383,10 @@ def _open_annotation(name: str):
     return _annotation_cls(name)
 
 
-def stamp(spans, field: str, t: Optional[int] = None) -> None:
-    """Write `field` (``"t_launch"``, ...) on every span of `spans`
-    (None entries skipped) at one clock read, `t` where the caller
-    already read it."""
-    if t is None:
-        t = now_ns()
-    for span in spans:
-        if span is not None:
-            setattr(span, field, t)
-
-
-def stage(name: Optional[str], spans=(), field: Optional[str] = None,
-          t: Optional[int] = None) -> None:
-    """One stage boundary on the calling thread (tracing on): stamp
-    `field` on `spans` as :func:`stamp` does, then close the thread's
-    open ``accl.*`` annotation and open `name` (None: open none).
-    Callers gate on :func:`enabled` or on a request's span, so with
-    tracing off nothing here runs: no clock read, no annotation."""
-    if field is not None:
-        stamp(spans, field, t)
+def stage(name: Optional[str]) -> None:
+    """Close the calling thread's open ``accl.*`` annotation and open
+    `name` (None: open none).  Callers gate on :func:`enabled`
+    (``record.mark`` does), so with tracing off nothing here runs."""
     ann = getattr(_stage_tls, "ann", None)
     if ann is not None:
         ann.__exit__(None, None, None)

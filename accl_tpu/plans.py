@@ -31,9 +31,8 @@ training step loop issues — is
 Invalidation contract: ``abort`` / ``reset_errors`` /
 ``shrink_communicator`` / ``grow_communicator`` fence every plan
 touching the affected communicator, on both the driver and the engine
-side — a replay after the fence **raises** (explicit plans) or
-transparently **re-captures** (the ``ACCL_PLAN_AUTO`` lane); it never
-silently runs on a fenced epoch.
+side — a replay after the fence **raises**; it never silently runs on
+a fenced epoch.
 
 Knobs:
 
@@ -41,11 +40,6 @@ Knobs:
   :class:`EagerPlan` whose ``replay`` just re-runs the captured
   function through the normal per-call driver path (the A/B lane the
   callrate bench records as ``callrate_r12_plan_off``).
-- ``ACCL_PLAN_AUTO=N`` — transparent auto-capture: after ``N``
-  identical resident synchronous gang calls, the world's ranks agree
-  (through the gang itself — every member marks intent on the same
-  instance, so no rank ever replays against an eager peer) to arm a
-  one-step plan and route subsequent identical calls through the ring.
 """
 from __future__ import annotations
 
@@ -66,6 +60,7 @@ from .constants import (
 from .observability import flight as _flight
 from .observability import metrics as _metrics
 from .observability import trace as _trace
+from .observability.record import S_DISPATCHED, CallRecord, mark
 from .utils.logging import get_logger
 
 # ---------------------------------------------------------------------------
@@ -85,23 +80,21 @@ def set_enabled(on: bool) -> None:
     _enabled = bool(on)
 
 
-def auto_threshold() -> int:
-    """``ACCL_PLAN_AUTO``: identical-iteration streak after which the
-    driver transparently arms a one-step plan (0 = off, the default).
-    Honors the ``ACCL_PLAN=0`` kill switch."""
-    from .constants import env_int
-
-    if not _enabled:
-        return 0
-    return env_int("ACCL_PLAN_AUTO", 0, minimum=0)
-
-
 #: how long a capture waits for the sibling ranks of an in-process world
 #: to reach their own capture_plan before degrading to single-rank
 #: validation (the pooled cross-rank checks need every program)
 _POOL_TIMEOUT_S = 10.0
 
-_replay_ids = itertools.count(1 << 20)  # flight req ids, driver-disjoint
+_replay_ids = itertools.count(1 << 20)  # record req ids, driver-disjoint
+
+
+def _close_replay(rec: Optional[CallRecord], retcode: int) -> None:
+    """Retire one replay's record; traced, hand it to the collector."""
+    if rec is None:
+        return
+    rec.finish(retcode, _trace.now_ns())
+    if rec.traced:
+        _trace.collector().add(rec)
 
 
 # ---------------------------------------------------------------------------
@@ -235,10 +228,9 @@ class PlanTicket:
         self._done = True
         if self._error is None:
             plan._finish_replay(self._rec, 0)
-        elif self._rec is not None:
-            self._rec.finish(getattr(self._error, "code", 0)
-                             or int(ErrorCode.DMA_INTERNAL_ERROR),
-                             _trace.now_ns())
+        else:
+            _close_replay(self._rec, getattr(self._error, "code", 0)
+                          or int(ErrorCode.DMA_INTERNAL_ERROR))
         return True
 
     def check(self) -> None:
@@ -336,22 +328,14 @@ class CollectivePlan:
                 int(ErrorCode.COMM_ABORTED))
         for buf, count in self._staged_in:
             buf.slice(0, count).sync_to_device()
-        rec = None
-        if accl.flight_recorder is not None and _flight.enabled():
-            rec = accl.flight_recorder.new_record(
-                next(_replay_ids), "plan_replay", self._comm0, 0,
-                "plan", self._total_count, 0, len(self.members), True,
-                _trace.now_ns())
-            rec.mark_dispatched("plan", _trace.now_ns())
+        rec = self._replay_record()
         budget = accl.call_timeout_s if timeout is None else timeout
         try:
             token = self._device.plan_replay(
                 self._handle, run_async=run_async, timeout_s=budget)
         except ACCLError as e:
-            if rec is not None:
-                rec.finish(getattr(e, "code", 0)
-                           or int(ErrorCode.DMA_INTERNAL_ERROR),
-                           _trace.now_ns())
+            _close_replay(rec, getattr(e, "code", 0)
+                          or int(ErrorCode.DMA_INTERNAL_ERROR))
             self._note_replay_error(e)
             raise
         if run_async:
@@ -359,11 +343,30 @@ class CollectivePlan:
         self._finish_replay(rec, 0)
         return None
 
+    def _replay_record(self) -> Optional[CallRecord]:
+        """One replay's record, dispatched on the ``plan`` lane: in the
+        flight ring when the recorder is on, traced when tracing is
+        (None when neither is)."""
+        accl = self._accl
+        flight = accl.flight_recorder is not None and _flight.enabled()
+        traced = _trace.enabled()
+        if not (flight or traced):
+            return None
+        t = _trace.now_ns()
+        rec = CallRecord("plan_replay", rank=accl.rank,
+                         count=self._total_count, dtype="plan",
+                         nranks=len(self.members), comm=self._comm0,
+                         gang=True, req_id=next(_replay_ids), t_submit=t)
+        if flight:
+            accl.flight_recorder.add(rec)
+        rec.traced = traced
+        mark((rec,), ("t_dispatch",), t, state=S_DISPATCHED, lane="plan")
+        return rec
+
     def _finish_replay(self, rec, retcode: int) -> None:
         for buf, count in self._staged_out:
             buf.slice(0, count).sync_from_device()
-        if rec is not None:
-            rec.finish(retcode, _trace.now_ns())
+        _close_replay(rec, retcode)
         self.stats["replays"] += 1
         if _metrics.enabled():
             _metrics.default_registry().inc("plans/replays")

@@ -19,8 +19,8 @@ import threading
 from typing import Callable, Optional
 
 from .constants import ACCLError, OperationStatus, error_code_to_str
-from .observability import flight as _flight
 from .observability import trace as _trace
+from .observability.record import S_QUEUED, CallRecord, mark
 
 #: sentinel distinguishing "no timeout passed" (resolve the configurable
 #: default) from an explicit None (block forever, the pre-r8 behavior)
@@ -46,15 +46,6 @@ class Request:
     """Handle for one in-flight collective call."""
 
     _ids = itertools.count()
-
-    #: persistent-plan auto-capture lane (accl_tpu/plans.py,
-    #: ACCL_PLAN_AUTO): class-level defaults so the per-call hot path
-    #: pays ZERO extra attribute writes — the driver sets an instance
-    #: `plan_intent` only on streak calls, and the engine publishes the
-    #: armed ring as an instance `plan_ring` only on the one gang
-    #: instance where every member agreed.
-    plan_intent = False
-    plan_ring = None
 
     def __init__(self, description: str = "", sync: bool = False):
         self.id = next(Request._ids)
@@ -85,19 +76,13 @@ class Request:
         self.pre_wait: Optional[Callable[[], None]] = None
         #: exception raised by on_complete, surfaced via check()
         self.callback_error: Optional[Exception] = None
-        #: observability (accl_tpu/observability): `trace` is this
-        #: call's TraceSpan (None when tracing is off — the
-        #: zero-allocation fast path), `metric` is the driver-attached
-        #: (registry, collective, dtype, nbytes, nranks, t_submit_ns,
-        #: tenant) tuple published at completion.  Both set by
-        #: ACCL._execute.
-        self.trace: Optional[object] = None
-        self.metric: Optional[tuple] = None
-        #: always-on flight-recorder record (observability/flight.py);
-        #: None only when ACCL_FLIGHT=0 or the request predates
-        #: initialize.  Set by ACCL._observe_call; state transitions are
+        #: this call's lifecycle record (observability/record.py): the
+        #: flight ring, the metrics registry and the tracer all read it.
+        #: None only when every observer is off (ACCL_FLIGHT=0,
+        #: ACCL_METRICS=0, no ACCL_TRACE) or the request predates
+        #: initialize.  Set by ACCL._observe_call; each boundary is
         #: stamped in place by the queue and the backends.
-        self.flight: Optional[_flight.FlightRecord] = None
+        self.record: Optional[CallRecord] = None
         #: True once a wait() observed completion — the signal the
         #: collective sanitizer's leaked-request checker and
         #: ACCL.deinit() use to tell a drained async call from one
@@ -118,29 +103,24 @@ class Request:
             self._done.set()
 
     def _observe(self) -> None:
-        """Publish this call's completion to the observability layer:
-        the flight record's finish and the metrics observation (both at
-        one clock read), then the span's complete stamp — the last
-        event before the waiter wakes, so the observers' own cost lies
-        inside the span.  Observer failures must never lose the
-        completion event."""
-        rec, metric, span = self.flight, self.metric, self.trace
-        if metric is None and span is None and rec is None:
+        """Close this call's record: the metrics observation, then the
+        complete stamp — the last event before the waiter wakes, so the
+        observers' own cost lies inside the record — and, traced, the
+        hand-over to the collector.  Observer failures must never lose
+        the completion event."""
+        rec = self.record
+        if rec is None:
             return
         try:
-            if rec is not None or metric is not None:
-                t_end = _trace.now_ns()
-                if rec is not None:
-                    rec.finish(self.retcode, t_end)
-                if metric is not None:
-                    reg, coll, dtype, nbytes, nranks, t0, tenant = metric
-                    reg.observe_call(coll, dtype, nbytes, t_end - t0,
-                                     nranks, ok=self.retcode == 0,
-                                     engine_ns=self.duration_ns,
-                                     tenant=tenant)
-            if span is not None:
-                span.t_complete = _trace.now_ns()
-                _trace.collector().add(span)
+            if rec.registry is not None:
+                rec.registry.observe_call(
+                    rec.collective, rec.dtype, rec.nbytes,
+                    _trace.now_ns() - rec.t_submit, rec.nranks,
+                    ok=self.retcode == 0, engine_ns=self.duration_ns,
+                    tenant=rec.tenant)
+            rec.finish(self.retcode, _trace.now_ns())
+            if rec.traced:
+                _trace.collector().add(rec)
         except Exception:  # pragma: no cover — observability is best-effort
             pass
 
@@ -165,8 +145,8 @@ class Request:
     def flight_info(self) -> str:
         """The flight-recorder view of this call, for error embedding
         ('' when the recorder is off): seq, state, lane, age."""
-        rec = self.flight
-        if rec is None:
+        rec = self.record
+        if rec is None or rec.seq < 0:
             return ""
         return f" [flight: {rec.summary()}]"
 
@@ -230,13 +210,8 @@ class RequestQueue:
     def submit(self, request: Request, start_fn: Callable[[Request], None]) -> Request:
         with self._lock:
             request.status = OperationStatus.EXECUTING
-            rec, span = request.flight, request.trace
-            if rec is not None or span is not None:
-                t = _trace.now_ns()
-                if rec is not None:
-                    rec.t_queue = t
-                    rec.state = _flight.S_QUEUED
-                if span is not None:  # hand-off: accl.driver ends here
-                    _trace.stage(None, (span,), "t_queue", t)
+            if request.record is not None:  # hand-off: accl.driver ends
+                mark((request.record,), ("t_queue",), state=S_QUEUED,
+                     stage=None)
             start_fn(request)
         return request

@@ -58,6 +58,7 @@ from ..observability import flight as _flight
 from ..observability import health as _health
 from ..observability import metrics as _metrics
 from ..observability import trace as _trace
+from ..observability.record import S_RECOVERING, mark
 from ..utils.logging import get_logger
 from .elastic import MembershipBoard, admit_pending
 from .membership import probe_alive
@@ -167,7 +168,8 @@ class RecoverySupervisor:
                     self.rec = fr.new_record(
                         -1, f"recovery/{name}", comm_id, 0, "none", 0, 0,
                         1, False, t)
-                    self.rec.mark_recovering(t)
+                    mark((self.rec,), ("t_dispatch",), t,
+                         state=S_RECOVERING, lane="supervisor")
                 sup._note(name)
                 return self
 

@@ -618,7 +618,7 @@ def test_comm_abort_retires_flight_record_like_sanitizer(emu_world):
     time.sleep(0.1)
     emu_world.accls[0].abort(0)
     assert reqs[1].wait(30.0)
-    rec = reqs[1].flight
+    rec = reqs[1].record
     assert rec is not None and not rec.in_flight
     assert obs_flight.STATE_NAMES[rec.state] == "aborted"
     assert "COMM_ABORTED" in error_code_to_str(rec.retcode)

@@ -15,6 +15,7 @@ import pytest
 
 from accl_tpu import ReduceFunction
 from accl_tpu.observability import attribution, flight, trace
+from accl_tpu.observability.record import CallRecord
 
 NRANKS = 4
 COUNT = 256
@@ -223,7 +224,7 @@ def test_merge_flight_dumps_tolerates_torn_merged_doc(tmp_path):
 def test_merge_trace_files_tolerates_torn_tail(tmp_path):
     coll = trace.TraceCollector()
     for k in range(6):
-        span = trace.TraceSpan("allreduce", rank=0, count=16)
+        span = CallRecord("allreduce", rank=0, count=16)
         span.t_submit = 1000 + k
         span.t_complete = 2000 + k
         span.gang_id = k

@@ -14,6 +14,7 @@ from accl_tpu import ACCLError, ReduceFunction
 from accl_tpu.observability import flight as obs_flight
 from accl_tpu.observability import health as obs_health
 from accl_tpu.observability import metrics as obs_metrics
+from accl_tpu.observability.record import S_RECOVERING, mark
 from accl_tpu.observability.trace import now_ns
 
 COUNT = 64
@@ -122,9 +123,9 @@ def test_check_on_in_flight_request_embeds_flight_record():
 
     recr = obs_flight.FlightRecorder(rank=3)
     req = Request("allreduce(SUM)")
-    req.flight = recr.new_record(req.id, "allreduce", 0, 0, "float32",
+    req.record = recr.new_record(req.id, "allreduce", 0, 0, "float32",
                                  64, 256, 4, True, now_ns())
-    req.flight.lane = "emu"
+    req.record.lane = "emu"
     with pytest.raises(ACCLError) as ei:
         req.check()
     msg = str(ei.value)
@@ -455,7 +456,7 @@ def test_flight_record_recovering_state():
     rec_ring = obs_flight.FlightRecorder(0, capacity=8)
     rec = rec_ring.new_record(-1, "recovery/shrink", 0, 0, "none", 0, 0,
                               1, False, obs_flight.now_ns())
-    rec.mark_recovering(obs_flight.now_ns())
+    mark((rec,), ("t_dispatch",), state=S_RECOVERING, lane="supervisor")
     assert obs_flight.STATE_NAMES[rec.state] == "recovering"
     assert rec.in_flight and not rec.gang
     assert rec.lane == "supervisor"

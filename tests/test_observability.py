@@ -53,7 +53,7 @@ def _allreduce_all_ranks(world, reps=1):
 def test_span_ordering_invariants(tracing):
     with _tpu_world() as w:
         _allreduce_all_ranks(w, reps=2)
-    spans = [s for s in tracing.spans() if s.name == "allreduce"]
+    spans = [s for s in tracing.spans() if s.collective == "allreduce"]
     assert len(spans) == 2 * NRANKS
     for s in spans:
         ts = s.timestamps()
@@ -72,7 +72,7 @@ def test_span_ordering_invariants(tracing):
 def test_multi_rank_gang_id_merge(tracing):
     with _tpu_world() as w:
         _allreduce_all_ranks(w, reps=3)
-    spans = [s for s in tracing.spans() if s.name == "allreduce"]
+    spans = [s for s in tracing.spans() if s.collective == "allreduce"]
     by_gang = {}
     for s in spans:
         by_gang.setdefault(s.gang_id, []).append(s)
@@ -99,13 +99,69 @@ def test_disabled_mode_zero_allocation(tracing, monkeypatch):
                 np.arange(COUNT, dtype=np.float32))
             r = accl.create_buffer(COUNT, np.float32)
             req = accl.allreduce(s, r, COUNT, ReduceFunction.SUM)
-            assert req.trace is None  # zero-allocation fast path
+            # nothing for the collector
+            assert req.record is None or not req.record.traced
             return True
 
         w.run(fn)
     assert obs_trace.new_span("x") is None
     assert len(tracing) == 0
     assert opened == []
+
+
+# ---------------------------------------------------------------------------
+# one record per call, on the lanes the gang-stage test does not cover
+# ---------------------------------------------------------------------------
+#: per lane, the stamps its record leaves None: the stages it has not
+LANE_UNSET = {
+    "local": ("gang_ready", "rebound"),
+    "p2p": ("gang_ready", "rebound"),
+    "emu": ("gang_ready", "rebound"),
+    "plan": ("queue", "gang_ready", "launch", "launched", "rebound"),
+}
+
+
+@pytest.mark.parametrize("lane", list(LANE_UNSET))
+def test_one_record_per_call(tracing, lane):
+    """The flight ring and the trace collector hold the same object for
+    a call; its stamps are in lifecycle order and None only where the
+    lane has no such stage."""
+    from accl_tpu.backends.emu import EmuWorld
+    from accl_tpu.backends.tpu import TpuWorld
+    from accl_tpu.observability import flight as obs_flight
+
+    def fn(accl, rank):
+        s = accl.create_buffer_like(
+            np.arange(COUNT, dtype=np.float32) + rank)
+        r = accl.create_buffer(COUNT, np.float32)
+        if lane == "local":
+            return accl.copy(s, r, COUNT).record
+        if lane == "p2p":
+            if rank == 0:
+                accl.send(s, COUNT, 1, tag=3)
+                return None
+            return accl.recv(r, COUNT, 0, tag=3).record
+        if lane == "emu":
+            return accl.allreduce(s, r, COUNT, ReduceFunction.SUM).record
+        s.sync_to_device()
+        plan = accl.capture_plan(lambda a: a.allreduce(
+            s, r, COUNT, ReduceFunction.SUM, from_fpga=True,
+            to_fpga=True))
+        plan.replay()
+        return [x for x in accl.flight_recorder.records()
+                if x.collective == "plan_replay"][-1]
+
+    with (EmuWorld(2) if lane == "emu" else TpuWorld(2)) as w:
+        rec = w.run(fn)[1]
+        ring = w.accls[1].flight_recorder.records()
+    assert any(x is rec for x in ring)
+    assert any(x is rec for x in tracing.spans())
+    assert rec.lane == lane
+    assert obs_flight.STATE_NAMES[rec.state] == "complete"
+    ts = rec.timestamps()
+    assert tuple(k for k, v in ts.items() if v is None) == LANE_UNSET[lane]
+    stamped = [v for v in ts.values() if v is not None]
+    assert stamped == sorted(stamped)
 
 
 # ---------------------------------------------------------------------------
@@ -147,7 +203,7 @@ def test_emu_backend_spans_and_merge(tracing):
             return r.host.copy()
 
         w.run(fn)
-    spans = [s for s in tracing.spans() if s.name == "allreduce"]
+    spans = [s for s in tracing.spans() if s.collective == "allreduce"]
     assert len(spans) == NRANKS
     assert len({s.gang_id for s in spans}) == 1  # one merged gang
     assert sorted(s.rank for s in spans) == list(range(NRANKS))
@@ -161,7 +217,7 @@ def test_emu_backend_spans_and_merge(tracing):
 def test_traced_window_and_merge_files(tracing, tmp_path):
     with obs_trace.traced_window("unit"):
         pass
-    spans = [s for s in tracing.spans() if s.name == "window:unit"]
+    spans = [s for s in tracing.spans() if s.collective == "window:unit"]
     assert len(spans) == 1 and spans[0].lane == "window"
     # merge: two single-file traces with a shared gang id align clocks
     p1, p2 = str(tmp_path / "a.json"), str(tmp_path / "b.json")

@@ -1,6 +1,6 @@
 """Persistent collective plans (accl_tpu/plans.py): capture/replay
-bitwise fidelity, capture-time validation, invalidation fencing, the
-ACCL_PLAN=0 kill switch, and the ACCL_PLAN_AUTO transparent lane.
+bitwise fidelity, capture-time validation, invalidation fencing and the
+ACCL_PLAN=0 kill switch.
 
 The bitwise contract: a captured plan replayed N times must produce
 exactly the byte streams the same N iterations produce through the
@@ -8,7 +8,6 @@ eager per-call driver path — on both the emulator engine (native C plan
 ring, one FFI per replay) and the TPU backend (PlanRing, one rendezvous
 per replay).
 """
-import os
 
 import numpy as np
 import pytest
@@ -292,91 +291,6 @@ def test_plan_kill_switch_eager_lane():
             assert np.array_equal(outs[rank], ref[rank])
     finally:
         plans_mod.set_enabled(True)
-
-
-def test_auto_capture_lane():
-    """ACCL_PLAN_AUTO=N: after N identical resident sync gang calls the
-    world transparently arms a one-step ring and replays through it —
-    results identical, engine counters prove the lane fired."""
-    os.environ["ACCL_PLAN_AUTO"] = "3"
-    try:
-        with TpuWorld(NRANKS) as w:
-            store: dict = {}
-
-            def setup(accl, rank):
-                s = accl.create_buffer_like(_data(rank))
-                s.sync_to_device()
-                r = accl.create_buffer(COUNT, np.float32)
-                store[rank] = (s, r)
-
-            w.run(setup)
-
-            def loop(accl, rank):
-                s, r = store[rank]
-                for _ in range(10):
-                    accl.allreduce(s, r, COUNT, ReduceFunction.SUM,
-                                   from_fpga=True, to_fpga=True)
-                r.sync_from_device()
-                return r.host.copy()
-
-            outs = w.run(loop)
-            stats = w.engine.stats
-            assert stats["plan_auto_captures"] == 1
-            assert stats["plan_replays"] >= 5
-        expected = sum(_data(rank) for rank in range(NRANKS))
-        for rank in range(NRANKS):
-            assert np.allclose(outs[rank], expected, atol=1e-4)
-    finally:
-        del os.environ["ACCL_PLAN_AUTO"]
-
-
-def test_auto_capture_refenced_after_abort():
-    """Auto lane + abort: the fenced ring is dropped, the next call
-    fast-fails on the aborted comm (never a silent stale replay), and
-    after recovery the lane re-captures transparently."""
-    os.environ["ACCL_PLAN_AUTO"] = "2"
-    try:
-        with TpuWorld(2) as w:
-            store: dict = {}
-
-            def setup(accl, rank):
-                s = accl.create_buffer_like(_data(rank))
-                s.sync_to_device()
-                r = accl.create_buffer(COUNT, np.float32)
-                store[rank] = (s, r)
-
-            w.run(setup)
-
-            def loop(accl, rank, iters):
-                s, r = store[rank]
-                for _ in range(iters):
-                    accl.allreduce(s, r, COUNT, ReduceFunction.SUM,
-                                   from_fpga=True, to_fpga=True)
-
-            w.run(loop, 5)
-            assert w.engine.stats["plan_auto_captures"] == 1
-            w.accls[0].abort(0)
-
-            def fenced(accl, rank):
-                with pytest.raises(ACCLError) as ei:
-                    loop(accl, rank, 1)
-                assert "aborted" in str(ei.value)
-
-            w.run(fenced)
-
-            def recover(accl, rank):
-                accl.reset_errors()
-
-            w.run(recover)
-            w.run(loop, 5)  # re-captures and finishes clean
-            assert w.engine.stats["plan_auto_captures"] == 2
-        expected = sum(_data(rank) for rank in range(2))
-        for rank in range(2):
-            s, r = store[rank]
-            r.sync_from_device()
-            assert np.allclose(r.host, expected, atol=1e-4)
-    finally:
-        del os.environ["ACCL_PLAN_AUTO"]
 
 
 def test_plan_metrics_family():

@@ -342,7 +342,7 @@ def test_abort_flight_record_terminal_state_and_health():
         time.sleep(0.2)
         world.accls[0].abort(0)
         assert reqs[1].wait(30.0)
-        rec = reqs[1].flight
+        rec = reqs[1].record
         assert rec is not None
         assert obs_flight.STATE_NAMES[rec.state] == "aborted"
         assert not rec.in_flight

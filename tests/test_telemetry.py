@@ -785,8 +785,8 @@ def test_perf_doctor_link_matrix_section(tmp_path):
 
 
 def test_tpu_plan_replay_traffic_lands_in_link_matrix():
-    """The plan-replay lane is the dominant steady-state traffic under
-    ACCL_PLAN_AUTO — replayed collectives must account into the link
+    """The plan-replay lane is the whole steady-state traffic of a
+    captured plan — replayed collectives must account into the link
     twin exactly like eager gang dispatches (a matrix that goes dark
     when plans kick in would mis-model precisely the hot traffic)."""
     from accl_tpu.backends.tpu import TpuWorld

@@ -1,6 +1,6 @@
-"""Stage spans of the TPU engine's dispatch path (observability/trace.py
-``stage``): on the leader, executor and batched lanes every gang call's
-span is stamped queue <= gang_ready <= dispatch <= launch <= launched <=
+"""Stage stamps of the TPU engine's dispatch path (observability/record.py
+``mark``): on the leader, executor and batched lanes every gang call's
+record is stamped queue <= gang_ready <= dispatch <= launch <= launched <=
 rebound <= complete, and its four stages (gang, marshal, launch,
 finish) sum to queue -> complete; under ``jax.profiler`` the same
 stages are ``accl.*`` annotations on the profiler's clock, and each
@@ -14,6 +14,7 @@ import pytest
 
 from accl_tpu import ReduceFunction
 from accl_tpu.observability import trace as obs_trace
+from accl_tpu.observability.record import CallRecord, mark
 
 COUNT = 64
 STAGES = ("accl.driver", "accl.gang", "accl.marshal", "accl.launch",
@@ -75,7 +76,7 @@ def _batched_calls(world, reps):
 
 
 def _check_spans(spans, lane, n):
-    spans = [s for s in spans if s.name == "allreduce"]
+    spans = [s for s in spans if s.collective == "allreduce"]
     assert len(spans) == n
     for s in spans:
         assert s.lane == lane
@@ -151,8 +152,9 @@ def test_profiler_holds_one_stage_per_call(tracing, tmp_path):
 
 
 def test_stage_helper_stamps_and_switches(monkeypatch):
-    """stage(): one clock read stamps every span, the thread's open
-    annotation closes before the next opens, None opens nothing."""
+    """record.mark(): one clock read stamps every record, and, tracing
+    on, the thread's open annotation closes before the next opens, None
+    opens nothing, the default leaves it alone."""
     log = []
 
     class Ann:
@@ -164,11 +166,12 @@ def test_stage_helper_stamps_and_switches(monkeypatch):
             log.append(("close", self.name))
 
     monkeypatch.setattr(obs_trace, "_open_annotation", Ann)
-    a, b = obs_trace.TraceSpan("x"), obs_trace.TraceSpan("y")
-    obs_trace.stage("accl.marshal", [a, None, b], "t_dispatch", t=7)
-    obs_trace.stage("accl.launch", [a, b], "t_launch")
-    obs_trace.stamp([a, b], "t_rebound", t=9)
-    obs_trace.stage(None)
+    monkeypatch.setattr(obs_trace, "_enabled", True)
+    a, b = CallRecord("x"), CallRecord("y")
+    mark([a, None, b], ("t_dispatch",), t=7, stage="accl.marshal")
+    mark([a, b], ("t_launch",), stage="accl.launch")
+    mark([a, b], ("t_rebound",), t=9)
+    mark([], (), stage=None)
     assert a.t_dispatch == b.t_dispatch == 7
     assert a.t_launch == b.t_launch and a.t_launch > 0
     assert a.t_rebound == b.t_rebound == 9
