@@ -69,29 +69,36 @@ def _import_jax():
     return jax, jnp, Mesh, NamedSharding, PartitionSpec
 
 
-def _global_constructor(nelems: int, dtype, sharding) -> Callable:
-    """A constructor of the 1-D global array of `nelems` elements from
-    its single-device shards, given as a list in `sharding`'s device
-    order.  The aval and sharding are fixed here, once, and a call skips
-    the per-call validation and rearrangement that
+def _global_constructor(shape: tuple, dtype, sharding) -> Callable:
+    """A constructor of the global array of `shape` from its
+    single-device shards, given as a list in `sharding`'s device order.
+    The aval and sharding are fixed here, once, and a call skips the
+    per-call validation and rearrangement that
     ``jax.make_array_from_single_device_arrays`` repeats: sound only for
-    shards that are whole, of the global's dtype and committed to their
-    devices — what a plan's all-fast operands are (jax 0.9 ``ArrayImpl``;
-    a missing or deleted shard still raises)."""
+    shards that are whole, of the global's dtype and shard shape and
+    committed to their devices — what a plan's all-fast operands are
+    (jax 0.9 ``ArrayImpl``; a missing or deleted shard still raises)."""
     from functools import partial
 
     from jax._src import core
     from jax._src.array import ArrayImpl
 
     aval = core.update_aval_with_sharding(
-        core.ShapedArray((nelems,), np.dtype(dtype)), sharding)
+        core.ShapedArray(tuple(shape), np.dtype(dtype)), sharding)
     return partial(ArrayImpl, aval, sharding, committed=True,
                    _skip_checks=True)
 
 
 class TpuBuffer(BaseBuffer):
     """Host numpy array paired with a single-device jax.Array resident on
-    this rank's device (the FPGABuffer analog: host map + device BO)."""
+    this rank's device (the FPGABuffer analog: host map + device BO).
+
+    The device array may have any shape whose size is the buffer's
+    length (its *form*): a buffer starts 1-D, and a whole-buffer write
+    or an alltoallv payload rebinds it in the written array's shape —
+    ``[rows, row]`` for an alltoallv's payload, so a model reads and
+    writes its rows with no relayout.  Element ranges (partial writes,
+    slices, the host mirror) act on a flat view of it."""
 
     def __init__(self, host: np.ndarray, device, jax_device, address: int):
         super().__init__(host, address)
@@ -110,10 +117,20 @@ class TpuBuffer(BaseBuffer):
     def dev(self):
         return self._dev
 
+    @property
+    def flat(self):
+        """The device array as 1-D elements (a relayout on the device
+        when the buffer holds rows)."""
+        dev = self._dev
+        return dev if dev.ndim == 1 else dev.reshape(-1)
+
     def set_dev_range(self, start: int, values) -> None:
-        """Write `values` into device elements [start, start+len)."""
-        if start == 0 and values.shape[0] == self._dev.shape[0] \
-                and values.dtype == self._dev.dtype:
+        """Write `values` into device elements [start, start+size).  A
+        write of the whole buffer adopts the array in its own shape."""
+        dev = self._dev
+        same = values.shape == dev.shape
+        if start == 0 and values.dtype == dev.dtype and (
+                same or values.size == dev.size):
             # full overwrite: adopt the array instead of dispatching a
             # device scatter (the gang path's per-rank hot path); keep
             # the buffer pinned to its rank's device — host-built values
@@ -123,16 +140,22 @@ class TpuBuffer(BaseBuffer):
             if getattr(values, "device", None) != self._jax_device:
                 values = jax.device_put(values, self._jax_device)
             self._dev = values
+            if not same:
+                self._device.reform(self)
             return
-        self._dev = self._dev.at[start:start + values.shape[0]].set(values)
+        self._dev = self.flat.at[start:start + values.size].set(
+            values.reshape(-1)).reshape(dev.shape)
 
     def sync_to_device(self) -> None:
         import jax
 
-        self._dev = jax.device_put(self._host.copy(), self._jax_device)
+        host = self._host.copy()
+        if self._dev is not None:   # keep the buffer's form
+            host = host.reshape(self._dev.shape)
+        self._dev = jax.device_put(host, self._jax_device)
 
     def sync_from_device(self) -> None:
-        self._host[:] = np.asarray(self._dev)
+        self._host[:] = np.asarray(self._dev).reshape(-1)
 
     def slice(self, start: int, end: int) -> "BaseBuffer":
         return _TpuBufferSlice(self, start, end)
@@ -169,7 +192,7 @@ class _TpuBufferSlice(BaseBuffer):
 
     def sync_from_device(self) -> None:
         self._parent.host[self._start:self._end] = np.asarray(
-            self._parent.dev[self._start:self._end])
+            self._parent.flat[self._start:self._end])
 
     def slice(self, start: int, end: int) -> "BaseBuffer":
         return _TpuBufferSlice(self._parent, self._start + start,
@@ -311,10 +334,13 @@ class TpuEngine:
         #: ``assemble_checked`` counts executions whose operands took the
         #: checked assembly (_assemble_global); the prepared share is
         #: 1 - assemble_checked / (sum of the lane counters).
+        #: ``a2av_flat_operands`` counts alltoallv payload operands that
+        #: arrived out of row form and were reshaped at assembly.
         self.metrics = _metrics.MetricsRegistry()
         for k in ("leader_dispatches", "executor_dispatches", "batches",
                   "batched_gangs", "plan_replays",
-                  *_LANE_COUNTERS, "assemble_checked"):
+                  *_LANE_COUNTERS, "assemble_checked",
+                  "a2av_flat_operands"):
             self.metrics.inc(k, 0)
         self._log = get_logger("accl_tpu.tpu")
         # per-link wire telemetry twin (r15): (src rank, comm, peer
@@ -350,6 +376,7 @@ class TpuEngine:
         self._plan_rings: list = []
         self._plan_board: list = []
         self._plan_cv = threading.Condition()
+        self._reform_lock = threading.Lock()   # see reform
         self._comm_gen: dict = {}
         # kernel streams: (rank, strm_id) -> deque of np arrays
         self._streams: dict[tuple[int, int], deque] = {}
@@ -383,11 +410,43 @@ class TpuEngine:
         rank = self._dev_to_rank[buf._jax_device]
         with self._lock:
             self._buffers[rank].pop(buf.address, None)
+        self._evict_plans(lambda plan: any(
+            o[0] == rank and (o[1] is buf or o[4] is buf)
+            for o in plan["ops"] + (plan.get("count_ops") or [])))
+
+    def _evict_plans(self, binds: Callable) -> None:
+        """Drop the cached gang plans for which `binds(plan)` holds."""
+        with self._lock:
             for sig in [sig for sig, plan in self._gang_plans.items()
-                        if any(o[0] == rank and (o[1] is buf or o[4] is buf)
-                               for o in plan["ops"]
-                               + (plan.get("count_ops") or []))]:
+                        if binds(plan)]:
                 del self._gang_plans[sig]
+
+    def reform(self, buf: TpuBuffer) -> None:
+        """`buf`'s device array changed form: re-plan what bound the old
+        one — a cached gang plan or an armed ring's slot that takes it as
+        an operand of a 1-D global (any collective's but an alltoallv
+        payload, which each call checks) is built anew.  Runs on a form
+        change only, never on a call that keeps the form."""
+        rank = self._dev_to_rank[buf._jax_device]
+
+        def binds(plan):
+            if plan is None:
+                return False
+            opnds = (plan["ops"] if plan["count_ops"] is None
+                     else plan["count_ops"])
+            return any(o[0] == rank and o[1] is buf for o in opnds)
+
+        self._evict_plans(binds)
+        with self._plan_cv:
+            rings = list(self._plan_rings)
+        # one re-plan at a time: a slot's new plan reads every member's
+        # form, and must not be overwritten by one that read less
+        with self._reform_lock:
+            for ring in rings:
+                for slot in ring.slots:
+                    if slot["kind"] == "gang" and binds(slot["plan"]):
+                        slot["plan"] = self._gang_plan(
+                            slot["op"], slot["comm"], slot["gang"])
 
     def resolve(self, rank: int, addr: int):
         """Map a descriptor address to (buffer, element offset)."""
@@ -513,7 +572,7 @@ class TpuEngine:
             vals = vals[:n]
         else:
             src, soff = self.resolve(rank, call.addr_0)
-            vals = src.dev[soff:soff + n]
+            vals = src.flat[soff:soff + n]
         if call.stream_flags & StreamFlags.RES_STREAM:
             self._push_stream(rank, call.tag, vals)
             return
@@ -529,7 +588,7 @@ class TpuEngine:
         op1, o1 = self.resolve(rank, call.addr_1)
         res, o2 = self.resolve(rank, call.addr_2)
         n = call.count
-        a, b = op0.dev[o0:o0 + n], op1.dev[o1:o1 + n]
+        a, b = op0.flat[o0:o0 + n], op1.flat[o1:o1 + n]
         # mixed-precision combine: arithmetic in the widest operand dtype,
         # result cast to the result buffer's representation (the arithcfg
         # lane selection, arithconfig.py; per-operand OP0/OP1/RES flags)
@@ -548,7 +607,7 @@ class TpuEngine:
         if call.stream_flags & StreamFlags.OP0_STREAM:
             data = self._krnl_in[rank].popleft()[:n]
         else:
-            data = src.dev[soff:soff + n]
+            data = src.flat[soff:soff + n]
         if call.compression_flags & CompressionFlags.ETH_COMPRESSED:
             data = _wire_roundtrip(data, self.wire_dtype_for(call.arithcfg))
         members = self._comms[call.comm]
@@ -1391,8 +1450,8 @@ class TpuEngine:
         else:  # p2p: pre-paired direct device-to-device move
             import jax
 
-            data = slot["src"].dev[slot["soff"]:slot["soff"]
-                                   + slot["n"]]
+            data = slot["src"].flat[slot["soff"]:slot["soff"]
+                                    + slot["n"]]
             if slot["wire"]:
                 data = _wire_roundtrip(data, slot["wire"])
             moved = jax.device_put(data, self.devices[slot["dst_rank"]])
@@ -1800,7 +1859,7 @@ class TpuEngine:
                 if buf is None:
                     buf, off = self.resolve(g, call.addr_2)
                 fast = (buf is not None and off == 0
-                        and buf.dev.shape[0] == in_len
+                        and buf.dev.shape == (in_len,)
                         and buf.dev.dtype == dtype)
             write_out = not (op in (Operation.reduce, Operation.gather)
                              and li != root)
@@ -1843,7 +1902,7 @@ class TpuEngine:
             # bound here in the sharding's device order
             by_dev = {self.devices[o[0]]: o[1] for o in ops}
             prepared = (
-                _global_constructor(nranks * in_len, dtype, sharding),
+                _global_constructor((nranks * in_len,), dtype, sharding),
                 tuple(by_dev[d]
                       for d in sharding._addressable_device_assignment))
         plan = {
@@ -1891,7 +1950,14 @@ class TpuEngine:
         """An alltoallv gang's plan: two operands (the payload and the
         int32 send counts) and two results (the payload and the receive
         counts), each a global sharded over the members.  Every buffer
-        must be whole and resident, so the plan is always prepared."""
+        must be whole and resident, so the plan is always prepared.
+
+        The payload globals are in row form, ``[P * rows, row]`` sharded
+        on the rows: a send buffer that holds ``[rows, row]`` is its own
+        shard, and the receive buffer is rebound to its ``[rows, row]``
+        shard.  A send that arrives in another form (a first call, a 1-D
+        write) is reshaped once at assembly, rebound in row form and
+        counted under ``a2av_flat_operands``."""
         _jax, _jnp, _Mesh, NamedSharding, P = _import_jax()
         nranks = len(members)
         mesh = self._mesh_for(tuple(members))
@@ -1914,10 +1980,10 @@ class TpuEngine:
                 bufs.append(b)
             send, scount, recv, rcount = bufs
             for b in (scount, rcount):
-                if b.dev.dtype != np.int32 or b.dev.shape[0] != nranks:
+                if b.dev.dtype != np.int32 or b.dev.shape != (nranks,):
                     raise ACCLError(f"rank {g}: alltoallv counts must be "
                                     f"int32 buffers of {nranks}")
-            shapes.add((send.dev.shape[0], recv.dev.shape[0],
+            shapes.add((send.dev.size, recv.dev.size,
                         str(send.dev.dtype), str(recv.dev.dtype)))
             ops.append((g, send, 0, True, recv, 0, False, None))
             count_ops.append((g, scount, 0, True, rcount, 0, False, None))
@@ -1932,11 +1998,21 @@ class TpuEngine:
         order = sharding._addressable_device_assignment
         by_dev = {self.devices[o[0]]: o[1] for o in ops}
         counts_by_dev = {self.devices[o[0]]: o[1] for o in count_ops}
-        build_x = _global_constructor(nranks * send_len, dtype, sharding)
-        build_c = _global_constructor(nranks * nranks, np.int32, sharding)
+        rows = (send_len // row, row)
+        build_x = _global_constructor((nranks * rows[0], row), dtype,
+                                      sharding)
+        build_c = _global_constructor((nranks * nranks,), np.int32,
+                                      sharding)
+        sends = tuple(by_dev[d] for d in order)
 
         def build(devs):
-            return build_x(devs[:nranks]), build_c(devs[nranks:])
+            xs = devs[:nranks]
+            for i, x in enumerate(xs):
+                if x.shape != rows:   # out of row form: one relayout
+                    self.metrics.inc("a2av_flat_operands")
+                    sends[i]._dev = xs[i] = x.reshape(rows)
+                    self.reform(sends[i])
+            return build_x(xs), build_c(devs[nranks:])
 
         fn_args = (mesh, nranks, send_len // row, recv_len // row, row,
                    dtype)
@@ -1949,8 +2025,10 @@ class TpuEngine:
             "sharding": sharding,
             "compiled": _alltoallv_fn(*fn_args),
             "ops": ops,
-            "prepared": (build, tuple(by_dev[d] for d in order)
+            "prepared": (build, sends
                          + tuple(counts_by_dev[d] for d in order)),
+            # the receive's row form, which _scatter_back rebinds it in
+            "recv_rows": (recv_len // row, row),
             "links": self._link_deltas(Operation.alltoallv, comm_id,
                                        members, 0, 0),
             "fn_args": fn_args,
@@ -2035,7 +2113,7 @@ class TpuEngine:
                 # member's own thread (call-order stream pairing)
                 shard = jnp.asarray(gang[g][2])[:in_len]
             else:
-                shard = buf.dev[off:off + in_len]
+                shard = buf.flat[off:off + in_len]
             if shard.dtype != dtype:
                 shard = shard.astype(dtype)
             if shard.shape[0] < in_len:  # placeholder short buffer (bcast)
@@ -2066,6 +2144,18 @@ class TpuEngine:
             got = dict(zip(order, (s.data for s in counts.addressable_shards)))
             for g, _b, _o, _f, res, _ro, _os, _rt in count_ops:
                 res._dev = got[g]
+            rows = plan["recv_rows"]
+            for g, _b, _o, _f, res, _ro, _os, _rt in plan["ops"]:
+                # the payload's [rows, row] shard, whatever form the
+                # receive held before (or a substituted program gave)
+                out = out_shards[g]
+                if out.shape != rows:
+                    out = out.reshape(rows)
+                reformed = res._dev.shape != rows
+                res._dev = out
+                if reformed:
+                    self.reform(res)
+            return
         for g, _buf, _off, _fast, res, roff, _op0s, res_tag in plan["ops"]:
             if res_tag is not None:
                 # RES_STREAM: the member's result lands in its local
@@ -2075,7 +2165,7 @@ class TpuEngine:
             if res is None:
                 continue
             out = out_shards[g]
-            if (roff == 0 and out.shape[0] == res.dev.shape[0]
+            if (roff == 0 and out.shape == res.dev.shape
                     and out.dtype == res.dev.dtype):
                 # whole-buffer result already on the right device: adopt
                 # directly (the set_dev_range fast path minus its
@@ -2408,11 +2498,13 @@ def _alltoallv_fn(mesh, nranks: int, send_rows: int, recv_rows: int,
     """Build + AOT-compile one alltoallv program: the members' P x P
     count matrix is gathered on the device, the send and receive
     offsets derived from it, and the rows moved by
-    ``ragged_all_to_all`` on ``[rows, row]`` views.  Where the compiler
-    lacks that op, the same program exchanges ``[P, send_rows, row]``
-    blocks with ``all_to_all`` and packs them (both forms write zeros
-    past the received total).  The compiled callable takes and
-    returns a (payload, counts) pair of globals."""
+    ``ragged_all_to_all``.  Where the compiler lacks that op, the same
+    program exchanges ``[P, send_rows, row]`` blocks with ``all_to_all``
+    and packs them (both forms write zeros past the received total).
+    The compiled callable takes and returns a (payload, counts) pair of
+    globals; the payloads are in row form, ``[P * send_rows, row]`` in
+    and ``[P * recv_rows, row]`` out, sharded on the rows, so a shard is
+    a member's ``[rows, row]`` buffer with no relayout on either side."""
     import jax
     import jax.numpy as jnp
     from jax import shard_map
@@ -2420,8 +2512,7 @@ def _alltoallv_fn(mesh, nranks: int, send_rows: int, recv_rows: int,
 
     ragged = _ragged_exchange_supported(mesh)
 
-    def body(x, c):
-        xv = x.reshape(send_rows, row)
+    def body(xv, c):
         m = jax.lax.all_gather(c, "rank")     # m[i, j]: rows i -> j
         r = jax.lax.axis_index("rank")
         send, recv = m[r], m[:, r]
@@ -2431,10 +2522,10 @@ def _alltoallv_fn(mesh, nranks: int, send_rows: int, recv_rows: int,
             # ranks before me send it
             before = jnp.arange(nranks)[:, None] < r
             out_off = jnp.sum(jnp.where(before, m, 0), axis=0)
-            out = jnp.zeros((recv_rows, row), x.dtype)
+            out = jnp.zeros((recv_rows, row), xv.dtype)
             y = jax.lax.ragged_all_to_all(xv, out, in_off, send, out_off,
                                           recv, axis_name="rank")
-            return y.reshape(-1), recv
+            return y, recv
         k = jnp.arange(send_rows)
         padded = jnp.concatenate([xv, jnp.zeros_like(xv)])
         blocks = jnp.stack([
@@ -2449,12 +2540,12 @@ def _alltoallv_fn(mesh, nranks: int, send_rows: int, recv_rows: int,
                           nranks - 1)
         pos = jnp.clip(t - (ends - recv)[src], 0, send_rows - 1)
         y = jnp.where((t < ends[-1])[:, None], got[src, pos], 0)
-        return y.reshape(-1), recv
+        return y, recv
 
     fn = shard_map(body, mesh=mesh, in_specs=(P("rank"), P("rank")),
                    out_specs=(P("rank"), P("rank")), check_vma=False)
     sh = NamedSharding(mesh, P("rank"))
-    args = (jax.ShapeDtypeStruct((nranks * send_rows * row,),
+    args = (jax.ShapeDtypeStruct((nranks * send_rows, row),
                                  np.dtype(dtype), sharding=sh),
             jax.ShapeDtypeStruct((nranks * nranks,), np.int32, sharding=sh))
     return jax.jit(lambda xc: fn(*xc)).lower(args).compile()
@@ -2530,6 +2621,7 @@ class TpuDeviceView(CCLODevice):
             "executor_dispatches": counters.get("executor_dispatches", 0),
             "batches": counters.get("batches", 0),
             "batched_gangs": counters.get("batched_gangs", 0),
+            "a2av_flat_operands": counters.get("a2av_flat_operands", 0),
             "ready_depth": ready_depth,
         }
 

@@ -26,7 +26,10 @@ a replica of the router and of the shared expert.  A layer call is:
   expert and the residual.
 
 The counts stay on the device from the router to the exchange: the
-host reads none of them.  The receive side is sized for the dropless
+host reads none of them.  The payloads stay in row form, ``[rows,
+hidden]``, from the router through both exchanges to the combine: the
+driver keeps an alltoallv buffer in its call's row form, so no stage
+relayouts them.  The receive side is sized for the dropless
 worst case (every rank's every row to this one), so no token is dropped
 at any skew.  A device-side tally of the rows moved is read on request
 (:meth:`EPMoELayer.stats`), never per call.
@@ -110,15 +113,15 @@ def select(cfg: EPMoEConfig, x, p):
 
 @partial(jax.jit, static_argnums=(0, 1))
 def _route(cfg: EPMoEConfig, nranks: int, x, p):
-    """Router, sorted send rows, per-expert and per-rank counts, the
-    shared expert.  x: [T, H] bf16."""
+    """Router, sorted send rows [T*k, H], per-expert and per-rank
+    counts, the shared expert.  x: [T, H] bf16."""
     k = cfg.top_k
     xf = x.astype(jnp.float32)
     h, sel, gates = _router(cfg, x, p)
     eid = sel.reshape(-1)
     order = jnp.argsort(eid, stable=True).astype(jnp.int32)   # [T*k]
     hb = h.astype(jnp.bfloat16)
-    rows = hb[order // k].reshape(-1)
+    rows = hb[order // k]
     ecounts = jnp.bincount(eid, length=cfg.n_experts).astype(jnp.int32)
     pcounts = ecounts.reshape(nranks, -1).sum(1).astype(jnp.int32)
     a = _silu_glu(jnp.dot(hb, p["s1"], preferred_element_type=jnp.float32),
@@ -191,26 +194,26 @@ def _grouped_matmul(x, w, sizes, interpret: bool):
 @partial(jax.jit, static_argnums=(0, 1))
 def _experts(cfg: EPMoEConfig, interpret: bool, rows, counts, p):
     """The held experts on the received rows, where they landed.  rows:
-    [cap * H] bf16 in source order, each source's rows grouped by local
+    [cap, H] bf16 in source order, each source's rows grouped by local
     expert; counts: [P * held] int32, rows from source i for local
     expert e at ``i * held + e``.  Group ``g`` is (source ``g // held``,
     local expert ``g % held``) and meets that expert's weights, so the
-    output is in the received order the combine sends back; no row is
-    permuted.  Rows past the received total are unspecified."""
+    output, [cap, H], is in the received order the combine sends back;
+    no row is permuted or relayouted.  Rows past the received total are
+    unspecified."""
     mm = partial(_grouped_matmul, sizes=counts, interpret=interpret)
-    xs = rows.reshape(-1, cfg.hidden)
-    a = _silu_glu(mm(xs, p["w1"]), mm(xs, p["w3"])).astype(jnp.bfloat16)
-    return mm(a, p["w2"]).reshape(-1)
+    a = _silu_glu(mm(rows, p["w1"]), mm(rows, p["w3"])).astype(jnp.bfloat16)
+    return mm(a, p["w2"])
 
 
 @partial(jax.jit, static_argnums=(0, 1), donate_argnums=(8,))
 def _combine(cfg: EPMoEConfig, rank: int, back, order, gates, base,
              sent, recvd, tally):
-    """Un-permute the returned rows, weight and sum them, add the shared
-    expert and the residual (``base``); fold the layer into the tally."""
+    """Un-permute the returned rows (``back``: [T*k, H]), weight and sum
+    them, add the shared expert and the residual (``base``); fold the
+    layer into the tally."""
     T = base.shape[0]
-    rows = back.reshape(T * cfg.top_k, cfg.hidden)
-    y = rows[_inverse(order)]
+    y = back[_inverse(order)]
     y = y.reshape(T, cfg.top_k, cfg.hidden).astype(jnp.float32)
     out = base + jnp.sum(gates[..., None] * y, axis=1)
     moved = jnp.stack([jnp.sum(sent) - sent[rank], jnp.sum(recvd),

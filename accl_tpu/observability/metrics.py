@@ -140,6 +140,8 @@ METRIC_HELP = {
     "accl_engine_executor_dispatches": "gangs executed on the executor",
     "accl_engine_batches": "fused executor dispatch batches",
     "accl_engine_batched_gangs": "gangs fused into executor batches",
+    "accl_engine_a2av_flat_operands": (
+        "alltoallv payload operands reshaped into row form at assembly"),
     "accl_engine_ready_depth": ("complete gangs queued behind the "
                                 "executor right now"),
     # ---- per-call collective families (observe_call) ----
@@ -216,6 +218,9 @@ METRIC_HELP = {
         "gang executions whose operands took the checked assembly "
         "(slice, widening, stream or padded operand); the rest were "
         "assembled from the plan's prepared aval and sharding"),
+    "accl_a2av_flat_operands": (
+        "alltoallv payload operands that arrived out of row form "
+        "([rows, row]) and were reshaped at assembly"),
 }
 
 #: HELP for families minted with dynamic name parts (bench lane labels,

@@ -150,6 +150,7 @@ COUNTER_FIELDS = frozenset((
     "executor_dispatches",
     "batches",
     "batched_gangs",
+    "a2av_flat_operands",
 ))
 
 

@@ -82,6 +82,181 @@ def test_alltoallv_matches_numpy(backend, case):
                               want.view(np.uint8)), (backend, case, r)
 
 
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_alltoallv_row_form_equals_flat(case):
+    """On the XLA:CPU fallback, the same exchange from row-form sends
+    ([rows, row] device arrays) and from flat host-written ones: the
+    receives hold the same rows and are rebound in row form."""
+    import jax.numpy as jnp
+
+    from accl_tpu.backends.tpu import TpuWorld
+
+    row, cap, dtype, counts = CASES[case]
+    counts = np.asarray(counts, np.int32)
+    sends = _sends(row, cap, dtype, seed=len(case))
+
+    def fn(a, r):
+        sc = a.create_buffer(P, np.int32)
+        sc.host[:] = counts[r]
+        sc.sync_to_device()
+        got = []
+        for rows in (False, True):
+            s = a.create_buffer(cap * row, dtype)
+            if rows:
+                s.set_dev_range(0, jnp.asarray(sends[r].reshape(cap, row)))
+            else:
+                s.host[:] = sends[r]
+                s.sync_to_device()
+            recv = a.create_buffer(P * cap * row, dtype)
+            rc = a.create_buffer(P, np.int32)
+            a.alltoallv(s, recv, sc, rc, row=row, from_fpga=True,
+                        to_fpga=True)
+            got.append((np.asarray(recv.dev), np.asarray(rc.dev),
+                        s.dev.shape))
+        return got
+
+    with TpuWorld(P) as world:
+        got = world.run(fn)
+        assert world.engine.stats["a2av_flat_operands"] == P
+    for r, (want, want_counts) in enumerate(_expected(sends, counts, row)):
+        n = want.shape[0]
+        (flat, flat_rc, _), (rows, rows_rc, _) = got[r]
+        assert flat.shape == rows.shape == (P * cap, row)
+        assert flat_rc.tolist() == rows_rc.tolist() == want_counts.tolist()
+        assert np.array_equal(rows[:n].view(np.uint8),
+                              flat[:n].view(np.uint8)), (case, r)
+        assert np.array_equal(rows[:n].view(np.uint8), want.view(np.uint8))
+        assert got[r][0][2] == got[r][1][2] == (cap, row)
+
+
+def test_flat_send_is_reshaped_once_and_counted():
+    """A send written flat ends in row form after its exchange and
+    counts one ``a2av_flat_operands``; a second call on it counts none,
+    and a flat write counts again."""
+    import jax.numpy as jnp
+
+    from accl_tpu.backends.tpu import TpuWorld
+
+    row, cap = 4, 6
+    counts = np.array([[1, 2, 3, 0]] * P, np.int32)
+
+    def fn(a, r):
+        s = a.create_buffer(cap * row, np.float32)
+        s.set_dev_range(0, jnp.arange(cap * row, dtype=np.float32) + r)
+        recv = a.create_buffer(P * cap * row, np.float32)
+        sc = a.create_buffer(P, np.int32)
+        sc.set_dev_range(0, jnp.asarray(counts[r]))
+        rc = a.create_buffer(P, np.int32)
+        kw = dict(row=row, from_fpga=True, to_fpga=True)
+        seen = [s.dev.shape]
+        for write in (False, False, True):
+            if write:
+                s.set_dev_range(0, jnp.arange(cap * row, dtype=np.float32))
+            a.alltoallv(s, recv, sc, rc, **kw)
+            a.barrier()
+            seen.append((s.dev.shape,
+                         world.engine.stats["a2av_flat_operands"]))
+            a.barrier()
+        return seen
+
+    with TpuWorld(P) as world:
+        got = world.run(fn)
+    for seen in got:
+        assert seen == [(cap * row,), ((cap, row), P), ((cap, row), P),
+                        ((cap, row), 2 * P)]
+
+
+#: name -> what is done to a buffer of 12 float32 that holds [3, 4] rows
+ROW_FORM_OPS = ("partial_write", "host_round_trip", "slice",
+                "allreduce_after_reform", "replay_after_reform",
+                "flat_write")
+
+
+@pytest.mark.parametrize("what", ROW_FORM_OPS)
+def test_row_form_buffer(what):
+    """A buffer whose device array holds rows: element writes, the host
+    mirror and slices act on its flat view and keep the rows, and a
+    collective's cached plan that bound it flat is built anew."""
+    import jax.numpy as jnp
+
+    from accl_tpu.backends.tpu import TpuWorld
+    from accl_tpu.constants import ReduceFunction
+
+    base = np.arange(12, dtype=np.float32)
+
+    def fn(a, r):
+        b = a.create_buffer(12, np.float32)
+        b.set_dev_range(0, jnp.asarray(base.reshape(3, 4)))
+        assert b.dev.shape == (3, 4)
+        want = base.copy()
+        if what == "partial_write":
+            b.set_dev_range(5, jnp.asarray([-1.0, -2.0], np.float32))
+            want[5:7] = [-1, -2]
+        elif what == "host_round_trip":
+            b.sync_from_device()
+            assert b.host.tolist() == base.tolist()
+            b.host[2] = 40.0
+            b.sync_to_device()
+            want[2] = 40
+        elif what == "slice":
+            sl = b.slice(4, 8)
+            sl.host[:] = [7, 7, 7, 7]
+            sl.sync_to_device()
+            sl.host[:] = 0
+            sl.sync_from_device()
+            assert sl.host.tolist() == [7, 7, 7, 7]
+            want[4:8] = 7
+        elif what == "allreduce_after_reform":
+            flat = a.create_buffer(12, np.float32)
+            out = a.create_buffer(12, np.float32)
+            kw = dict(from_fpga=True, to_fpga=True)
+            flat.set_dev_range(0, jnp.asarray(base))
+            a.allreduce(flat, out, 12, ReduceFunction.SUM, **kw)
+            a.barrier()
+            checked = world.engine.stats["assemble_checked"]
+            a.barrier()
+            # the cached plan bound `flat` as a 1-D operand; now it holds
+            # rows: the plan is built anew, and reads it through the
+            # checked assembly's flat view
+            flat.set_dev_range(0, jnp.asarray(2 * base).reshape(3, 4))
+            a.allreduce(flat, out, 12, ReduceFunction.SUM, **kw)
+            a.barrier()
+            assert world.engine.stats["assemble_checked"] == checked + 1
+            assert np.asarray(out.dev).reshape(-1).tolist() == \
+                (2 * P * base).tolist()
+            a.allreduce(b, out, 12, ReduceFunction.SUM, **kw)
+            want = P * base
+            b = out
+        elif what == "replay_after_reform":
+            flat = a.create_buffer(12, np.float32)
+            out = a.create_buffer(12, np.float32)
+            flat.set_dev_range(0, jnp.asarray(base))
+            plan = a.capture_plan(lambda x: x.allreduce(
+                flat, out, 12, ReduceFunction.SUM, from_fpga=True,
+                to_fpga=True))
+            plan.replay()
+            a.barrier()
+            checked = world.engine.stats["assemble_checked"]
+            # an armed ring's slot that bound `flat` flat is re-planned
+            flat.set_dev_range(0, jnp.asarray(3 * base).reshape(3, 4))
+            a.barrier()
+            plan.replay()
+            a.barrier()
+            assert world.engine.stats["assemble_checked"] == checked + 1
+            b, want = out, 3 * P * base
+        elif what == "flat_write":
+            b.set_dev_range(0, jnp.asarray(base + 1))
+            assert b.dev.shape == (12,)
+            want = base + 1
+        if what in ("partial_write", "host_round_trip", "slice"):
+            assert b.dev.shape == (3, 4)
+        b.sync_from_device()
+        return b.host.tolist() == want.tolist()
+
+    with TpuWorld(P) as world:
+        assert all(world.run(fn))
+
+
 def test_alltoallv_counts_stay_on_device_and_links_carry_no_bytes():
     """Device-resident operands: the host copies of the counts are never
     written, and the link twin records one message per peer pair with

@@ -203,7 +203,12 @@ def test_driver_alltoallv_moonlight_4_chips(ring4, send_rows, recv_rows):
     compiled = _alltoallv_fn(ring4, 4, send_rows, recv_rows, 2048,
                              "bfloat16")
     assert time.perf_counter() - t0 < 30
-    assert "ragged-all-to-all" in compiled.as_text()
+    text = compiled.as_text()
+    assert "ragged-all-to-all" in text
+    # row form: a chip's program takes and returns its [rows, 2048] shard
+    entry = next(l for l in text.splitlines() if l.startswith("ENTRY"))
+    assert f"bf16[{send_rows},2048]" in entry.split("->")[0], entry
+    assert f"-> (bf16[{recv_rows},2048]" in entry, entry
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < HBM_BYTES
@@ -212,7 +217,11 @@ def test_driver_alltoallv_moonlight_4_chips(ring4, send_rows, recv_rows):
 def test_moe_experts_moonlight_one_chip(one_chip):
     # ep_alltoall.4chip's expert layer on one chip's dropless receive
     # (4 x 8192 x 6 rows of 2048): the grouped matmuls run on the rows
-    # where they landed, with no sort or row index over the capacity
+    # where they landed, with no sort or row index over the capacity,
+    # and read and write them in the exchange's row form with no
+    # relayout of a capacity-sized array
+    import re
+
     from accl_tpu.models import moe_ep
 
     cfg = moe_ep.EPMoEConfig(hidden=2048, n_experts=64, top_k=6,
@@ -226,11 +235,17 @@ def test_moe_experts_moonlight_one_chip(one_chip):
     p = {"w1": shape(held, 2048, 1408), "w3": shape(held, 2048, 1408),
          "w2": shape(held, 1408, 2048)}
     compiled = moe_ep._experts.lower(
-        cfg, False, shape(cap * 2048), shape(4 * held, dtype=jnp.int32),
+        cfg, False, shape(cap, 2048), shape(4 * held, dtype=jnp.int32),
         p).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert " sort(" not in text and f"s32[{cap}]" not in text
+    relayouts = [l for l in text.splitlines()
+                 if re.search(r"= bf16\[(196608|24576),[^=]* (reshape|copy)\(",
+                              l)]
+    assert not relayouts, relayouts
+    entry = next(l for l in text.splitlines() if l.startswith("ENTRY"))
+    assert f"-> bf16[{cap},2048]" in entry, entry
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < HBM_BYTES

@@ -2,6 +2,8 @@
 driver on four CPU devices, against the plain float32 reference
 (accl_tpu.models.moe_ep_reference) on seeded weights, at a small size:
 hidden 64, 16 experts, top-6, expert width 32, two layers."""
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -95,6 +97,9 @@ def test_layer_matches_reference_three_calls_a_layer():
     # every one assembled from prepared operands
     assert engine["lane_hlo"] == 3 * L
     assert engine["assemble_checked"] == 0
+    # the layer writes its sends in the exchange's row form: no send is
+    # reshaped at assembly
+    assert engine["a2av_flat_operands"] == 0
     # dropless: every (token, slot) row lands on some rank, and comes back
     stats = [s for _o, s in got]
     assert all(s["calls"] == L for s in stats)
@@ -102,6 +107,94 @@ def test_layer_matches_reference_three_calls_a_layer():
     assert all(s["dispatch_offchip_bytes"]
                == s["dispatch_offchip_rows"] * CFG.hidden * 2
                for s in stats)
+
+
+@partial(jax.jit, static_argnums=(0,))
+def _flat_experts(cfg, rows, counts, p):
+    """The expert layer as it ran on 1-D driver buffers: the received
+    rows reshaped in, the output flattened out."""
+    mm = partial(moe_ep._grouped_matmul, sizes=counts, interpret=True)
+    xs = rows.reshape(-1, cfg.hidden)
+    a = moe_ep._silu_glu(mm(xs, p["w1"]), mm(xs, p["w3"]))
+    return mm(a.astype(jnp.bfloat16), p["w2"]).reshape(-1)
+
+
+@partial(jax.jit, static_argnums=(0,))
+def _flat_combine(cfg, back, order, gates, base):
+    """The combine as it read a 1-D receive."""
+    T = base.shape[0]
+    y = back.reshape(T * cfg.top_k, cfg.hidden)[moe_ep._inverse(order)]
+    y = y.reshape(T, cfg.top_k, cfg.hidden).astype(jnp.float32)
+    return (base + jnp.sum(gates[..., None] * y, axis=1)).astype(
+        jnp.bfloat16)
+
+
+def _flat_layer_call(layer, x, p):
+    """One layer call as the driver ran it on 1-D buffers, on `layer`'s
+    buffers: every payload written and read flat."""
+    cfg, a = layer.cfg, layer.accl
+    kw = dict(from_fpga=True, to_fpga=True)
+    rows, ecounts, pcounts, order, gates, base = moe_ep._route(
+        cfg, layer.nranks, x, p)
+    layer.dispatch_send.set_dev_range(0, rows.reshape(-1))
+    layer.ecount_send.set_dev_range(0, ecounts)
+    layer.dispatch_count.set_dev_range(0, pcounts)
+    a.alltoall(layer.ecount_send, layer.ecount_recv, layer.per_rank, **kw)
+    a.alltoallv(layer.dispatch_send, layer.dispatch_recv,
+                layer.dispatch_count, layer.dispatch_recv_count,
+                row=cfg.hidden, **kw)
+    out = _flat_experts(cfg, layer.dispatch_recv.flat,
+                        layer.ecount_recv.dev, p)
+    layer.combine_send.set_dev_range(0, out)
+    a.alltoallv(layer.combine_send, layer.combine_recv,
+                layer.dispatch_recv_count, layer.combine_recv_count,
+                row=cfg.hidden, **kw)
+    return _flat_combine(cfg, layer.combine_recv.flat, order, gates, base)
+
+
+def test_row_form_layer_equals_the_flat_form():
+    """The layer on row-form buffers gives the flat form's output bit
+    for bit; the flat form's 1-D sends are each reshaped at assembly
+    and counted, the row form's none."""
+    from accl_tpu.backends.tpu import TpuWorld
+
+    layers, xs = _params(9), _inputs(10)
+
+    def fn(a, r):
+        dev = world.engine.devices[r]
+        rowf = moe_ep.EPMoELayer(a, CFG, T)
+        flat = moe_ep.EPMoELayer(a, CFG, T)
+        held = slice(rowf.held.start, rowf.held.stop)
+        ps = [jax.device_put({k: (v[held] if k in ("w1", "w3", "w2")
+                                  else v) for k, v in p.items()}, dev)
+              for p in layers]
+        x = y = jax.device_put(xs[r], dev)
+        outs = []
+        for p in ps:
+            x = rowf(x, p)
+            outs.append(np.asarray(x))
+        a.barrier()
+        mid = world.engine.stats["a2av_flat_operands"]
+        a.barrier()
+        for l, p in enumerate(ps):
+            y = _flat_layer_call(flat, y, p)
+            outs[l] = (outs[l], np.asarray(y))
+        forms = (rowf.dispatch_recv.dev.shape, rowf.combine_recv.dev.shape)
+        rowf.close()
+        flat.close()
+        return outs, mid, forms
+
+    with TpuWorld(P) as world:
+        got = world.run(fn)
+        flat_ops = world.engine.stats["a2av_flat_operands"]
+    cap = -(-P * T * CFG.top_k // moe_ep._TILE_ROWS) * moe_ep._TILE_ROWS
+    for outs, mid, forms in got:
+        assert mid == 0
+        assert forms == ((cap, CFG.hidden), (T * CFG.top_k, CFG.hidden))
+        for rowf, flat in outs:
+            assert np.array_equal(rowf.view(np.uint16), flat.view(np.uint16))
+    # every flat call's dispatch and combine send, on every rank
+    assert flat_ops == 2 * P * L
 
 
 def test_no_token_dropped_under_extreme_skew():
@@ -172,11 +265,11 @@ def test_experts_in_received_order_match_permuted_experts(case):
     cap = -(-full // tile) * tile
     # rows past the received total hold noise here: none may leak out
     rows = jnp.asarray(rng.standard_normal((cap, CFG.hidden)),
-                       jnp.bfloat16).reshape(-1)
+                       jnp.bfloat16)
     p = {k: v[:held] for k, v in _params(8)[0].items()
          if k in ("w1", "w3", "w2")}
-    got = np.asarray(moe_ep._experts(CFG, True, rows, counts, p)
-                     ).reshape(cap, CFG.hidden)
+    got = np.asarray(moe_ep._experts(CFG, True, rows, counts, p))
+    assert got.shape == (cap, CFG.hidden)   # row form in and out
     want = np.asarray(_permuted_experts(nranks, rows, counts, p))
     # the same products in f32, summed in another order: at most one
     # bf16 rounding step apart (rows past the total are unspecified)
