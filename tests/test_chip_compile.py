@@ -251,6 +251,68 @@ def test_moe_experts_moonlight_one_chip(one_chip):
             + mem.temp_size_in_bytes) < HBM_BYTES
 
 
+def _longcat_decode(held=8, tokens=256):
+    from accl_tpu.models import scmoe
+
+    return scmoe.ScMoEConfig.from_hf({
+        "hidden_size": 6144, "n_routed_experts": 512, "moe_topk": 12,
+        "expert_ffn_hidden_size": 2048, "ffn_hidden_size": 12288,
+        "zero_expert_num": 256, "zero_expert_type": "identity",
+        "routed_scaling_factor": 6, "rms_norm_eps": 1e-5},
+        (tuple(range(held)),)), tokens * held
+
+
+@pytest.mark.parametrize("cap", [2048, 8192])
+def test_moe_experts_longcat_decode_one_chip(one_chip, cap):
+    # scmoe_decode.1chip's expert layer: 8 held [6144, 2048] experts on a
+    # 256-token decode capacity (128-row tiles, whole weights in VMEM),
+    # and on a 4x capacity (512-row tiles: the weight blocked along N)
+    from accl_tpu.models import moe_ep
+
+    cfg, _ = _longcat_decode()
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    p = {"w1": shape(8, 6144, 2048), "w3": shape(8, 6144, 2048),
+         "w2": shape(8, 2048, 6144)}
+    compiled = moe_ep._experts.lower(
+        cfg.moe, False, shape(cap, 6144), shape(8, dtype=jnp.int32),
+        p).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert moe_ep._tiles(cap, 6144, 2048, 8)[0] == (128 if cap == 2048
+                                                    else 512)
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES
+
+
+def test_scmoe_route_and_dense_decode_one_chip(one_chip):
+    # the ScMoE layer's softmax router over 768 experts (top-12, f32) and
+    # its two dense [6144, 12288] FFNs on a 256-token step
+    from accl_tpu.models import moe_ep, scmoe
+
+    cfg, _ = _longcat_decode()
+    H, D = 6144, 12288
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    p = {"norm": shape(H), "norm4": shape(H),
+         "router": shape(768, H, dtype=jnp.float32),
+         "bias": shape(768, dtype=jnp.float32),
+         "ffn1_w1": shape(H, D), "ffn1_w3": shape(H, D),
+         "ffn1_w2": shape(D, H), "ffn2_w1": shape(H, D),
+         "ffn2_w3": shape(H, D), "ffn2_w2": shape(D, H)}
+    x = shape(256, H)
+    for compiled in (moe_ep._route.lower(cfg.moe, 1, x, p).compile(),
+                     scmoe._dense.lower(cfg, x, p).compile()):
+        mem = compiled.memory_analysis()
+        assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes) < HBM_BYTES
+
+
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_flash_attention_d128(one_chip, direction):
     from accl_tpu.ops.flash import flash_attention

@@ -134,8 +134,8 @@ def _flat_layer_call(layer, x, p):
     buffers: every payload written and read flat."""
     cfg, a = layer.cfg, layer.accl
     kw = dict(from_fpga=True, to_fpga=True)
-    rows, ecounts, pcounts, order, gates, base = moe_ep._route(
-        cfg, layer.nranks, x, p)
+    rows, ecounts, pcounts, order, gates, _routed, local, _picks = \
+        moe_ep._route(cfg, layer.nranks, x, p)
     layer.dispatch_send.set_dev_range(0, rows.reshape(-1))
     layer.ecount_send.set_dev_range(0, ecounts)
     layer.dispatch_count.set_dev_range(0, pcounts)
@@ -149,7 +149,8 @@ def _flat_layer_call(layer, x, p):
     a.alltoallv(layer.combine_send, layer.combine_recv,
                 layer.dispatch_recv_count, layer.combine_recv_count,
                 row=cfg.hidden, **kw)
-    return _flat_combine(cfg, layer.combine_recv.flat, order, gates, base)
+    return _flat_combine(cfg, layer.combine_recv.flat, order, gates,
+                         x.astype(jnp.float32) + local)
 
 
 def test_row_form_layer_equals_the_flat_form():
@@ -187,7 +188,10 @@ def test_row_form_layer_equals_the_flat_form():
     with TpuWorld(P) as world:
         got = world.run(fn)
         flat_ops = world.engine.stats["a2av_flat_operands"]
-    cap = -(-P * T * CFG.top_k // moe_ep._TILE_ROWS) * moe_ep._TILE_ROWS
+    # dropless: each token picks at most min(top-k, held) of a rank's
+    # experts
+    most = P * T * min(CFG.top_k, CFG.n_experts // P)
+    cap = -(-most // moe_ep._TILE_ROWS) * moe_ep._TILE_ROWS
     for outs, mid, forms in got:
         assert mid == 0
         assert forms == ((cap, CFG.hidden), (T * CFG.top_k, CFG.hidden))
@@ -295,3 +299,46 @@ def test_experts_must_divide_over_the_ranks():
     with TpuWorld(3) as world:
         with pytest.raises(ValueError, match="divide"):
             world.run(lambda a, r: moe_ep.EPMoELayer(a, CFG, T))
+
+
+def test_moonlight_defaults_unchanged():
+    """The DeepSeek-V3 layer on its default shares (rank r holds experts
+    4r..4r+3) gives what strided shares given explicitly (rank r holds
+    r, r+4, r+8, r+12) give through the slot map, bit for bit: the new
+    defaults leave Moonlight's layer as it was."""
+    from accl_tpu.backends.tpu import TpuWorld
+
+    from dataclasses import replace
+
+    strided = replace(CFG, held=tuple(tuple(range(r, CFG.n_experts, P))
+                                      for r in range(P)))
+    assert CFG.held is None and CFG.zero_experts == 0
+    assert CFG.router == "sigmoid_norm"
+    layers, xs = _params(21), _inputs(22)
+
+    def fn(a, r):
+        dev = world.engine.devices[r]
+        outs = []
+        for cfg in (CFG, strided):
+            layer = moe_ep.EPMoELayer(a, cfg, T)
+            idx = np.asarray(list(layer.held))
+            x = jax.device_put(xs[r], dev)
+            ys = []
+            for p in layers:
+                p = {k: (v[idx] if k in ("w1", "w3", "w2") else v)
+                     for k, v in p.items()}
+                x = layer(x, jax.device_put(p, dev))
+                ys.append(np.asarray(x))
+            outs.append((ys, layer.stats()))
+            layer.close()
+        return outs
+
+    with TpuWorld(P) as world:
+        got = world.run(fn)
+    for (base, sb), (other, so) in got:
+        for yb, yo in zip(base, other):
+            assert np.array_equal(yb.view(np.uint16), yo.view(np.uint16))
+        assert sb["zero_rows"] == so["zero_rows"] == 0
+        assert sb["absent_rows"] == so["absent_rows"] == 0
+    assert sum(s["dispatch_recv_rows"] for (_y, s), _o in got) == \
+        P * T * CFG.top_k * L
